@@ -14,7 +14,7 @@ absent, checked 2026-07-30):
 * ``pandas`` — ``merge_asof(by=key)`` + groupby ``rolling('10s')``
   mean/std + groupby ``ewm(alpha).mean()``: the idiomatic single-node
   answer, and a *stronger* per-row baseline than Spark local-mode
-  (argued in BASELINE.md).
+  (pandas beats Spark local mode per row).
 * ``numpy`` — a hand-vectorised implementation of the same ops:
   searchsorted + last-valid-scan AS-OF (the reference's
   ``__getLastRightRow`` semantics), prefix-sum windowed mean/std with

@@ -1,6 +1,6 @@
 """Capital-markets flow: skewed NBBO quotes <-> trades AS-OF join.
 
-Mirrors BASELINE.md configs 4-5 (the reference's capital-markets
+Mirrors bench configs 4-5 (the reference's capital-markets
 reference architecture): a Zipf-skewed symbol universe where a handful
 of tickers carry most of the volume — exactly the shape Spark needs the
 ``tsPartitionVal`` skew join for (reference tsdf.py:164-190).  Shows:

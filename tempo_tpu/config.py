@@ -59,9 +59,6 @@ KNOBS: Dict[str, Knob] = _knobs(
     Knob("TEMPO_TPU_COMPUTE_DTYPE", "dtype", None, "tempo_tpu/packing",
          "float64|float32 override of the per-backend metric-math "
          "dtype policy"),
-    Knob("TEMPO_TPU_CACHE_DIR", "path", "~/.cache/tempo_tpu/jax",
-         "tempo_tpu/__init__",
-         "persistent XLA compilation cache location; empty disables"),
     Knob("TEMPO_TPU_SORT_KERNELS", "bool", None, "tempo_tpu/ops/sortmerge",
          "force/forbid the sort-and-scan kernel forms (default: on for "
          "TPU, off elsewhere)"),
@@ -211,8 +208,9 @@ KNOBS: Dict[str, Knob] = _knobs(
          "AdmissionError (it could never run)"),
     Knob("TEMPO_TPU_SERVICE_HBM_BUDGET", "int", None,
          "tempo_tpu/service/admission",
-         "total HBM admission budget in bytes (default 2 GiB; "
-         "explicit 0 admits nothing): a query whose projected "
+         "total HBM admission budget in bytes (default: the first "
+         "device's reported memory limit, else 2 GiB; explicit 0 "
+         "admits nothing): a query whose projected "
          "footprint exceeds the whole budget is REJECTED; one that "
          "merely exceeds the currently-free share is QUEUED until "
          "running queries release theirs"),

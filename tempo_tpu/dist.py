@@ -597,12 +597,7 @@ class DistributedTSDF:
         #     [2n+3+H, 2n+3+2H) host-col non-null planes
         planes = [col.values for _, col in r_recs]
         valid_planes = [col.valid for _, col in r_recs]
-        chunk_mask = jnp.int64((1 << 21) - 1)
-        ts_chunks = [
-            ((right.ts >> shift) & chunk_mask).astype(dt)
-            for shift in (42, 21, 0)
-        ]
-        planes.extend(ts_chunks)
+        planes.extend(ts_chunk_planes(right.ts, dt))
 
         host_flat: Dict[str, np.ndarray] = {}
         h_notna_dev = []
@@ -1554,6 +1549,15 @@ def _canon_func(func: str) -> str:
 
     return {CLOSEST_LEAD: floor, MEAN_LEAD: average, MIN_LEAD: min_func,
             MAX_LEAD: max_func}.get(func, func)
+
+
+def ts_chunk_planes(ts, dt):
+    """An int64 ns timestamp plane as three planes of ``dt`` that hold
+    it exactly: bits 63..42 (arithmetic shift, so pre-epoch timestamps
+    keep their sign), 41..21 and 20..0.  ``collect`` adds them back."""
+    mask = jnp.int64((1 << 21) - 1)
+    return [(ts >> 42).astype(dt), ((ts >> 21) & mask).astype(dt),
+            (ts & mask).astype(dt)]
 
 
 def _key_perm(left_kf: pd.DataFrame, right_kf: pd.DataFrame,

@@ -41,7 +41,7 @@ def _estimate_merged_lanes(l_codes: np.ndarray, r_codes: np.ndarray,
                            n_series: int) -> int:
     """Padded merged-lane count the AS-OF kernels would materialise for
     the dense layout — the quantity whose measured ceiling (~205K lanes,
-    BASELINE.md r3) OOM-kills the XLA compiler.  Host-side and O(n):
+    round-3 chip notes) OOM-kills the XLA compiler.  Host-side and O(n):
     runs before any packing so oversize joins can be rerouted."""
     max_l = int(np.bincount(l_codes, minlength=max(n_series, 1)).max(initial=0))
     max_r = int(np.bincount(r_codes, minlength=max(n_series, 1)).max(initial=0))

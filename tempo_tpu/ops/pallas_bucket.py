@@ -252,7 +252,7 @@ def _bucket_stats_call(bid, x, valid, depth=2, interpret=False):
             out_like=1, bk=bk, depth=depth, interpret=interpret)
         return tuple(o[..., :K, :] for o in out)
 
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec2 = pl.BlockSpec((bk, L), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
         if n_cols == 1:
@@ -267,7 +267,7 @@ def _bucket_stats_call(bid, x, valid, depth=2, interpret=False):
             in_specs=[spec2, spec3, spec3],
             out_specs=[spec3] * 7,
             out_shape=[jax.ShapeDtypeStruct(out_shape, jnp.float32)] * 7,
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
                 dimension_semantics=psr.grid_semantics(len(grid)),
             ),
@@ -418,7 +418,7 @@ def _resample_ema_call(secs, x, valid, step, alpha, scale, depth=2,
             interpret=interpret)
         return out[0][:K], out[1][:K]
 
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, L), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
         out = pl.pallas_call(
@@ -428,7 +428,7 @@ def _resample_ema_call(secs, x, valid, step, alpha, scale, depth=2,
             + [spec] * 3,
             out_specs=[spec] * 2,
             out_shape=[jax.ShapeDtypeStruct((K_pad, L), jnp.float32)] * 2,
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
                 dimension_semantics=psr.grid_semantics(len(grid)),
             ),

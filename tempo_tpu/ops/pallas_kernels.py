@@ -49,20 +49,6 @@ _BK = 32  # series rows per grid step; carries + roll temps + I/O double
           # buffers for a [32, 8192] f32 block stay under the 16M VMEM cap
 
 
-def x64_off():
-    """Context manager forcing 32-bit tracing around a pallas_call
-    (index maps must trace as i32: under the library's global x64 mode
-    they come out i64, which Mosaic's func.return rejects).  Newer jax
-    exposes this as ``jax.enable_x64``; older builds (this image's
-    0.4.37) only have the experimental context manager — same object,
-    different home."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import enable_x64 as _enable_x64
-
-    return _enable_x64(False)
-
-
 def interpret_scope(interpret: bool):
     """Scope for CALLING an interpret-capable kernel wrapper: interpret
     mode inlines the pallas machinery into the caller's jaxpr and
@@ -72,15 +58,7 @@ def interpret_scope(interpret: bool):
     x64).  Compiled mode needs no extra scope."""
     import contextlib
 
-    return x64_off() if interpret else contextlib.nullcontext()
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across jax versions (older builds spell
-    it ``TPUCompilerParams``)."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return jax.enable_x64(False) if interpret else contextlib.nullcontext()
 
 
 def _ladder_levels(L: int):
@@ -182,7 +160,7 @@ def _cumsum3_call(x, valid, interpret=False):
     # three carries + three outputs live at once: a larger array budget
     grid, bk, K_pad = _plan(K, L, arrays=16, bk_max=16) or ((1,), K, K)
     x, valid = _pad_rows(x, K_pad), _pad_rows(valid, K_pad)
-    with x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, L), lambda i: (i, 0), memory_space=pltpu.VMEM)
         out = pl.pallas_call(
             _cumsum3_kernel,
@@ -265,7 +243,7 @@ def _ema_call(x, valid, alpha, interpret=False):
     x, valid = _pad_rows(x, K_pad), _pad_rows(valid, K_pad)
     # index maps must trace as i32: under the library's global x64 mode
     # they come out i64, which Mosaic's func.return rejects
-    with x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, L), lambda i: (i, 0), memory_space=pltpu.VMEM)
         out = pl.pallas_call(
             _ema_kernel,
@@ -287,7 +265,7 @@ def _last_valid_call(x, valid, interpret=False):
     K, L = x.shape
     grid, bk, K_pad = _plan(K, L) or ((1,), K, K)
     x, valid = _pad_rows(x, K_pad), _pad_rows(valid, K_pad)
-    with x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, L), lambda i: (i, 0), memory_space=pltpu.VMEM)
         out = pl.pallas_call(
             _last_valid_kernel,
@@ -308,7 +286,7 @@ def _index_scan_call(valid, kernel, interpret=False):
     K, L = valid.shape
     grid, bk, K_pad = _plan(K, L, arrays=8) or ((1,), K, K)
     valid = _pad_rows(valid, K_pad)
-    with x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, L), lambda i: (i, 0), memory_space=pltpu.VMEM)
         out = pl.pallas_call(
             kernel,

@@ -337,7 +337,7 @@ def _merge_call(keys, payload, n_payload, Lc2, Llp, segmented=False,
         )
     grid, bk, K_pad = plan
     args = [pk._pad_rows(a, K_pad) for a in (*keys, *payload)]
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, Lc2), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
         ospec = pl.BlockSpec((bk, Llp), lambda i: (i, 0),
@@ -354,7 +354,7 @@ def _merge_call(keys, payload, n_payload, Lc2, Llp, segmented=False,
             # 16M default scoped-vmem cap at [8, 16384] blocks; v5e has
             # 128M physical VMEM per core — raise the cap instead of
             # shrinking blocks below Mosaic's 8-sublane minimum
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
             ),
             interpret=interpret,
@@ -592,7 +592,7 @@ def _merge_network_xla(keys, payload, Lc2, Llp, segmented, keyed_fill):
 
     ~3*log2(Lc2) simple stages compile where ``lax.sort``'s O(log^2)
     unrolled network OOM-killed the compiler at ~205K lanes
-    (BASELINE.md r3), which is the point: this is the oversize engine
+    (round-3 chip notes), which is the point: this is the oversize engine
     for tracer contexts (shard_map in dist.py / parallel/halo.py)."""
     shape = keys[0].shape
     roll = _roll_jnp
@@ -750,7 +750,7 @@ def _rank_call(keys, isk, n_keys, Lc2, Lqp, interpret=False):
         raise ValueError("merge_rank kernel infeasible for this shape")
     grid, bk, K_pad = plan
     args = [pk._pad_rows(a, K_pad) for a in (*keys, isk)]
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, Lc2), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
         ospec = pl.BlockSpec((bk, Lqp), lambda i: (i, 0),
@@ -761,7 +761,7 @@ def _rank_call(keys, isk, n_keys, Lc2, Lqp, interpret=False):
             in_specs=[spec] * (n_keys + 1),
             out_specs=ospec,
             out_shape=jax.ShapeDtypeStruct((K_pad, Lqp), jnp.float32),
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
             ),
             interpret=interpret,
@@ -1149,14 +1149,14 @@ def _chunked_call(keys, payload, n_payload, n_out, Cm, segmented,
         # the horizon is a runtime SMEM scalar: one compiled program
         # per shape serves every maxLookback value
         args = [jnp.asarray(ml, jnp.float32).reshape(1)] + args
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, Cm), lambda i, c: (i, c),
                             memory_space=pltpu.VMEM)
         ospec = pl.BlockSpec((bk, CL), lambda i, c: (i, c),
                              memory_space=pltpu.VMEM)
         # ring mode keeps the payload planes in HBM and streams them
         # through the explicit prefetch ring (scratch below)
-        pspec = (pl.BlockSpec(memory_space=pltpu.ANY) if use_ring
+        pspec = (pl.BlockSpec(memory_space=pl.ANY) if use_ring
                  else spec)
         sspec = [pl.BlockSpec(memory_space=pltpu.SMEM)] if windowed \
             else []
@@ -1182,7 +1182,7 @@ def _chunked_call(keys, payload, n_payload, n_out, Cm, segmented,
             out_shape=[jax.ShapeDtypeStruct((K_pad, nc * CL),
                                             jnp.float32)] * n_out,
             scratch_shapes=scratch,
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
                 dimension_semantics=psr.grid_semantics(
                     2, carry_axes=(1,)),

@@ -161,7 +161,7 @@ def _stats_call(secs, x, valid, window, max_behind, max_ahead,
     grid, bk, K_pad = plan
     secs = pk._pad_rows(secs, K_pad)
     x, valid = pk._pad_rows(x, K_pad), pk._pad_rows(valid, K_pad)
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec = pl.BlockSpec((bk, L), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
         out = pl.pallas_call(
@@ -174,7 +174,7 @@ def _stats_call(secs, x, valid, window, max_behind, max_ahead,
             # measured 18.9M at [8, 8192] blocks: over the 16M default
             # scoped cap; v5e has 128M physical VMEM (same treatment as
             # the merge kernel)
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
             ),
             interpret=interpret,

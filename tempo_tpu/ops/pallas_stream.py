@@ -1,10 +1,11 @@
 """Explicit DMA pipelining for the HBM-stream-bound kernels.
 
 The streaming kernels (``ops/pallas_window.py``, ``ops/pallas_bucket.py``)
-ran at 0.18-0.28 of the *measured* 675 GB/s stream rate (BENCH_r05
-``roofline``): every grid step's HBM->VMEM block copy rode Mosaic's
-implicit BlockSpec pipeline, which is fixed at double buffering and
-couples the copy granularity to the compute granularity.  This module
+ran at 0.18-0.28 of the *measured* 675 GB/s stream rate (the
+``roofline`` of the pre-PR-1 chip bench): every grid step's HBM->VMEM
+block copy rode Mosaic's implicit BlockSpec pipeline, which is fixed at
+double buffering and couples the copy granularity to the compute
+granularity.  This module
 provides the two mechanisms BlockSpecs cannot express:
 
 * :func:`ring_call` — an **N-deep input ring**: the operands stay in
@@ -168,11 +169,13 @@ def _slab(ref, i: int, bk: int):
 
 
 def _make_ring_kernel(math, n_scalar: int, n_in: int, n_out: int,
-                      bk: int, n_slabs: int, depth: int):
+                      bk: int, n_slabs: int, depth: int, as_bool=()):
     """Kernel closure running ``math`` over every row slab with the
     N-deep input ring and double-buffered output staging.  ``math``
     takes (scalar_refs_tuple, slab_arrays_list) and returns ``n_out``
-    f32 arrays shaped like the out-template slab."""
+    f32 arrays shaped like the out-template slab.  Inputs listed in
+    ``as_bool`` ride the ring as int32 (Mosaic DMAs no bool) and reach
+    ``math`` as bool again."""
 
     def kernel(*refs):
         scalar_refs = refs[:n_scalar]
@@ -210,8 +213,9 @@ def _make_ring_kernel(math, n_scalar: int, n_in: int, n_out: int,
                     in_dma(nxt, j).start()
             for j in range(n_in):
                 in_dma(i, j).wait()
-            outs = math(scalar_refs, [rings[j][slot]
-                                      for j in range(n_in)])
+            slabs = [rings[j][slot] for j in range(n_in)]
+            outs = math(scalar_refs, [a != 0 if j in as_bool else a
+                                      for j, a in enumerate(slabs)])
             # the stage pair is reused every other slab: the write of
             # slab i-2 must have landed before slab i overwrites it
             if i >= 2:
@@ -239,6 +243,9 @@ def ring_call(math, scalars: Sequence, planes: Sequence, n_out: int,
     analyzer's vmem-budget rule folds the declared ring/stage scratch
     at its full N-deep shape) and for checking :func:`ring_plan`."""
     planes = [jnp.asarray(p) for p in planes]
+    as_bool = tuple(j for j, p in enumerate(planes) if p.dtype == jnp.bool_)
+    planes = [p.astype(jnp.int32) if j in as_bool else p
+              for j, p in enumerate(planes)]
     K_pad = planes[0].shape[-2]
     plan = ring_plan(K_pad, bk, depth)
     if plan is None:
@@ -258,17 +265,17 @@ def ring_call(math, scalars: Sequence, planes: Sequence, n_out: int,
            pltpu.SemaphoreType.DMA((2, n_out))]
     )
     kernel = _make_ring_kernel(math, n_scalar, n_in, n_out, bk,
-                               n_slabs, depth)
-    with pk.x64_off():
+                               n_slabs, depth, as_bool)
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * n_scalar
-            + [pl.BlockSpec(memory_space=pltpu.ANY)] * n_in,
-            out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n_out,
+            + [pl.BlockSpec(memory_space=pl.ANY)] * n_in,
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_out,
             out_shape=[jax.ShapeDtypeStruct(out_tpl.shape, jnp.float32)]
             * n_out,
             scratch_shapes=scratch,
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
             ),
             interpret=interpret,

@@ -8,8 +8,9 @@ W<=64 rows (measured: W~150 overflowed VMEM by 7M, W~266 by 20M).
 Wider frames used to fall back to the prefix-scan + RMQ form
 (``ops/rolling.py:windowed_stats``), which is gather-bound on this
 hardware (~96 ms per ``take_along_axis`` level at [1024, 8192]) — the
-one regime where a TPU chip lost to a single CPU core (BENCH_r05
-``2b_range_stats_dense_50hz``: 8.0M rows/s vs 9.6M numpy).
+one regime where a TPU chip lost to a single CPU core (the pre-PR-1
+chip bench's ``2b_range_stats_dense_50hz``: 8.0M rows/s vs 9.6M
+numpy).
 
 This module replaces that regime with a *streaming* kernel: the block
 tiles through VMEM once (one HBM read of (secs, x, valid), one write
@@ -45,8 +46,8 @@ inside the kernel — downstream consumers that previously re-streamed
 the column through a separate elementwise pass (bench bodies, fused
 pipelines) fold it here for free.
 
-HBM-roofline mechanisms (PR 6 — BENCH_r05 put these kernels at
-0.18-0.28 of the measured stream rate):
+HBM-roofline mechanisms (PR 6 — the pre-PR-1 chip bench put these
+kernels at 0.18-0.28 of the measured stream rate):
 
 * **multi-column payload packing** (``range_stats_stream_packed`` /
   ``range_stats_unrolled_packed``): one kernel pass reduces a stacked
@@ -133,8 +134,8 @@ def _stream_max_rows() -> int:
     dynamic-rotate passes, so at SOME width the O(L log L) sort-based
     windowed form must win again; extrapolating the measured pass rate
     (~15us per [1024, 8192] rotate) against the measured RMQ-path
-    floor (~1.05 s/iteration at that shape, BENCH_r05) puts the
-    crossover above 20k rows.  Re-measure with bench.py
+    floor (~1.05 s/iteration at that shape, the pre-PR-1 chip bench)
+    puts the crossover above 20k rows.  Re-measure with bench.py
     --only-stream-stats and override here.  Env unset falls back to
     the tuned-profile prior (tempo_tpu/tune — the autotuner's
     audit-gated winner: a candidate ceiling that flipped the engine
@@ -245,8 +246,13 @@ def _window_math(max_behind: int, max_ahead: int, unroll: bool,
                               _roll(xc, L - j),
                               _roll(xc2, L - j))
 
-        # j = 0: the row itself (always inside its own frame)
-        carry = (validf, xc, xc2,
+        # j = 0: the row itself (always inside its own frame).  The
+        # square seeds s2 through a select (bitwise xc2: xc is +0 on
+        # invalid rows) so no compiler contracts it with the first
+        # accumulation into an FMA: XLA:CPU did so in the unrolled form,
+        # where product and add share one fusion, and not in the loop
+        # form, which rounded stddev 1 ulp apart
+        carry = (validf, xc, jnp.where(valid, xc2, f0),
                  jnp.where(valid, xc, pinf), jnp.where(valid, xc, -pinf))
         if unroll:
             for j in range(1, max_behind + 1):
@@ -397,7 +403,7 @@ def _call(secs, x, valid, params, scale, max_behind, max_ahead,
             bk=bk, depth=depth, interpret=interpret)
         return tuple(o[..., :K, :] for o in out)
 
-    with pk.x64_off():
+    with jax.enable_x64(False):
         spec2 = pl.BlockSpec((bk, L), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
         if n_cols == 1:
@@ -415,7 +421,7 @@ def _call(secs, x, valid, params, scale, max_behind, max_ahead,
             + [spec2, spec3, spec3],
             out_specs=[spec3] * 8,
             out_shape=[jax.ShapeDtypeStruct(out_shape, jnp.float32)] * 8,
-            compiler_params=pk.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024,
                 dimension_semantics=psr.grid_semantics(len(grid)),
             ),

@@ -35,10 +35,10 @@ from tempo_tpu.ops import window_utils as wu
 #    (ops/sortmerge.py:range_stats_shifted; VMEM-resident via the
 #    unrolled ops/pallas_window.py kernel on TPU).  Wins every extent
 #    it can legally reach (shifted 175M rows/s vs windowed 8.0M on
-#    identical ~140-row windows, BENCH_r05) but is bounded by
-#    resources: compile-time growth on small shards (SHIFTED_MAX_ROWS)
-#    and HBM shifted-copy materialisation on large ones
-#    (:func:`shifted_row_budget`).
+#    identical ~140-row windows, the pre-PR-1 chip bench) but is
+#    bounded by resources: compile-time growth on small shards
+#    (SHIFTED_MAX_ROWS) and HBM shifted-copy materialisation on large
+#    ones (:func:`shifted_row_budget`).
 # 2. **stream** — the streaming VMEM sweep
 #    (ops/pallas_window.py:range_stats_stream): same O(W) work but the
 #    width is a runtime scalar, O(1) live planes, one HBM read — it

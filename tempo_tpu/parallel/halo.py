@@ -38,28 +38,21 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map as _shard_map_raw  # jax >= 0.8
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+from jax import shard_map as _shard_map_raw
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tempo_tpu.ops import asof as asof_ops
 from tempo_tpu.ops import rolling as rk
 
 from tempo_tpu.packing import RANGE_STATS, TS_PAD, TS_REAL_MAX
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return _shard_map_raw(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
+
 
 # sentinel smaller than any real ns timestamp, with headroom so
 # subtracting a window width cannot underflow int64 (mirror of TS_PAD)

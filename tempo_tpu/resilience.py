@@ -479,7 +479,7 @@ def call_with_retry(fn, *args, policy: Optional[RetryPolicy] = None,
 #: Merged-lane ceiling above which the AS-OF join degrades to the host
 #: time-bracketing path instead of handing XLA a program it cannot
 #: compile.  The measured failure: the lax.sort merge ladder OOM-killed
-#: the compiler at ~205K merged lanes (BASELINE.md r3, VERDICT.md
+#: the compiler at ~205K merged lanes (the round-3 chip notes, VERDICT.md
 #: missing #1); 192K leaves headroom below that cliff.
 DEFAULT_MAX_MERGED_LANES = 196_608
 

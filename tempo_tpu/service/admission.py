@@ -33,7 +33,8 @@ from typing import Optional
 
 from tempo_tpu.plan import ir
 
-#: default total-HBM admission budget (bytes) when the knob is unset.
+#: total-HBM admission budget (bytes) when the knob is unset and the
+#: backend reports no device memory limit (the CPU).
 _DEFAULT_HBM_BUDGET = 2 << 30
 
 
@@ -71,12 +72,19 @@ def vmem_budget_bytes() -> int:
 
 
 def hbm_budget_bytes() -> int:
-    """``TEMPO_TPU_SERVICE_HBM_BUDGET``; unset = 2 GiB.  An explicit 0
-    means 0 (admit nothing) — only *unset* defaults."""
+    """``TEMPO_TPU_SERVICE_HBM_BUDGET``; unset = the memory limit the
+    first device reports (16 GB on a v5e chip), or 2 GiB where the
+    backend reports none.  An explicit 0 means 0 (admit nothing) —
+    only *unset* defaults."""
+    import jax
+
     from tempo_tpu import config
 
     val = config.get_int("TEMPO_TPU_SERVICE_HBM_BUDGET")
-    return _DEFAULT_HBM_BUDGET if val is None else val
+    if val is not None:
+        return val
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", _DEFAULT_HBM_BUDGET))
 
 
 def _geometry(node: ir.Node) -> Optional[tuple]:

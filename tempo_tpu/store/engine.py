@@ -776,6 +776,19 @@ def read_dataset_df(path: str, columns: Optional[List[str]] = None,
     ctx.raise_if_corrupt()
     schema = ds.schema if cols is None else pa.schema(
         [ds.schema.field(c) for c in cols])
-    if not batches:
-        return pa.Table.from_batches([], schema).to_pandas()
-    return pa.Table.from_batches(batches, schema).to_pandas()
+    df = pa.Table.from_batches(batches, schema).to_pandas()
+    return _restore_datetime_units(df, ds.schema.pandas_metadata)
+
+
+def _restore_datetime_units(df: pd.DataFrame, meta) -> pd.DataFrame:
+    """Give datetime columns back the unit they were written with.
+    Parquet has no seconds unit, so a ``datetime64[s]`` column is stored
+    as milliseconds and pandas reads ``datetime64[ms]``; the pandas
+    metadata pyarrow writes beside it names the original dtype."""
+    for col in (meta or {}).get("columns", []):
+        name, want = col.get("name"), col.get("numpy_type") or ""
+        if (col.get("pandas_type") == "datetime" and name in df.columns
+                and want.startswith("datetime64[")
+                and str(df[name].dtype) != want):
+            df[name] = df[name].astype(want)
+    return df

@@ -62,11 +62,14 @@ def main(argv=None) -> int:
 
     names = ([c.strip() for c in args.classes.split(",") if c.strip()]
              if args.classes else None)
-    out_path = args.out
-    if out_path is None and not args.smoke:
-        out_path = profile.default_path()
     payload, failures = harness.sweep(
-        class_names=names, smoke=args.smoke, out_path=out_path)
+        class_names=names, smoke=args.smoke, out_path=args.out)
+    if args.out is None and not args.smoke and payload.get("fingerprint"):
+        # the checked-in location of the device the children measured
+        # on (this process never starts a backend of its own)
+        path = profile.default_path(payload["fingerprint"]["device_kind"])
+        profile.write(payload, path)
+        print(f"[tune] profile written to {path}", file=sys.stderr)
 
     for name, rec in payload["classes"].items():
         if "hardware_gated" in rec:
@@ -81,8 +84,8 @@ def main(argv=None) -> int:
                   f"x{rec['speedup']}) knobs={rec['knobs']} "
                   f"[{rec['probes']} probes, "
                   f"{len(rec['rejected'])} rejected]", file=sys.stderr)
-    if out_path:
-        print(f"[tune] profile written: {out_path}", file=sys.stderr)
+    if args.out:
+        print(f"[tune] profile written: {args.out}", file=sys.stderr)
     for f in failures:
         print(f"[tune] BITWISE-AUDIT FAILURE: class {f['class']} "
               f"knobs {f['knobs']}: {f['reason']}", file=sys.stderr)

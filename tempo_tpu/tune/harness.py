@@ -30,9 +30,11 @@ re-probe included), so a knob that is structurally inert on this
 backend keeps its default even when scheduler noise hands one child a
 lucky run — the defaults stay the incumbent unless the win reproduces.
 
-Classes marked ``requires_tpu`` on a non-TPU backend are recorded
-``hardware_gated`` with the reason — runnable unchanged on real
-hardware, never faked.
+The harness itself never starts a JAX backend: a chip belongs to one
+process, so a parent holding it would starve every probe child.  The
+children decide what their backend can run — a class whose kernels need
+a TPU comes back ``hardware_gated`` with the reason, never faked — and
+report the device they measured on, which keys the profile.
 """
 
 from __future__ import annotations
@@ -108,26 +110,18 @@ def run_probe(probe: str, knobs: Dict[str, object],
                          f"({type(e).__name__}: {e})"}
 
 
-def _backend() -> str:
-    import jax
-
-    return jax.default_backend()
-
-
 def sweep_class(cls: tune_space.ShapeClass, smoke: bool = False,
                 probe_fn=run_probe) -> Tuple[Dict, List[Dict]]:
     """Sweep one shape class; returns (class record, audit failures).
     ``probe_fn`` is injectable for the harness unit tests."""
-    if cls.requires_tpu and _backend() != "tpu":
-        reason = (f"requires TPU (backend is {_backend()!r}): the "
-                  f"Mosaic kernels this class tunes cannot run here — "
-                  f"sweep runs unchanged on real hardware")
-        logger.info("tune: class %s hardware-gated: %s", cls.name, reason)
-        return {"hardware_gated": reason}, []
-
     t0 = time.time()
     assign: Dict[str, object] = {}
     base = probe_fn(cls.probe, assign, smoke=smoke)
+    if "hardware_gated" in base:
+        logger.info("tune: class %s hardware-gated: %s", cls.name,
+                    base["hardware_gated"])
+        return {"hardware_gated": base["hardware_gated"],
+                "fingerprint": base.get("fingerprint")}, []
     if "error" in base:
         return {"error": f"baseline probe failed: {base['error']}"}, []
     digest0 = base["digest"]
@@ -243,6 +237,7 @@ def sweep_class(cls: tune_space.ShapeClass, smoke: bool = False,
         "sweep_seconds": round(time.time() - t0, 1),
         "audit": "bitwise (every kept candidate's output digest == "
                  "the default-knob digest on deterministic data)",
+        "fingerprint": base.get("fingerprint"),
     }
     if base.get("stream_gbps"):
         record["stream_gbps"] = base["stream_gbps"]
@@ -285,9 +280,14 @@ def sweep(class_names=None, smoke: bool = False,
         measured["join_chunked_rate"] = (
             float(jc["bytes_per_iter"]) / float(jc["t_iter"]))
 
+    # the device the probe children measured on keys the profile
+    fingerprint = next((rec.pop("fingerprint") for rec in records.values()
+                        if rec.get("fingerprint")), None)
+    for rec in records.values():
+        rec.pop("fingerprint", None)
     payload = {
         "format_version": tune_profile.FORMAT_VERSION,
-        "fingerprint": tune_profile.runtime_fingerprint(),
+        "fingerprint": fingerprint,
         "created_unix": int(time.time()),
         "smoke": bool(smoke),
         "margin": MARGIN,

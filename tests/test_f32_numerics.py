@@ -4,9 +4,9 @@ On TPU the metric kernels compute in float32 (f64 is ~25x emulated,
 packing.compute_dtype); the reference computes in f64 on the JVM
 (tsdf.py:709-718).  This tier runs the same frame-level ops under
 ``TEMPO_TPU_COMPUTE_DTYPE=float32`` against the f64 run and asserts
-the divergence stays inside the documented bounds (BASELINE.md carries
-the measured table at L=2^13..2^17 produced by
-``tools/f32_error_table.py``).
+the divergence stays inside the documented bounds
+(``tempo_tpu.testing.numerics.F32_BOUNDS``; ``tools/f32_error_table.py``
+measures the table at L=2^13..2^17).
 
 The bound model: prefix sums are mean-centred per series, so window
 aggregates of W values drift like W * eps_f32 * |x| (not L * eps);
@@ -18,24 +18,15 @@ import pandas as pd
 import pytest
 
 from tempo_tpu import TSDF
+from tempo_tpu.testing.numerics import F32_BOUNDS
 
 L = 8192          # rows per key in this tier (the tool sweeps 2^13..2^17)
 K = 4
 
-# Asserted ceilings for standard-normal data at L=8192, 32-row windows.
-# Generous vs the measured table in BASELINE.md (~10x headroom) so the
-# tier is a tripwire for accumulation-order regressions, not noise.
-BOUNDS = {
-    "mean": 5e-4,
-    "sum": 5e-3,
-    "count": 0.0,        # exact: integer accumulation in f32 < 2^24
-    "min": 1e-6,         # selection, not accumulation (casting only)
-    "max": 1e-6,
-    "stddev": 5e-3,
-    "zscore": 5e-2,      # divides by a small stddev: loosest
-    "ema": 1e-4,
-    "linear": 1e-5,      # interpolation is local arithmetic
-}
+# Asserted ceilings for standard-normal data at L=8192, 32-row windows
+# (~10x headroom, so the tier is a tripwire for accumulation-order
+# regressions, not noise).
+BOUNDS = F32_BOUNDS
 
 
 @pytest.fixture(scope="module")

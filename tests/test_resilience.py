@@ -181,7 +181,7 @@ class TestMergedLanesKnob:
         assert resilience.max_merged_lanes() == 1234
 
     def test_default_sits_below_measured_compiler_oom(self, monkeypatch):
-        """BASELINE.md r3: the XLA sort-merge ladder OOM-killed the
+        """round-3 chip notes: the XLA sort-merge ladder OOM-killed the
         compiler at ~205K merged lanes; the default guard must trip
         before that measured cliff."""
         monkeypatch.delenv("TEMPO_TPU_MAX_MERGED_LANES", raising=False)

@@ -6,8 +6,9 @@ failures invisible to the resilience layer's classify/retry machinery.
 
 tools/check_no_dynamic_gather.py bans gather/scatter-shaped calls in
 the Pallas kernel modules (ops/pallas_*.py) — the primitive class
-behind the dense-regime rolling regression (BENCH_r05 2b at 8M rows/s,
-below one CPU core) that the streaming window engine removed."""
+behind the dense-regime rolling regression (config 2b of the
+pre-PR-1 chip bench at 8M rows/s, below one CPU core) that the
+streaming window engine removed."""
 
 import subprocess
 import sys

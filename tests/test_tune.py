@@ -375,7 +375,8 @@ def _fake_probe(rates, digests=None, calls=None):
         rate = (rates or {}).get(key, 1000.0)
         digest = (digests or {}).get(key, 42)
         return {"class": probe, "rows_per_sec": rate, "t_iter": 0.001,
-                "bytes_per_iter": 100, "digest": digest}
+                "bytes_per_iter": 100, "digest": digest,
+                "fingerprint": tp.runtime_fingerprint()}
     return fn
 
 
@@ -481,17 +482,47 @@ def test_harness_prunes_dominated_ladder():
     assert rec["knobs"] == {}
 
 
-def test_harness_hardware_gates_tpu_classes():
-    import jax
+def test_harness_hardware_gates_what_the_child_cannot_run():
+    """The probe child decides whether its backend runs the class (the
+    parent never starts a backend); a gated baseline gates the class
+    without probing any candidate."""
+    calls = []
 
-    if jax.default_backend() == "tpu":
-        pytest.skip("gating is for non-TPU backends")
+    def gated(probe, knobs, smoke=False, timeout=None):
+        calls.append(knobs)
+        return {"class": probe, "hardware_gated": "requires the TPU "
+                "backend", "fingerprint": tp.runtime_fingerprint()}
+
     cls = _cls([space.Axis("TEMPO_TPU_JOIN_CHUNK_LANES", (None, 4096),
                            (None, 4096))],
                owns=["TEMPO_TPU_JOIN_CHUNK_LANES"], requires_tpu=True)
-    rec, fails = harness.sweep_class(cls, probe_fn=_fake_probe({}))
+    rec, fails = harness.sweep_class(cls, probe_fn=gated)
     assert "hardware_gated" in rec and "TPU" in rec["hardware_gated"]
-    assert not fails
+    assert not fails and len(calls) == 1
+
+
+def test_harness_parent_never_starts_a_backend(monkeypatch):
+    """A chip belongs to one process: the sweep's parent must leave the
+    backend to its probe children, and key the profile by theirs."""
+    import jax
+
+    def no_backend(*a, **k):
+        raise AssertionError("the tune parent started a JAX backend")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    monkeypatch.setattr(jax, "default_backend", no_backend)
+    ax = space.Axis("TEMPO_TPU_SERVE_BATCH_ROWS", (64, 16), (64, 16))
+    cls = _cls([ax], owns=["TEMPO_TPU_SERVE_BATCH_ROWS"],
+               name="serve_batch")
+    monkeypatch.setattr(space, "SPACE", (cls,))
+    fp = {"device_kind": "TPU v5 lite", "jaxlib": "0.9.0"}
+
+    def child(probe, knobs, smoke=False, timeout=None):
+        return {"class": probe, "rows_per_sec": 1000.0, "t_iter": 0.001,
+                "bytes_per_iter": 100, "digest": 7, "fingerprint": fp}
+
+    payload, fails = harness.sweep(probe_fn=child)
+    assert not fails and payload["fingerprint"] == fp
 
 
 def test_harness_baseline_error_records_class_error():

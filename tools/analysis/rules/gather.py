@@ -4,9 +4,9 @@ modules.
 Supersedes ``tools/check_no_dynamic_gather.py`` (now a shim): per-lane
 dynamic gathers are the one data-movement primitive this hardware
 cannot do at speed (the ~96 ms ``take_along_axis`` levels behind the
-BENCH_r05 dense-regime loss) and Mosaic cannot lower them in-kernel at
-all.  The legacy script matched call *names* only; this rule adds the
-dataflow it punted on:
+dense-regime loss of the pre-PR-1 chip bench) and Mosaic cannot lower
+them in-kernel at all.  The legacy script matched call *names* only;
+this rule adds the dataflow it punted on:
 
 * aliased imports — ``from jax.numpy import take_along_axis as g`` /
   ``h = jnp.take`` are resolved through the module alias map;

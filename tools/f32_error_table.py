@@ -1,6 +1,6 @@
 """Measure f32-vs-f64 max abs error for the metric kernels at scale.
 
-Produces the BASELINE.md numerics table (VERDICT r1 item 5): runs
+Produces the f32 numerics table (VERDICT r1 item 5): runs
 withRangeStats (10s window), exact EMA, and linear interpolation under
 ``TEMPO_TPU_COMPUTE_DTYPE=float32`` and ``float64`` on the current
 backend and reports per-stat max abs divergence at L = 2^13 .. 2^17
