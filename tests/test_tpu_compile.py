@@ -81,7 +81,7 @@ def _planes(sharding, L, *dtypes, K=SERIES):
 
 
 # ----------------------------------------------------------------------
-# one builder per kernel: (sharding, lanes) -> lowered program
+# one function per kernel: (sharding, lanes) -> lowered program
 # ----------------------------------------------------------------------
 
 def _ema(sh, L):
