@@ -476,9 +476,9 @@ def report_environment() -> None:
     log(f"native packer loaded: {native.available()}")
 
 
-def one_chip(args) -> None:
-    left, right = make_data(args.seed, args.rows, args.series)
-    keys = sample_keys(args.series, args.seed)
+def one_chip(seed: int, rows: int, series: int = HHAR_SERIES) -> None:
+    left, right = make_data(seed, rows, series)
+    keys = sample_keys(series, seed)
     eager = run_phase("eager", lambda: eager_phase(left, right))
     ref = reference(left.df, right.df, keys)
     check_against_reference(eager["chain"], ref, keys, "eager chain")
@@ -499,7 +499,11 @@ MESHES = (
     ("series=4", {"series": 4}, None),
     ("series=2,time=2", {"series": 2, "time": 2}, "time"),
 )
-JOINED = ("user", "event_ts", "x", "right_event_ts", "right_wx")
+#: columns a mesh with a time axis may compute differently from one
+#: chip: the EMA scan carries its state across the time shards, which
+#: changes the association order of its f32 sums.  A series split
+#: changes no per-series arithmetic, so there every column is bitwise.
+TIME_SPLIT_MAY_DIFFER = ("EMA_x",)
 
 
 def device_bytes() -> list:
@@ -526,14 +530,35 @@ def mesh_phase(left, right, axes: dict, time_axis) -> dict:
     return {"df": eager, "held": held}
 
 
-def four_chips(args) -> None:
+def compare_with_one_chip(df: pd.DataFrame, one: pd.DataFrame, label: str,
+                          time_axis) -> None:
+    """Every column of a mesh's result bitwise equal to the one-chip
+    run's, but those in TIME_SPLIT_MAY_DIFFER on a mesh with a time axis
+    (they are held to the float64 reference instead).  Both frames
+    sorted by series and time."""
+    if list(df.columns) != list(one.columns) or len(df) != len(one):
+        raise AssertionError(f"[{label}] result shape differs from the "
+                             f"one-chip run")
+    may_differ = TIME_SPLIT_MAY_DIFFER if time_axis else ()
+    differ = sorted(c for c in one.columns if not np.array_equal(
+        df[c].to_numpy(), one[c].to_numpy(),
+        equal_nan=df[c].dtype.kind == "f"))
+    unexpected = [c for c in differ if c not in may_differ]
+    if unexpected:
+        raise AssertionError(f"[{label}] columns differ from the one-chip "
+                             f"run: {unexpected}")
+    log(f"[{label}] vs one chip: bitwise equal but {differ} (allowed to "
+        f"differ: {list(may_differ)})")
+
+
+def four_chips(seed: int, rows: int, series: int = HHAR_SERIES) -> None:
     """The join chain on devices[0], on a 4-way series mesh and on a
-    2x2 series x time mesh: planned == eager bitwise on each mesh, the
-    joined columns bitwise equal to the one-chip run, every column
-    against the float64 reference, and the data spread over the four
-    devices."""
-    left, right = make_data(args.seed, args.rows, args.series)
-    keys = sample_keys(args.series, args.seed)
+    2x2 series x time mesh: planned == eager bitwise on each mesh, every
+    column against the float64 reference, every column bitwise equal to
+    the one-chip run but those a time split may change, and the data
+    spread over the four devices."""
+    left, right = make_data(seed, rows, series)
+    keys = sample_keys(series, seed)
     ref = reference(left.df, right.df, keys)
     one = None
     for label, axes, time_axis in MESHES:
@@ -553,17 +578,8 @@ def four_chips(args) -> None:
         df = df.reset_index(drop=True)
         if one is None:
             one = df
-            continue
-        same = {c: bool(np.array_equal(df[c].to_numpy(), one[c].to_numpy(),
-                                       equal_nan=df[c].dtype.kind == "f"))
-                for c in one.columns}
-        differ = sorted(c for c, eq in same.items() if not eq)
-        if any(c in JOINED for c in differ):
-            raise AssertionError(f"[mesh {label}] joined columns differ "
-                                 f"from the one-chip run: {differ}")
-        log(f"[mesh {label}] vs one chip: joined columns bitwise equal; "
-            f"columns not bitwise equal (held to the reference instead): "
-            f"{differ}")
+        else:
+            compare_with_one_chip(df, one, f"mesh {label}", time_axis)
 
 
 def main(argv=None) -> int:
@@ -573,16 +589,15 @@ def main(argv=None) -> int:
     parser.add_argument("--rows", type=int, default=HHAR_ROWS,
                         help="rows a side before rounding to equal series "
                              "(default: the HHAR quickstart's)")
-    parser.add_argument("--series", type=int, default=HHAR_SERIES)
     args = parser.parse_args(argv)
 
     device = require_tpu(args.chips)
     report_environment()
     PROBE.install()
     if args.chips == 4:
-        four_chips(args)
+        four_chips(args.seed, args.rows)
     else:
-        one_chip(args)
+        one_chip(args.seed, args.rows)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

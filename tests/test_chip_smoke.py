@@ -3,12 +3,13 @@ run (the float64 reference, planned == eager bitwise through the query
 service, the engine report), and ``main()`` refuses to run anywhere but
 on a TPU."""
 
-import argparse
 import os
 import shutil
 import subprocess
 import sys
 
+import jax
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -25,8 +26,7 @@ def probe(monkeypatch):
 
 
 def test_one_chip_phases_check_out_at_tiny_size(probe, capsys):
-    args = argparse.Namespace(seed=3, rows=4096, series=8, chips=1)
-    chip_smoke.one_chip(args)
+    chip_smoke.one_chip(seed=3, rows=4096, series=8)
     out = capsys.readouterr().out
     for label in ("eager chain", "mesh chain", "interpolate",
                   "resampleEMA"):
@@ -49,6 +49,58 @@ def test_reference_check_catches_a_wrong_join():
     bad["right_wx"] = bad["right_wx"] + 1.0
     with pytest.raises(AssertionError, match="right_wx"):
         chip_smoke.check_against_reference(bad, ref, keys, "bad")
+
+
+def _live_device_bytes():
+    """Bytes of the live arrays' shards on each device: what
+    ``memory_stats()`` reports on a TPU, which the CPU does not."""
+    held = {d: 0 for d in jax.devices()}
+    for arr in jax.live_arrays():
+        for shard in arr.addressable_shards:
+            held[shard.device] += shard.data.nbytes
+    return [held[d] for d in jax.devices()]
+
+
+def test_four_chip_phase_checks_out_on_virtual_devices(probe, monkeypatch,
+                                                       capsys):
+    monkeypatch.setattr(chip_smoke, "device_bytes", _live_device_bytes)
+    chip_smoke.four_chips(seed=5, rows=2048, series=8)
+    out = capsys.readouterr().out
+    for label in ("1 chip", "series=4", "series=2,time=2"):
+        assert f"[mesh {label}] planned == eager bitwise" in out, label
+        assert f"[mesh {label}] reference check passed" in out, label
+    assert "[mesh series=4] vs one chip: bitwise equal but []" in out
+
+
+def _one_chip_frame():
+    left, right = chip_smoke.make_data(2, 1024, 4)
+    df = chip_smoke.eager_phase(left, right)["chain"]
+    return df.sort_values(["user", "event_ts"],
+                          kind="mergesort").reset_index(drop=True)
+
+
+@pytest.mark.parametrize("column,time_axis,allowed", [
+    ("mean_x", None, False),
+    ("EMA_x", None, False),
+    ("right_wx", "time", False),
+    ("stddev_x", "time", False),
+    ("EMA_x", "time", True),
+])
+def test_one_chip_comparison_allows_only_time_split_columns(
+        column, time_axis, allowed):
+    one = _one_chip_frame()
+    shifted = one.copy()
+    col = shifted[column].to_numpy(copy=True)
+    i = int(np.flatnonzero(~np.isnan(col))[0])
+    col[i] = np.nextafter(col[i], np.inf)
+    shifted[column] = col
+    chip_smoke.compare_with_one_chip(one, one, "same", time_axis)
+    if allowed:
+        chip_smoke.compare_with_one_chip(shifted, one, "shifted", time_axis)
+    else:
+        with pytest.raises(AssertionError, match=column):
+            chip_smoke.compare_with_one_chip(shifted, one, "shifted",
+                                             time_axis)
 
 
 def test_main_refuses_a_cpu_and_names_it(capsys):

@@ -66,10 +66,22 @@ def _describe_one_chip():
 
 @pytest.fixture(scope="module")
 def one_chip():
-    try:
-        return _describe_one_chip()
-    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    """The described chip for this module; afterwards the compile cache
+    and the environment are as they were, so the tests that share this
+    worker keep their persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_on = jax.config.values["jax_enable_compilation_cache"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            try:
+                sharding = _describe_one_chip()
+            except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+                pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+            yield sharding
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+            compilation_cache.reset_cache()
 
 
 def _s(sharding, shape, dtype):
