@@ -26,6 +26,7 @@ import numpy as np
 import pandas as pd
 
 from tempo_tpu import packing
+from tempo_tpu.profiling import span
 from tempo_tpu.packing import FlatLayout
 
 logger = logging.getLogger(__name__)
@@ -115,7 +116,8 @@ class TSDF:
         self.sequence_col = "" if sequence_col is None else sequence_col
         if self.sequence_col:
             self.__validated_column(df, self.sequence_col)
-        self.df = df.reset_index(drop=True)
+        with span("tempo.frame", rows=len(df)):
+            self.df = df.reset_index(drop=True)
         self._layout: Optional[FlatLayout] = None
         self._packed: Dict[str, object] = {}
 
@@ -244,11 +246,14 @@ class TSDF:
 
     def numeric_flat(self, col: str):
         """(values float64, valid bool) in sorted flat layout."""
-        series = self.df[col]
-        vals = pd.to_numeric(series, errors="coerce").to_numpy(dtype=np.float64)
-        valid = ~pd.isna(series).to_numpy()
-        valid &= ~np.isnan(vals)
-        return vals[self.layout.order], valid[self.layout.order]
+        order = self.layout.order
+        with span("tempo.pack", rows=len(order)):
+            series = self.df[col]
+            vals = pd.to_numeric(series, errors="coerce").to_numpy(
+                dtype=np.float64)
+            valid = ~pd.isna(series).to_numpy()
+            valid &= ~np.isnan(vals)
+            return vals[order], valid[order]
 
     def packed_len(self) -> int:
         return packing.pad_length(int(self.layout.lengths.max(initial=0)))
@@ -272,8 +277,10 @@ class TSDF:
         if key not in self._packed:
             vals, valid = self.numeric_flat(col)
             L = self.packed_len()
-            pv = packing.pack_column(vals.astype(dt), self.layout, L, fill=np.nan)
-            pm = packing.pack_column(valid, self.layout, L, fill=False)
+            with span("tempo.pack", rows=len(vals)):
+                pv = packing.pack_column(vals.astype(dt), self.layout, L,
+                                         fill=np.nan)
+                pm = packing.pack_column(valid, self.layout, L, fill=False)
             self._packed[key] = (pv, pm)
         return self._packed[key]
 
@@ -613,18 +620,19 @@ class TSDF:
                 skipNulls=skipNulls, sql_join_opt=sql_join_opt,
                 suppress_null_warning=suppress_null_warning,
                 maxLookback=maxLookback))
-        return join.asof_join(
-            self,
-            right_tsdf,
-            left_prefix=left_prefix,
-            right_prefix=right_prefix,
-            tsPartitionVal=tsPartitionVal,
-            fraction=fraction,
-            skipNulls=skipNulls,
-            sql_join_opt=sql_join_opt,
-            suppress_null_warning=suppress_null_warning,
-            maxLookback=maxLookback,
-        )
+        with span("tempo.asofJoin", rows=len(self.df)):
+            return join.asof_join(
+                self,
+                right_tsdf,
+                left_prefix=left_prefix,
+                right_prefix=right_prefix,
+                tsPartitionVal=tsPartitionVal,
+                fraction=fraction,
+                skipNulls=skipNulls,
+                sql_join_opt=sql_join_opt,
+                suppress_null_warning=suppress_null_warning,
+                maxLookback=maxLookback,
+            )
 
     def fourier_transform(self, timestep: float, valueCol: str) -> "TSDF":  # plan-ok: eager-only
         """Frequency-domain representation per series (parity:
@@ -736,7 +744,9 @@ class TSDF:
                 colsToSummarize=tuple(colsToSummarize)
                 if colsToSummarize else None,
                 rangeBackWindowSecs=rangeBackWindowSecs))
-        return rolling.with_range_stats(self, type, colsToSummarize, rangeBackWindowSecs)
+        with span("tempo.withRangeStats", rows=len(self.df)):
+            return rolling.with_range_stats(self, type, colsToSummarize,
+                                            rangeBackWindowSecs)
 
     def withGroupedStats(self, metricCols=None, freq=None) -> "TSDF":  # plan-ok: eager-only
         """Tumbling-window grouped statistics (parity: tsdf.py:723-759)."""
@@ -758,8 +768,9 @@ class TSDF:
             return self._plan_record("ema", params=dict(
                 colName=colName, window=window, exp_factor=exp_factor,
                 exact=exact, inclusive_window=inclusive_window))
-        return rolling.ema(self, colName, window, exp_factor, exact,
-                           inclusive_window)
+        with span("tempo.EMA", rows=len(self.df)):
+            return rolling.ema(self, colName, window, exp_factor, exact,
+                               inclusive_window)
 
     def vwap(  # plan-ok: eager-only
         self, frequency: str = "m", volume_col: str = "volume", price_col: str = "price"

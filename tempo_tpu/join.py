@@ -32,6 +32,7 @@ import numpy as np
 import pandas as pd
 
 from tempo_tpu import packing, profiling, resilience
+from tempo_tpu.profiling import span
 from tempo_tpu.ops import asof as asof_ops
 
 logger = logging.getLogger(__name__)
@@ -211,34 +212,35 @@ def _binpacked_indices(right, l_layout, r_layout, r_sorted_take,
     from tempo_tpu.ops import pallas_merge as pm
     from tempo_tpu.ops import sortmerge as sm
 
-    Wl = packing.pad_length(
-        max(int(l_layout.lengths.max(initial=0)), 1), 128)
-    Wr = packing.pad_length(
-        max(int(r_layout.lengths.max(initial=0)), 1), 128)
-    bp = packing.bin_pack_series(
-        l_layout.lengths, r_layout.lengths, Wl, Wr)
-    K2 = packing.pad_length(bp.n_rows)
-    # destination slots computed once, reused for every plane
-    dest_l = packing.binpack_dest(l_layout.starts, bp.row, bp.l_off, Wl)
-    dest_r = packing.binpack_dest(r_layout.starts, bp.row, bp.r_off, Wr)
-    lt = packing.binpack_scatter(
-        l_layout.ts_ns, dest_l, K2, Wl, packing.TS_PAD)
-    rt = packing.binpack_scatter(
-        r_layout.ts_ns, dest_r, K2, Wr, packing.TS_PAD)
-    lsid = packing.binpack_scatter(
-        l_layout.key_ids.astype(np.int32), dest_l, K2, Wl,
-        packing.SID_PAD)
-    rsid = packing.binpack_scatter(
-        r_layout.key_ids.astype(np.int32), dest_r, K2, Wr,
-        packing.SID_PAD)
-    rv = np.stack([
-        packing.binpack_scatter(
-            (~pd.isna(right.df[c])).to_numpy()[r_sorted_take],
-            dest_r, K2, Wr, False)
-        for c in valid_cols
-    ]) if valid_cols else np.zeros((0, K2, Wr), bool)
-    rsq = (packing.binpack_scatter(r_seq_sorted, dest_r, K2, Wr, np.inf)
-           if r_seq_sorted is not None else None)
+    with span("tempo.pack", rows=l_layout.n_rows + r_layout.n_rows):
+        Wl = packing.pad_length(
+            max(int(l_layout.lengths.max(initial=0)), 1), 128)
+        Wr = packing.pad_length(
+            max(int(r_layout.lengths.max(initial=0)), 1), 128)
+        bp = packing.bin_pack_series(
+            l_layout.lengths, r_layout.lengths, Wl, Wr)
+        K2 = packing.pad_length(bp.n_rows)
+        # destination slots computed once, reused for every plane
+        dest_l = packing.binpack_dest(l_layout.starts, bp.row, bp.l_off, Wl)
+        dest_r = packing.binpack_dest(r_layout.starts, bp.row, bp.r_off, Wr)
+        lt = packing.binpack_scatter(
+            l_layout.ts_ns, dest_l, K2, Wl, packing.TS_PAD)
+        rt = packing.binpack_scatter(
+            r_layout.ts_ns, dest_r, K2, Wr, packing.TS_PAD)
+        lsid = packing.binpack_scatter(
+            l_layout.key_ids.astype(np.int32), dest_l, K2, Wl,
+            packing.SID_PAD)
+        rsid = packing.binpack_scatter(
+            r_layout.key_ids.astype(np.int32), dest_r, K2, Wr,
+            packing.SID_PAD)
+        rv = np.stack([
+            packing.binpack_scatter(
+                (~pd.isna(right.df[c])).to_numpy()[r_sorted_take],
+                dest_r, K2, Wr, False)
+            for c in valid_cols
+        ]) if valid_cols else np.zeros((0, K2, Wr), bool)
+        rsq = (packing.binpack_scatter(r_seq_sorted, dest_r, K2, Wr, np.inf)
+               if r_seq_sorted is not None else None)
 
     if engine == "chunked":
         last_idx, per_col = pm.asof_merge_indices_chunked(
@@ -328,7 +330,8 @@ def asof_join(
         once per column (shared by the oversize-bracket carries and the
         packed validity planes)."""
         if c not in _valid_cache:
-            _valid_cache[c] = (~pd.isna(right.df[c])).to_numpy()
+            with span("tempo.pack", rows=len(right.df)):
+                _valid_cache[c] = (~pd.isna(right.df[c])).to_numpy()
         return _valid_cache[c]
 
     # --- joint key encoding over the union of both sides' keys ---------
@@ -350,7 +353,6 @@ def asof_join(
         r_seq_vals = np.where(np.isnan(r_seq_vals), -np.inf, r_seq_vals)
 
     # --- skew variant: compose key with overlapping time brackets ------
-    l_take = np.arange(len(left.df), dtype=np.int64)
     r_take = np.arange(len(right.df), dtype=np.int64)
     if broadcast_path:
         # the reference's sql_join_opt fast path returns before any skew
@@ -479,14 +481,16 @@ def asof_join(
         and _binpack_worthwhile(l_layout, r_layout)
     )
     if use_binpack:
-        last_row_idx, per_col_idx, bp = _binpacked_indices(
-            right, l_layout, r_layout, r_sorted_take,
-            right_value_cols if skipNulls else [],
-            max_lookback=int(maxLookback or 0),
-            r_seq_sorted=(r_seq_j[r_layout.order]
-                          if r_seq_j is not None else None),
-            engine=join_engine, interpret=interp_chunked,
-        )
+        with span("tempo.dispatch") as fetched:
+            last_row_idx, per_col_idx, bp = _binpacked_indices(
+                right, l_layout, r_layout, r_sorted_take,
+                right_value_cols if skipNulls else [],
+                max_lookback=int(maxLookback or 0),
+                r_seq_sorted=(r_seq_j[r_layout.order]
+                              if r_seq_j is not None else None),
+                engine=join_engine, interpret=interp_chunked,
+            )
+            fetched.rows = last_row_idx.size + per_col_idx.size
         keep_mask_packed = None
     else:
         bp = None
@@ -494,66 +498,69 @@ def asof_join(
     Ll = packing.pad_length(int(l_layout.lengths.max(initial=0)))
     Lr = packing.pad_length(int(r_layout.lengths.max(initial=0)))
     if not use_binpack:
-        l_ts_p = packing.pack_column(
-            l_layout.ts_ns, l_layout, Ll, fill=packing.TS_PAD)
-        r_ts_p = packing.pack_column(
-            r_layout.ts_ns, r_layout, Lr, fill=packing.TS_PAD)
+        with span("tempo.pack", rows=l_layout.n_rows + r_layout.n_rows):
+            l_ts_p = packing.pack_column(
+                l_layout.ts_ns, l_layout, Ll, fill=packing.TS_PAD)
+            r_ts_p = packing.pack_column(
+                r_layout.ts_ns, r_layout, Lr, fill=packing.TS_PAD)
 
-        # validity masks per right column (order: right_value_cols)
-        r_valid_packed = []
-        for c in right_value_cols:
-            valid = _right_valid(c)[r_sorted_take]
-            r_valid_packed.append(
-                packing.pack_column(valid, r_layout, Lr, fill=False)
+            # validity masks per right column (order: right_value_cols)
+            r_valid_packed = []
+            for c in right_value_cols:
+                valid = _right_valid(c)[r_sorted_take]
+                r_valid_packed.append(
+                    packing.pack_column(valid, r_layout, Lr, fill=False)
+                )
+            r_valids = np.stack(r_valid_packed) if r_valid_packed else \
+                np.zeros((0, n_series, Lr), bool)
+            r_seq_packed = (
+                packing.pack_column(
+                    r_seq_j[r_layout.order], r_layout, Lr, fill=np.inf
+                )
+                if r_seq_j is not None and not broadcast_path
+                else None
             )
-        r_valids = np.stack(r_valid_packed) if r_valid_packed else \
-            np.zeros((0, n_series, Lr), bool)
 
     # --- kernel dispatch ----------------------------------------------
+    # (from the first device call to the last blocking fetch)
     use_merge = strategy == "merge"
-    r_seq_packed = (
-        packing.pack_column(
-            r_seq_j[r_layout.order], r_layout, Lr, fill=np.inf
-        )
-        if r_seq_j is not None and not use_binpack and not broadcast_path
-        else None
-    )
-    if use_binpack:
-        pass
-    elif broadcast_path:
-        idx, matched = asof_ops.asof_indices_inner(l_ts_p, r_ts_p)
-        last_row_idx = np.asarray(idx)
-        per_col_idx = None  # broadcast path is row-level, nulls included
-        keep_mask_packed = np.asarray(matched)
-    elif join_engine == "chunked":
-        from tempo_tpu.ops import pallas_merge as pm
+    if not use_binpack:
+        with span("tempo.dispatch") as fetched:
+            keep_mask_packed = None
+            if broadcast_path:
+                last_row_idx, matched = asof_ops.asof_indices_inner(
+                    l_ts_p, r_ts_p)
+                per_col_idx = None  # row-level, nulls included
+                keep_mask_packed = np.asarray(matched)
+            elif join_engine == "chunked":
+                from tempo_tpu.ops import pallas_merge as pm
 
-        last_row_idx, per_col_idx = pm.asof_merge_indices_chunked(
-            l_ts_p, r_ts_p, r_valids, r_seq=r_seq_packed,
-            max_lookback=int(maxLookback or 0),
-            interpret=interp_chunked,
-        )
-        last_row_idx = np.asarray(last_row_idx)
-        per_col_idx = np.asarray(per_col_idx)
-        keep_mask_packed = None
-    elif use_merge:
-        last_row_idx, per_col_idx = asof_ops.asof_indices_merge(
-            l_ts_p, None, r_ts_p, r_seq_packed, r_valids,
-            n_cols=len(right_value_cols), max_lookback=int(maxLookback),
-        )
-        last_row_idx = np.asarray(last_row_idx)
-        per_col_idx = np.asarray(per_col_idx)
-        keep_mask_packed = None
-    else:
-        last_row_idx, per_col_idx = asof_ops.asof_indices_searchsorted(
-            l_ts_p, r_ts_p, r_valids, n_cols=len(right_value_cols)
-        )
-        last_row_idx = np.asarray(last_row_idx)
-        per_col_idx = np.asarray(per_col_idx)
-        keep_mask_packed = None
+                last_row_idx, per_col_idx = pm.asof_merge_indices_chunked(
+                    l_ts_p, r_ts_p, r_valids, r_seq=r_seq_packed,
+                    max_lookback=int(maxLookback or 0),
+                    interpret=interp_chunked,
+                )
+            elif use_merge:
+                last_row_idx, per_col_idx = asof_ops.asof_indices_merge(
+                    l_ts_p, None, r_ts_p, r_seq_packed, r_valids,
+                    n_cols=len(right_value_cols),
+                    max_lookback=int(maxLookback),
+                )
+            else:
+                last_row_idx, per_col_idx = asof_ops.asof_indices_searchsorted(
+                    l_ts_p, r_ts_p, r_valids, n_cols=len(right_value_cols)
+                )
+            last_row_idx = np.asarray(last_row_idx)
+            if per_col_idx is not None:
+                per_col_idx = np.asarray(per_col_idx)
+            fetched.rows = sum(a.size for a in (
+                last_row_idx, per_col_idx, keep_mask_packed)
+                if a is not None)
 
     # --- flatten back to left row coordinates --------------------------
-    pos = np.arange(l_layout.n_rows) - l_layout.starts[l_layout.key_ids]
+    n_left = l_layout.n_rows
+    with span("tempo.unpack", rows=n_left):
+        pos = np.arange(n_left) - l_layout.starts[l_layout.key_ids]
     k_ids = l_layout.key_ids
 
     if use_binpack:
@@ -573,53 +580,56 @@ def asof_join(
             return flat, ok
 
     out = {}
-    left_sorted = left.df.iloc[l_layout.order].reset_index(drop=True)
-    for c in pcols:
-        out[c] = left_sorted[c].to_numpy()
-    for c in left_value_cols:
-        out[lmap[c]] = left_sorted[c].to_numpy()
+    with span("tempo.frame", rows=n_left + len(r_sorted_take)):
+        left_sorted = left.df.iloc[l_layout.order].reset_index(drop=True)
+        for c in pcols:
+            out[c] = left_sorted[c].to_numpy()
+        for c in left_value_cols:
+            out[lmap[c]] = left_sorted[c].to_numpy()
+        r_sorted_df = right.df.iloc[r_sorted_take].reset_index(drop=True)
 
-    r_sorted_df = right.df.iloc[r_sorted_take].reset_index(drop=True)
-    for ci, c in enumerate(right_value_cols):
-        if skipNulls and not broadcast_path:
-            flat, ok = flat_right_indices(per_col_idx[ci])
-        else:
-            flat, ok = flat_right_indices(last_row_idx)
-        vals = r_sorted_df[c].to_numpy()
-        col_out = _gather(vals, flat, ok)
-        if (not skipNulls) and not broadcast_path:
-            # last right row's value, nulls included (tsdf.py:123-136)
-            col_valid = (~pd.isna(r_sorted_df[c])).to_numpy()
-            ok2 = ok & col_valid[np.where(ok, flat, 0)]
-            col_out = _gather(vals, flat, ok2)
-        out[rmap[c]] = col_out
-        if (
-            tsPartitionVal is not None
-            and not suppress_null_warning
-            and logger.isEnabledFor(logging.WARNING)
-        ):
-            if (~ok).any():
-                logger.warning(
-                    "Column " + rmap[c] + " had no values within the lookback "
-                    "window. Consider using a larger window to avoid missing "
-                    "values. If this is the first record in the data frame, "
-                    "this warning can be ignored."
-                )
+    with span("tempo.unpack", rows=n_left):
+        for ci, c in enumerate(right_value_cols):
+            if skipNulls and not broadcast_path:
+                flat, ok = flat_right_indices(per_col_idx[ci])
+            else:
+                flat, ok = flat_right_indices(last_row_idx)
+            vals = r_sorted_df[c].to_numpy()
+            col_out = _gather(vals, flat, ok)
+            if (not skipNulls) and not broadcast_path:
+                # last right row's value, nulls included (tsdf.py:123-136)
+                col_valid = (~pd.isna(r_sorted_df[c])).to_numpy()
+                ok2 = ok & col_valid[np.where(ok, flat, 0)]
+                col_out = _gather(vals, flat, ok2)
+            out[rmap[c]] = col_out
+            if (
+                tsPartitionVal is not None
+                and not suppress_null_warning
+                and logger.isEnabledFor(logging.WARNING)
+            ):
+                if (~ok).any():
+                    logger.warning(
+                        "Column " + rmap[c] + " had no values within the "
+                        "lookback window. Consider using a larger window to "
+                        "avoid missing values. If this is the first record "
+                        "in the data frame, this warning can be ignored."
+                    )
 
-    res = pd.DataFrame(out)
-    if broadcast_path:
-        # apply the inner-join filter while rows are still in packed
-        # order — keep_mask_packed is indexed by (k_ids, pos)
-        keep = keep_mask_packed[k_ids, pos]
-        res = res[keep].reset_index(drop=True)
-    if tsPartitionVal is not None or auto_bracketed:
-        # the joint (key, bracket) layout emits rows in bracket order;
-        # restore the same (key, ts) order the non-skew path produces so
-        # the two strategies are interchangeable row-for-row
-        perm = np.lexsort(
-            (l_ts_ns[l_layout.order], l_codes[l_layout.order])
-        )
-        res = res.iloc[perm].reset_index(drop=True)
+    with span("tempo.frame", rows=n_left):
+        res = pd.DataFrame(out)
+        if broadcast_path:
+            # apply the inner-join filter while rows are still in packed
+            # order — keep_mask_packed is indexed by (k_ids, pos)
+            keep = keep_mask_packed[k_ids, pos]
+            res = res[keep].reset_index(drop=True)
+        if tsPartitionVal is not None or auto_bracketed:
+            # the joint (key, bracket) layout emits rows in bracket order;
+            # restore the same (key, ts) order the non-skew path produces
+            # so the two strategies are interchangeable row-for-row
+            perm = np.lexsort(
+                (l_ts_ns[l_layout.order], l_codes[l_layout.order])
+            )
+            res = res.iloc[perm].reset_index(drop=True)
 
-    new_ts = lmap[left.ts_col]
-    return TSDF(res, ts_col=new_ts, partition_cols=pcols)
+        new_ts = lmap[left.ts_col]
+        return TSDF(res, ts_col=new_ts, partition_cols=pcols)

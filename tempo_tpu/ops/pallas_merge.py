@@ -89,6 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tempo_tpu.ops import pallas_kernels as pk
 from tempo_tpu.ops import pallas_stream as psr
+from tempo_tpu.profiling import span
 
 # left/right side marker added to the within-side position to form the
 # tie-break key: right rows (sec = pos) sort before left rows
@@ -1254,10 +1255,11 @@ def asof_merge_values_chunked(l_ts, r_ts, r_valids, r_values,
     of length — the property the single-plan kernel had and the XLA
     ladders lose.  Outputs are bit-identical to the single-plan kernel
     and the XLA oracle: fills select values, they never compute."""
-    keys, planes, plan, meta = build_chunked_planes(
-        l_ts, r_ts, r_valids, r_values, l_sid=l_sid, r_sid=r_sid,
-        l_seq=l_seq, r_seq=r_seq, skip_nulls=skip_nulls,
-        max_lookback=max_lookback, chunk_lanes=chunk_lanes)
+    with span("tempo.pack", rows=np.size(l_ts) + np.size(r_ts)):
+        keys, planes, plan, meta = build_chunked_planes(
+            l_ts, r_ts, r_valids, r_values, l_sid=l_sid, r_sid=r_sid,
+            l_seq=l_seq, r_seq=r_seq, skip_nulls=skip_nulls,
+            max_lookback=max_lookback, chunk_lanes=chunk_lanes)
     # every operand is 32-bit by construction, so the whole call can
     # run in the 32-bit scope interpret mode needs (pk.interpret_scope)
     ml = int(max_lookback or 0)
@@ -1279,12 +1281,14 @@ def chunked_outputs(out, plan, C, Ll):
     from tempo_tpu.packing import chunk_gather
 
     K = plan.l_out.shape[0]
-    outs = [chunk_gather(np.asarray(o), plan.l_out, np.nan, np.float32)
-            for o in out]
-    vals = (np.stack(outs[:C]) if C
-            else np.zeros((0, K, Ll), np.float32))
-    found = ~np.isnan(vals)
-    idx = np.where(np.isnan(outs[C]), -1, outs[C]).astype(np.int32)
+    fetched = [np.asarray(o) for o in out]
+    with span("tempo.unpack", rows=plan.l_out.size):
+        outs = [chunk_gather(o, plan.l_out, np.nan, np.float32)
+                for o in fetched]
+        vals = (np.stack(outs[:C]) if C
+                else np.zeros((0, K, Ll), np.float32))
+        found = ~np.isnan(vals)
+        idx = np.where(np.isnan(outs[C]), -1, outs[C]).astype(np.int32)
     return jnp.asarray(vals), jnp.asarray(found), jnp.asarray(idx)
 
 
@@ -1455,16 +1459,18 @@ def asof_merge_indices_chunked(l_ts, r_ts, r_valids,
     under bin-packing."""
     r_valids = np.asarray(r_valids)
     C, K, Lr = r_valids.shape
-    pos = np.ascontiguousarray(np.broadcast_to(
-        np.arange(Lr, dtype=np.float32), (K, Lr)))
-    planes = np.ascontiguousarray(np.broadcast_to(pos, (C, K, Lr)))
+    with span("tempo.pack", rows=r_valids.size):
+        pos = np.ascontiguousarray(np.broadcast_to(
+            np.arange(Lr, dtype=np.float32), (K, Lr)))
+        planes = np.ascontiguousarray(np.broadcast_to(pos, (C, K, Lr)))
     vals, found, last_idx = asof_merge_values_chunked(
         l_ts, r_ts, r_valids, planes, l_sid=l_sid, r_sid=r_sid,
         l_seq=l_seq, r_seq=r_seq, max_lookback=max_lookback,
         chunk_lanes=chunk_lanes, interpret=interpret,
     )
-    per_col = np.where(np.asarray(found), np.asarray(vals),
-                       -1).astype(np.int32)
+    found, vals = np.asarray(found), np.asarray(vals)
+    with span("tempo.unpack", rows=vals.size):
+        per_col = np.where(found, vals, -1).astype(np.int32)
     return last_idx, jnp.asarray(per_col)
 
 

@@ -26,6 +26,7 @@ import numpy as np
 import pandas as pd
 
 from tempo_tpu import native
+from tempo_tpu.profiling import span
 
 NS_PER_S = 1_000_000_000
 
@@ -80,16 +81,16 @@ def rebase_seconds(ts_sec: np.ndarray, pad_mask: Optional[np.ndarray] = None):
     """
     if ts_sec.size == 0:
         return ts_sec.astype(np.int32), True
-    first = ts_sec[:, :1]
-    span = ts_sec - first
-    if pad_mask is not None:
-        span = np.where(pad_mask, 0, span)
-    if span.max(initial=0) >= 2**31 - 2:
-        return ts_sec.astype(np.int64), False
-    out = span.astype(np.int32)
-    if pad_mask is not None:
-        out = np.where(pad_mask, np.int32(2**31 - 1), out)
-    return out, True
+    with span("tempo.pack", rows=ts_sec.size):
+        offsets = ts_sec - ts_sec[:, :1]
+        if pad_mask is not None:
+            offsets = np.where(pad_mask, 0, offsets)
+        if offsets.max(initial=0) >= 2**31 - 2:
+            return ts_sec.astype(np.int64), False
+        out = offsets.astype(np.int32)
+        if pad_mask is not None:
+            out = np.where(pad_mask, np.int32(2**31 - 1), out)
+        return out, True
 
 
 def series_to_ns(values: "pd.Series | np.ndarray") -> np.ndarray:
@@ -100,20 +101,22 @@ def series_to_ns(values: "pd.Series | np.ndarray") -> np.ndarray:
     the raw value in 'seconds' units for windowing math); floats -> seconds
     scaled to ns.
     """
-    if isinstance(values, pd.Series) and isinstance(
-        values.dtype, pd.DatetimeTZDtype
-    ):
-        # tz-aware columns canonicalise through UTC (Spark stores
-        # session-local timestamps as UTC micros the same way)
-        values = values.dt.tz_convert("UTC").dt.tz_localize(None)
-    arr = values.to_numpy() if isinstance(values, pd.Series) else np.asarray(values)
-    if np.issubdtype(arr.dtype, np.datetime64):
-        return arr.astype("datetime64[ns]").astype(np.int64)
-    if np.issubdtype(arr.dtype, np.integer):
-        return arr.astype(np.int64) * NS_PER_S
-    if np.issubdtype(arr.dtype, np.floating):
-        return np.round(arr * NS_PER_S).astype(np.int64)
-    raise TypeError(f"Unsupported timestamp dtype: {arr.dtype}")
+    with span("tempo.keys", rows=len(values)):
+        if isinstance(values, pd.Series) and isinstance(
+            values.dtype, pd.DatetimeTZDtype
+        ):
+            # tz-aware columns canonicalise through UTC (Spark stores
+            # session-local timestamps as UTC micros the same way)
+            values = values.dt.tz_convert("UTC").dt.tz_localize(None)
+        arr = (values.to_numpy() if isinstance(values, pd.Series)
+               else np.asarray(values))
+        if np.issubdtype(arr.dtype, np.datetime64):
+            return arr.astype("datetime64[ns]").astype(np.int64)
+        if np.issubdtype(arr.dtype, np.integer):
+            return arr.astype(np.int64) * NS_PER_S
+        if np.issubdtype(arr.dtype, np.floating):
+            return np.round(arr * NS_PER_S).astype(np.int64)
+        raise TypeError(f"Unsupported timestamp dtype: {arr.dtype}")
 
 
 def ns_to_original(ns: np.ndarray, like_dtype):
@@ -141,6 +144,11 @@ def encode_keys(
     Key order is order of first appearance (stable), so round-trips keep
     a deterministic layout.
     """
+    with span("tempo.keys", rows=len(df)):
+        return _factorize_keys(df, partition_cols)
+
+
+def _factorize_keys(df: pd.DataFrame, partition_cols: List[str]):
     if not partition_cols:
         key_ids = np.zeros(len(df), dtype=np.int64)
         key_frame = pd.DataFrame(index=[0])
@@ -171,10 +179,11 @@ def encode_keys_joint(
             np.zeros(len(df_right), dtype=np.int64),
             pd.DataFrame(index=[0]),
         )
-    both = pd.concat(
-        [df_left[partition_cols], df_right[partition_cols]], ignore_index=True
-    )
-    codes, key_frame = encode_keys(both, partition_cols)
+    with span("tempo.keys", rows=nl + len(df_right)):
+        both = pd.concat(
+            [df_left[partition_cols], df_right[partition_cols]],
+            ignore_index=True)
+        codes, key_frame = _factorize_keys(both, partition_cols)
     return codes[:nl], codes[nl:], key_frame
 
 
@@ -215,18 +224,20 @@ def build_flat_layout(
 ) -> FlatLayout:
     key_ids, key_frame = encode_keys(df, partition_cols)
     ts_ns = series_to_ns(df[ts_col])
-    # keep integer sequence columns exact: int64 ids above 2^53 must not
-    # round through float64 before the tie-break sort
-    seq = pd.to_numeric(df[sequence_col]).to_numpy() if sequence_col else None
-    n_series = len(key_frame)
-    order, starts = _sort_layout(key_ids, ts_ns, seq, n_series)
-    return FlatLayout(
-        key_ids=take(key_ids, order),
-        ts_ns=take(ts_ns, order),
-        order=order,
-        starts=starts,
-        key_frame=key_frame,
-    )
+    with span("tempo.layout", rows=len(key_ids)):
+        # keep integer sequence columns exact: int64 ids above 2^53 must
+        # not round through float64 before the tie-break sort
+        seq = (pd.to_numeric(df[sequence_col]).to_numpy() if sequence_col
+               else None)
+        n_series = len(key_frame)
+        order, starts = _sort_layout(key_ids, ts_ns, seq, n_series)
+        return FlatLayout(
+            key_ids=take(key_ids, order),
+            ts_ns=take(ts_ns, order),
+            order=order,
+            starts=starts,
+            key_frame=key_frame,
+        )
 
 
 def _sort_layout(
@@ -272,14 +283,15 @@ def build_layout_from_codes(
 ) -> FlatLayout:
     """Like :func:`build_flat_layout` but with externally-assigned series
     ids (joint join encodings, skew bracket composition)."""
-    order, starts = _sort_layout(key_ids, ts_ns, seq, n_series)
-    return FlatLayout(
-        key_ids=take(key_ids, order),
-        ts_ns=take(ts_ns, order),
-        order=order,
-        starts=starts,
-        key_frame=pd.DataFrame(index=range(n_series)),
-    )
+    with span("tempo.layout", rows=len(key_ids)):
+        order, starts = _sort_layout(key_ids, ts_ns, seq, n_series)
+        return FlatLayout(
+            key_ids=take(key_ids, order),
+            ts_ns=take(ts_ns, order),
+            order=order,
+            starts=starts,
+            key_frame=pd.DataFrame(index=range(n_series)),
+        )
 
 
 def pad_length(max_len: int, multiple: int = 8) -> int:
@@ -298,26 +310,30 @@ def pack_column(
     """Scatter a flat (already key/ts-sorted) column into [K, L] dense form."""
     if padded_len is None:
         padded_len = pad_length(int(layout.lengths.max(initial=0)))
-    if values.dtype != object and native.available():
-        return native.pack(values, layout.starts, int(padded_len), fill)
-    K = layout.n_series
-    out = np.full((K, padded_len), fill, dtype=values.dtype)
-    pos = np.arange(layout.n_rows, dtype=np.int64) - layout.starts[layout.key_ids]
-    out[layout.key_ids, pos] = values
-    return out
+    with span("tempo.pack", rows=layout.n_rows):
+        if values.dtype != object and native.available():
+            return native.pack(values, layout.starts, int(padded_len), fill)
+        out = np.full((layout.n_series, padded_len), fill, dtype=values.dtype)
+        pos = (np.arange(layout.n_rows, dtype=np.int64)
+               - layout.starts[layout.key_ids])
+        out[layout.key_ids, pos] = values
+        return out
 
 
 def unpack_column(packed: np.ndarray, layout: FlatLayout) -> np.ndarray:
     """Gather [K, L] padded form back into the sorted flat layout."""
-    if packed.dtype != object and native.available():
-        return native.unpack(packed, layout.starts)
-    pos = np.arange(layout.n_rows, dtype=np.int64) - layout.starts[layout.key_ids]
-    return packed[layout.key_ids, pos]
+    with span("tempo.unpack", rows=layout.n_rows):
+        if packed.dtype != object and native.available():
+            return native.unpack(packed, layout.starts)
+        pos = (np.arange(layout.n_rows, dtype=np.int64)
+               - layout.starts[layout.key_ids])
+        return packed[layout.key_ids, pos]
 
 
 def row_mask(layout: FlatLayout, padded_len: int) -> np.ndarray:
     """Boolean [K, L] mask of real (non-padding) rows."""
-    return np.arange(padded_len)[None, :] < layout.lengths[:, None]
+    with span("tempo.pack", rows=layout.n_rows):
+        return np.arange(padded_len)[None, :] < layout.lengths[:, None]
 
 
 def layout_rowbounds(layout: "FlatLayout", window_secs: float):
@@ -333,27 +349,28 @@ def layout_rowbounds(layout: "FlatLayout", window_secs: float):
     cache = layout.__dict__.setdefault("_rowbound_cache", {})
     key = float(window_secs)
     if key not in cache:
-        secs = layout.ts_ns // NS_PER_S
-        w = np.int64(window_secs)
-        behind = 0
-        ahead = 0
-        span_i32 = True
-        for k in range(layout.n_series):
-            s = secs[layout.starts[k]: layout.starts[k + 1]]
-            if len(s) == 0:
-                continue
-            idx = np.arange(len(s))
-            behind = max(
-                behind,
-                int((idx - np.searchsorted(s, s - w, side="left")).max()),
-            )
-            ahead = max(
-                ahead,
-                int((np.searchsorted(s, s, side="right") - 1 - idx).max()),
-            )
-            if int(s[-1] - s[0]) + int(w) >= 2**31 - 2:
-                span_i32 = False
-        cache[key] = (behind, ahead) if span_i32 else None
+        with span("tempo.pack", rows=layout.n_rows):
+            secs = layout.ts_ns // NS_PER_S
+            w = np.int64(window_secs)
+            behind = 0
+            ahead = 0
+            span_i32 = True
+            for k in range(layout.n_series):
+                s = secs[layout.starts[k]: layout.starts[k + 1]]
+                if len(s) == 0:
+                    continue
+                idx = np.arange(len(s))
+                behind = max(
+                    behind,
+                    int((idx - np.searchsorted(s, s - w, side="left")).max()),
+                )
+                ahead = max(
+                    ahead,
+                    int((np.searchsorted(s, s, side="right") - 1 - idx).max()),
+                )
+                if int(s[-1] - s[0]) + int(w) >= 2**31 - 2:
+                    span_i32 = False
+            cache[key] = (behind, ahead) if span_i32 else None
     return cache[key]
 
 

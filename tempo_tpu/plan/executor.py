@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import logging
 import os
-import time
 from typing import Dict, List, Optional
 
 from tempo_tpu.plan import cache, hints, ir, optimizer
@@ -47,10 +46,8 @@ def execute(root: ir.Node):
                      plan_ckpt.fingerprint())
 
     def build():
-        t0 = time.perf_counter()
         with cost.pinned(snap):
             exe = Executable(optimizer.optimize(root))
-        exe.build_seconds = time.perf_counter() - t0
         # run() binds the caller's payloads positionally, so the
         # build-time frames on the optimized copy are dead weight —
         # drop them or the process-global cache pins up to max_size()
@@ -72,8 +69,6 @@ class Executable:
 
     def __init__(self, plan: ir.Node):
         self.plan = plan
-        self.build_seconds = 0.0
-        self.runs = 0
 
     def run(self, payloads: List):
         from tempo_tpu import plan as plan_mod
@@ -83,7 +78,6 @@ class Executable:
             raise ValueError(
                 f"plan expects {len(sources)} source frame(s); "
                 f"got {len(payloads)}")
-        self.runs += 1
         env: Dict[int, object] = {}
         spec = plan_ckpt.active()
         # barrier nodes only exist in plans optimized under an active

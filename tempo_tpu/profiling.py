@@ -10,6 +10,9 @@ The TPU-native equivalents:
 
 * :func:`trace` — a context manager around ``jax.profiler`` producing
   TensorBoard-loadable traces (the Spark-UI analog).
+* :class:`span` — a named host span inside an operator: written to the
+  profiler's trace and kept in a bounded in-memory ring
+  (:func:`recent_spans`), so the host's time splits by phase.
 * :func:`compiled_cost` — XLA's own post-compilation cost/memory
   analysis for a jitted function, the compiler-backed version of the
   ``sizeInBytes`` scrape.
@@ -21,9 +24,13 @@ The TPU-native equivalents:
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import contextvars
+import itertools
 import logging
-from typing import Dict, Optional
+import time
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import pandas as pd
@@ -32,6 +39,21 @@ logger = logging.getLogger(__name__)
 
 # tsdf.py:491 uses 30MiB as the broadcast cutoff
 BROADCAST_BYTES_THRESHOLD = 30 * 1024 * 1024
+
+#: spans the ring keeps; older ones are pushed out and counted
+SPAN_RING = 1 << 17
+
+SpanRecord = collections.namedtuple(
+    "SpanRecord", "id parent root name start_ns end_ns rows")
+
+_ring: collections.deque = collections.deque(maxlen=SPAN_RING)
+_ring_slots = itertools.count()
+_span_ids = itertools.count(1)
+#: (id, root) of the innermost open span of this thread or task
+_open_span: contextvars.ContextVar = contextvars.ContextVar(
+    "tempo_tpu_open_span", default=None)
+_Annotation = jax.profiler.TraceAnnotation
+_clock_ns = time.perf_counter_ns
 
 
 @contextlib.contextmanager
@@ -50,10 +72,64 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named sub-span inside a :func:`trace` block (shows up on the TPU
-    timeline): ``with profiling.annotate("asof-kernel"): ...``"""
-    return jax.profiler.TraceAnnotation(name)
+class span:
+    """A named host span around one phase of an operator::
+
+        with profiling.span("tempo.layout", rows=n):
+            ...
+
+    Each span is written to the profiler as a ``TraceAnnotation`` (so it
+    shows on the device trace's clock inside a :func:`trace` block) and
+    kept as a :data:`SpanRecord` in a bounded in-memory ring, even when
+    its body raises.  A record holds the span's ``id``, its enclosing
+    span's (``parent``, None at the outermost), the outermost span's
+    (``root``: one operator call and everything under it), ``name``,
+    ``start_ns``/``end_ns`` on ``time.perf_counter_ns`` and ``rows``,
+    the amount of work the phase did (settable inside the block).
+    Nesting follows the thread or task that opens the span.  Recording
+    is always on; :func:`recent_spans` reads the ring."""
+
+    __slots__ = ("name", "rows", "_id", "_outer", "_token", "_note", "_t0")
+
+    def __init__(self, name: str, rows: int = 0):
+        self.name = name
+        self.rows = rows
+
+    def __enter__(self) -> "span":
+        sid = self._id = next(_span_ids)
+        # (parent, root) of this span
+        outer = self._outer = _open_span.get() or (None, sid)
+        self._token = _open_span.set((sid, outer[1]))
+        # an annotation costs more than the rest of the span: make one
+        # only while the profiler records
+        if _Annotation.is_enabled():
+            note = self._note = _Annotation(self.name)
+            note.__enter__()
+        else:
+            self._note = None
+        self._t0 = _clock_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = _clock_ns()
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        _open_span.reset(self._token)
+        # the ring's slot number first, so the ring itself says how
+        # many spans ended before the oldest it holds
+        parent, root = self._outer
+        _ring.append((next(_ring_slots), self._id, parent, root, self.name,
+                      self._t0, t1, self.rows))
+        return False
+
+
+def recent_spans() -> Tuple[List[SpanRecord], int]:
+    """``(spans, dropped)``: the spans the ring holds, in the order they
+    ended, and how many older ones it has pushed out since the process
+    started."""
+    held = list(_ring)
+    dropped = min(r[0] for r in held) if held else 0
+    return [SpanRecord._make(r[1:]) for r in held], dropped
 
 
 def compiled_cost(fn, *args, **kwargs) -> Dict[str, Optional[float]]:

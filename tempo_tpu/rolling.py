@@ -21,6 +21,7 @@ import pandas as pd
 from tempo_tpu import packing
 from tempo_tpu.freq import freq_to_seconds, UNIT_SECONDS
 from tempo_tpu.ops import rolling as rk
+from tempo_tpu.profiling import span
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +29,13 @@ import jax.numpy as jnp
 
 def _packed_metric_stack(tsdf, cols: List[str]):
     """Stack metric columns into [C, K, L] values + valids."""
-    vals, valids = [], []
-    for c in cols:
-        v, m = tsdf.packed_numeric(c)
-        vals.append(v)
-        valids.append(m)
-    return np.stack(vals), np.stack(valids)
+    with span("tempo.pack", rows=tsdf.layout.n_rows * len(cols)):
+        vals, valids = [], []
+        for c in cols:
+            v, m = tsdf.packed_numeric(c)
+            vals.append(v)
+            valids.append(m)
+        return np.stack(vals), np.stack(valids)
 
 
 def plan_range_engine(tsdf, cols: List[str], rangeBackWindowSecs: int):
@@ -57,16 +59,17 @@ def plan_range_engine(tsdf, cols: List[str], rangeBackWindowSecs: int):
     # so rebase to per-series int32 seconds when spans allow (range
     # windows only ever compare within a series, so a per-series
     # origin is safe)
-    ts_long = tsdf.packed_ts() // packing.NS_PER_S
-    ts_long, _ = packing.rebase_seconds(ts_long, ~tsdf.packed_mask())
-    # a window larger than any rebased span is equivalent to
-    # 'unbounded preceding'; clamp so huge windows cannot overflow the
-    # int32 path
-    w = min(int(rangeBackWindowSecs),
-            int(np.iinfo(ts_long.dtype).max) // 2)
-    rb = (packing.layout_rowbounds(layout, w)
-          if ts_long.dtype == np.int32 and sm.use_sort_kernels()
-          else None)
+    with span("tempo.pack", rows=layout.n_rows):
+        ts_long = tsdf.packed_ts() // packing.NS_PER_S
+        ts_long, _ = packing.rebase_seconds(ts_long, ~tsdf.packed_mask())
+        # a window larger than any rebased span is equivalent to
+        # 'unbounded preceding'; clamp so huge windows cannot overflow
+        # the int32 path
+        w = min(int(rangeBackWindowSecs),
+                int(np.iinfo(ts_long.dtype).max) // 2)
+        rb = (packing.layout_rowbounds(layout, w)
+              if ts_long.dtype == np.int32 and sm.use_sort_kernels()
+              else None)
     K, L = ts_long.shape
     f32 = np.dtype(packing.compute_dtype()) == np.float32
     # feasibility and the HBM budget are per COLUMN since the packed
@@ -89,7 +92,8 @@ def with_range_stats(tsdf, type: str = "range", colsToSummarize=None,
 
     cols = colsToSummarize or tsdf.summarizable_columns()
     layout = tsdf.layout
-    out = tsdf.df.iloc[layout.order].reset_index(drop=True)
+    with span("tempo.frame", rows=layout.n_rows):
+        out = tsdf.df.iloc[layout.order].reset_index(drop=True)
     if not cols:
         # reference adds zero stat columns in this case (tsdf.py:691-721)
         return TSDF(out, tsdf.ts_col, tsdf.partitionCols, tsdf.sequence_col or None)
@@ -120,55 +124,57 @@ def with_range_stats(tsdf, type: str = "range", colsToSummarize=None,
 
     engine, rb, ts_long, w = plan_range_engine(tsdf, cols,
                                                rangeBackWindowSecs)
-    if engine == "shifted":
-        # multi-column payload packing: the [C, K, L] metric stack
-        # shares ONE [K, L] key plane — the packed kernels read it once
-        # per pack where the seed path materialised a C-wide broadcast
-        # copy of the timestamps (`tile`) and streamed it per column
-        stats = dict(sm.range_stats_shifted_packed(
-            jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
-            jnp.asarray(np.int32(w)),
-            max_behind=int(rb[0]), max_ahead=int(rb[1]),
-        ))
-        # the truncation audit rides the SAME stacked fetch as the
-        # stats below (one device->host round trip, not two)
-    elif engine == "stream":
-        stats = dict(rk.range_stats_streaming_packed(
-            jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
-            jnp.asarray(np.int32(w)),
-            max_behind=int(rb[0]), max_ahead=int(rb[1]),
-        ))
-    else:
-        ts_arr = jnp.asarray(ts_long)
-        start, end = rk.range_window_bounds(
-            ts_arr, rk.range_window_width(ts_arr, w)
-        )
-        # static row bound for the min/max sparse tables: a 10s window
-        # over 1Hz data needs 4 levels, not log2(L); bucket to a power
-        # of two so distinct datasets reuse the compiled kernel.
-        # Padded slots all share the clamped sentinel timestamp, so
-        # their windows span the whole pad run — mask them out of the
-        # bound or ragged series inflate it toward L
-        real = jnp.asarray(tsdf.packed_mask())
-        max_w = max(1, int(jax.device_get(
-            jnp.max(jnp.where(real, end - start, 0)))))
-        max_w = 1 << (max_w - 1).bit_length()
-        stats = rk.windowed_stats(
-            flat(vals), flat(valids), tile(start), tile(end),
-            max_window=max_w
-        )
-    # one stacked device->host transfer instead of one per stat: each
-    # transfer pays a fixed latency.  The shifted path's
-    # truncation-audit scalar piggybacks as one extra element on the
-    # same flattened buffer.
-    clip = stats.pop("clipped", None)
-    names = sorted(stats)
-    planes = jnp.stack([stats[k] for k in names]).reshape(-1)
-    if clip is not None:
-        planes = jnp.concatenate(
-            [planes, jnp.sum(clip).reshape(1).astype(planes.dtype)]
-        )
-    buf = np.asarray(planes)
+    with span("tempo.dispatch") as fetched:
+        if engine == "shifted":
+            # multi-column payload packing: the [C, K, L] metric stack
+            # shares ONE [K, L] key plane — the packed kernels read it once
+            # per pack where the seed path materialised a C-wide broadcast
+            # copy of the timestamps (`tile`) and streamed it per column
+            stats = dict(sm.range_stats_shifted_packed(
+                jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
+                jnp.asarray(np.int32(w)),
+                max_behind=int(rb[0]), max_ahead=int(rb[1]),
+            ))
+            # the truncation audit rides the SAME stacked fetch as the
+            # stats below (one device->host round trip, not two)
+        elif engine == "stream":
+            stats = dict(rk.range_stats_streaming_packed(
+                jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
+                jnp.asarray(np.int32(w)),
+                max_behind=int(rb[0]), max_ahead=int(rb[1]),
+            ))
+        else:
+            ts_arr = jnp.asarray(ts_long)
+            start, end = rk.range_window_bounds(
+                ts_arr, rk.range_window_width(ts_arr, w)
+            )
+            # static row bound for the min/max sparse tables: a 10s window
+            # over 1Hz data needs 4 levels, not log2(L); bucket to a power
+            # of two so distinct datasets reuse the compiled kernel.
+            # Padded slots all share the clamped sentinel timestamp, so
+            # their windows span the whole pad run — mask them out of the
+            # bound or ragged series inflate it toward L
+            real = jnp.asarray(tsdf.packed_mask())
+            max_w = max(1, int(jax.device_get(
+                jnp.max(jnp.where(real, end - start, 0)))))
+            max_w = 1 << (max_w - 1).bit_length()
+            stats = rk.windowed_stats(
+                flat(vals), flat(valids), tile(start), tile(end),
+                max_window=max_w
+            )
+        # one stacked device->host transfer instead of one per stat: each
+        # transfer pays a fixed latency.  The shifted path's
+        # truncation-audit scalar piggybacks as one extra element on the
+        # same flattened buffer.
+        clip = stats.pop("clipped", None)
+        names = sorted(stats)
+        planes = jnp.stack([stats[k] for k in names]).reshape(-1)
+        if clip is not None:
+            planes = jnp.concatenate(
+                [planes, jnp.sum(clip).reshape(1).astype(planes.dtype)]
+            )
+        buf = np.asarray(planes)
+        fetched.rows = buf.size
     if clip is not None:
         clipped_total = float(buf[-1])
         buf = buf[:-1]
@@ -182,15 +188,19 @@ def with_range_stats(tsdf, type: str = "range", colsToSummarize=None,
     stacked = buf.reshape(len(names), C, K, L)
     stats = {k: stacked[i] for i, k in enumerate(names)}
 
-    for ci, c in enumerate(cols):
-        for stat in packing.RANGE_STATS:
-            flat = packing.unpack_column(stats[stat][ci], layout)
-            if stat == "count":
-                out[f"{stat}_{c}"] = flat.astype(np.int64)
-            else:
+    new_cols = {}
+    with span("tempo.unpack", rows=layout.n_rows * len(cols)):
+        for ci, c in enumerate(cols):
+            for stat in packing.RANGE_STATS:
+                flat = packing.unpack_column(stats[stat][ci], layout)
                 # Spark emits DoubleType stats regardless of input width
-                out[f"{stat}_{c}"] = flat.astype(np.float64)
-    return TSDF(out, tsdf.ts_col, tsdf.partitionCols, tsdf.sequence_col or None)
+                new_cols[f"{stat}_{c}"] = flat.astype(
+                    np.int64 if stat == "count" else np.float64)
+    with span("tempo.frame", rows=layout.n_rows):
+        for name, col in new_cols.items():
+            out[name] = col
+        return TSDF(out, tsdf.ts_col, tsdf.partitionCols,
+                    tsdf.sequence_col or None)
 
 
 def _bucket_ns(ts_ns: np.ndarray, freq_sec: int) -> np.ndarray:
@@ -258,17 +268,25 @@ def ema(tsdf, colName: str, window: int = 30, exp_factor: float = 0.2,
     layout = tsdf.layout
     v, m = tsdf.packed_numeric(colName)
     n_taps = int(window) + (1 if inclusive_window else 0)
-    if exact:
-        from tempo_tpu.ops import pallas_kernels as pk
+    with span("tempo.dispatch") as fetched:
+        if exact:
+            from tempo_tpu.ops import pallas_kernels as pk
 
-        y = pk.ema_scan(jnp.asarray(v), jnp.asarray(m), exp_factor)
-    else:
-        y = rk.ema_compat(jnp.asarray(v), jnp.asarray(m), n_taps, float(exp_factor))
-    out = tsdf.df.iloc[layout.order].reset_index(drop=True)
-    out["EMA_" + colName] = packing.unpack_column(
-        np.asarray(y), layout
-    ).astype(np.float64)
-    return TSDF(out, tsdf.ts_col, tsdf.partitionCols, tsdf.sequence_col or None)
+            y = pk.ema_scan(jnp.asarray(v), jnp.asarray(m), exp_factor)
+        else:
+            y = rk.ema_compat(jnp.asarray(v), jnp.asarray(m), n_taps,
+                              float(exp_factor))
+        # the frame is built while the device runs
+        with span("tempo.frame", rows=layout.n_rows):
+            out = tsdf.df.iloc[layout.order].reset_index(drop=True)
+        y = np.asarray(y)
+        fetched.rows = y.size
+    with span("tempo.unpack", rows=layout.n_rows):
+        ema_col = packing.unpack_column(y, layout).astype(np.float64)
+    with span("tempo.frame", rows=layout.n_rows):
+        out["EMA_" + colName] = ema_col
+        return TSDF(out, tsdf.ts_col, tsdf.partitionCols,
+                    tsdf.sequence_col or None)
 
 
 _VWAP_TRUNC = {"m": "min", "H": "hr", "D": "day"}

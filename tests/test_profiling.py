@@ -63,8 +63,112 @@ class TestCostProbe:
         assert out["flops"] is None or out["flops"] > 0
 
     def test_trace_context(self, tmp_path):
+        from jax.profiler import ProfileData
+
         with profiling.trace(str(tmp_path)):
-            with profiling.annotate("unit-test-span"):
+            with profiling.span("unit-test-span"):
                 jnp.ones((8,)).sum().block_until_ready()
-        # a trace directory must have been produced
-        assert any(tmp_path.iterdir())
+        # the span is a host event of the trace
+        found = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+        assert found
+        names = {ev.name
+                 for plane in ProfileData.from_file(str(found[0])).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert "unit-test-span" in names
+
+
+def _mine(name):
+    """This test's records of span ``name``, oldest first."""
+    spans, _ = profiling.recent_spans()
+    return [s for s in spans if s.name == name]
+
+
+class TestSpanRecorder:
+    def test_nested_spans_carry_parent_and_root(self):
+        with profiling.span("t.outer"):
+            with profiling.span("t.middle"):
+                with profiling.span("t.inner"):
+                    pass
+            with profiling.span("t.sibling"):
+                pass
+        outer, = _mine("t.outer")[-1:]
+        middle, = _mine("t.middle")[-1:]
+        inner, = _mine("t.inner")[-1:]
+        sibling, = _mine("t.sibling")[-1:]
+        assert outer.parent is None and outer.root == outer.id
+        assert middle.parent == outer.id and middle.root == outer.id
+        assert inner.parent == middle.id and inner.root == outer.id
+        assert sibling.parent == outer.id and sibling.root == outer.id
+        assert outer.start_ns <= middle.start_ns <= inner.start_ns
+        assert inner.end_ns <= middle.end_ns <= sibling.start_ns
+        assert sibling.end_ns <= outer.end_ns
+        # the next outermost span starts a new root
+        with profiling.span("t.outer"):
+            pass
+        again = _mine("t.outer")[-1]
+        assert again.parent is None and again.root == again.id != outer.id
+
+    def test_rows_given_or_set_inside(self):
+        with profiling.span("t.rows", rows=7):
+            pass
+        with profiling.span("t.rows") as sp:
+            sp.rows = 11
+        assert [s.rows for s in _mine("t.rows")[-2:]] == [7, 11]
+
+    def test_a_span_whose_body_raises_is_recorded(self):
+        with pytest.raises(ZeroDivisionError):
+            with profiling.span("t.raises", rows=3):
+                1 / 0
+        rec = _mine("t.raises")[-1]
+        assert rec.rows == 3 and rec.end_ns >= rec.start_ns
+        # the open span was closed: the next one is outermost again
+        with profiling.span("t.after"):
+            pass
+        assert _mine("t.after")[-1].parent is None
+
+    def test_ring_is_bounded_and_counts_what_it_dropped(self, monkeypatch):
+        import collections
+        import itertools
+
+        monkeypatch.setattr(profiling, "_ring",
+                            collections.deque(maxlen=4))
+        monkeypatch.setattr(profiling, "_ring_slots", itertools.count())
+        assert profiling.recent_spans() == ([], 0)
+        for i in range(3):
+            with profiling.span("t.ring", rows=i):
+                pass
+        spans, dropped = profiling.recent_spans()
+        assert [s.rows for s in spans] == [0, 1, 2] and dropped == 0
+        for i in range(3, 10):
+            with profiling.span("t.ring", rows=i):
+                pass
+        spans, dropped = profiling.recent_spans()
+        assert [s.rows for s in spans] == [6, 7, 8, 9] and dropped == 6
+
+    def test_spans_on_two_threads_do_not_parent_each_other(self):
+        import threading
+
+        opened, closed = threading.Event(), threading.Event()
+
+        def other():
+            opened.wait(10)
+            with profiling.span("t.thread_b"):
+                with profiling.span("t.thread_b_inner"):
+                    pass
+            closed.set()
+
+        worker = threading.Thread(target=other)
+        worker.start()
+        with profiling.span("t.thread_a"):
+            opened.set()
+            closed.wait(10)
+        worker.join(10)
+        a = _mine("t.thread_a")[-1]
+        b = _mine("t.thread_b")[-1]
+        inner = _mine("t.thread_b_inner")[-1]
+        # b ran entirely inside a's time, on another thread
+        assert a.start_ns < b.start_ns and b.end_ns < a.end_ns
+        assert b.parent is None and b.root == b.id
+        assert inner.parent == b.id and inner.root == b.id
+        assert a.parent is None and a.root == a.id
