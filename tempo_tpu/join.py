@@ -206,7 +206,12 @@ def _binpacked_indices(right, l_layout, r_layout, r_sorted_take,
     (ts, seq) per series so the segmented merge precondition holds
     (round-6 lift of the seq x bin-pack exclusion).  ``engine``:
     'chunked' runs the lane-chunked streaming VMEM kernel (oversize
-    lane-row widths past the single-plan merge)."""
+    lane-row widths past the single-plan merge).
+
+    Returns ``(take, planes, bp)``: ``planes`` the host index planes of
+    the channels the join reads (one per column, or the last-row channel
+    alone), holding right positions within the lane row; ``take`` every
+    left row's flat position in them."""
     import jax.numpy as jnp
 
     from tempo_tpu.ops import pallas_merge as pm
@@ -243,16 +248,39 @@ def _binpacked_indices(right, l_layout, r_layout, r_sorted_take,
                if r_seq_sorted is not None else None)
 
     if engine == "chunked":
-        last_idx, per_col = pm.asof_merge_indices_chunked(
-            lt, rt, rv, lsid, rsid, r_seq=rsq,
-            max_lookback=int(max_lookback), interpret=interpret)
-    else:
-        last_idx, per_col = sm.asof_indices_binpacked(
-            jnp.asarray(lt), jnp.asarray(rt), jnp.asarray(rv),
-            jnp.asarray(lsid), jnp.asarray(rsid),
-            max_lookback=int(max_lookback),
-            r_seq=jnp.asarray(rsq) if rsq is not None else None)
-    return np.asarray(last_idx), np.asarray(per_col), bp
+        take, planes = pm.asof_merge_indices_chunked(
+            lt, rt, rv, dest_l, lsid, rsid, r_seq=rsq,
+            skip_nulls=bool(valid_cols), max_lookback=int(max_lookback),
+            interpret=interpret)
+        return take, planes, bp
+    last_idx, per_col = sm.asof_indices_binpacked(
+        jnp.asarray(lt), jnp.asarray(rt), jnp.asarray(rv),
+        jnp.asarray(lsid), jnp.asarray(rsid),
+        max_lookback=int(max_lookback),
+        r_seq=jnp.asarray(rsq) if rsq is not None else None)
+    planes = (list(np.asarray(per_col)) if valid_cols
+              else [np.asarray(last_idx)])
+    return dest_l, planes, bp
+
+
+def _right_starts(l_layout, r_layout, bp) -> np.ndarray:
+    """Per left row (layout order), the flat right row its channel
+    positions count from: the series' right start, less the series'
+    lane offset when bin-packed (``bp``), as positions are then within
+    the shared lane row."""
+    start = r_layout.starts[:-1] - (bp.r_off if bp is not None else 0)
+    return np.repeat(start, l_layout.lengths)
+
+
+def _right_rows(plane: np.ndarray, take: np.ndarray, r_start: np.ndarray):
+    """``(flat right row, ok)`` of every left row from one index channel:
+    one take straight into left-row order, -1 (index planes) or NaN
+    (the chunked kernel's f32 planes) marking no match."""
+    ridx = np.take(plane.reshape(-1), take)
+    ok = ridx >= 0
+    flat = np.fmax(ridx, 0).astype(np.int64)   # fmax maps NaN to 0 too
+    flat += r_start
+    return flat, ok
 
 
 def _joint_bracket_codes(l_codes, r_codes_taken, l_brackets, r_brackets):
@@ -480,9 +508,14 @@ def asof_join(
         and n_series > 1
         and _binpack_worthwhile(l_layout, r_layout)
     )
+    # the join reads the per-column channels under skipNulls, else the
+    # last-right-row channel alone; each engine hands them back as host
+    # planes with ``take``, every left row's flat position in them
+    read_cols = skipNulls and not broadcast_path
+    keep_mask_packed = None
     if use_binpack:
         with span("tempo.dispatch") as fetched:
-            last_row_idx, per_col_idx, bp = _binpacked_indices(
+            take, planes, bp = _binpacked_indices(
                 right, l_layout, r_layout, r_sorted_take,
                 right_value_cols if skipNulls else [],
                 max_lookback=int(maxLookback or 0),
@@ -490,14 +523,18 @@ def asof_join(
                               if r_seq_j is not None else None),
                 engine=join_engine, interpret=interp_chunked,
             )
-            fetched.rows = last_row_idx.size + per_col_idx.size
-        keep_mask_packed = None
+            fetched.rows = sum(p.size for p in planes)
     else:
         bp = None
 
     Ll = packing.pad_length(int(l_layout.lengths.max(initial=0)))
     Lr = packing.pad_length(int(r_layout.lengths.max(initial=0)))
     if not use_binpack:
+        with span("tempo.unpack"):
+            # each left row's flat lane in the packed [K, Ll] left side
+            take = packing.binpack_dest(
+                l_layout.starts, np.arange(n_series),
+                np.zeros(n_series, np.int64), Ll)
         with span("tempo.pack", rows=l_layout.n_rows + r_layout.n_rows):
             l_ts_p = packing.pack_column(
                 l_layout.ts_ns, l_layout, Ll, fill=packing.TS_PAD)
@@ -526,58 +563,45 @@ def asof_join(
     use_merge = strategy == "merge"
     if not use_binpack:
         with span("tempo.dispatch") as fetched:
-            keep_mask_packed = None
             if broadcast_path:
                 last_row_idx, matched = asof_ops.asof_indices_inner(
                     l_ts_p, r_ts_p)
-                per_col_idx = None  # row-level, nulls included
+                planes = [np.asarray(last_row_idx)]  # nulls included
                 keep_mask_packed = np.asarray(matched)
             elif join_engine == "chunked":
                 from tempo_tpu.ops import pallas_merge as pm
 
-                last_row_idx, per_col_idx = pm.asof_merge_indices_chunked(
-                    l_ts_p, r_ts_p, r_valids, r_seq=r_seq_packed,
+                take, planes = pm.asof_merge_indices_chunked(
+                    l_ts_p, r_ts_p, r_valids, take, r_seq=r_seq_packed,
+                    skip_nulls=skipNulls,
                     max_lookback=int(maxLookback or 0),
                     interpret=interp_chunked,
                 )
-            elif use_merge:
-                last_row_idx, per_col_idx = asof_ops.asof_indices_merge(
-                    l_ts_p, None, r_ts_p, r_seq_packed, r_valids,
-                    n_cols=len(right_value_cols),
-                    max_lookback=int(maxLookback),
-                )
             else:
-                last_row_idx, per_col_idx = asof_ops.asof_indices_searchsorted(
-                    l_ts_p, r_ts_p, r_valids, n_cols=len(right_value_cols)
-                )
-            last_row_idx = np.asarray(last_row_idx)
-            if per_col_idx is not None:
-                per_col_idx = np.asarray(per_col_idx)
-            fetched.rows = sum(a.size for a in (
-                last_row_idx, per_col_idx, keep_mask_packed)
-                if a is not None)
+                if use_merge:
+                    last_row_idx, per_col_idx = asof_ops.asof_indices_merge(
+                        l_ts_p, None, r_ts_p, r_seq_packed, r_valids,
+                        n_cols=len(right_value_cols),
+                        max_lookback=int(maxLookback),
+                    )
+                else:
+                    last_row_idx, per_col_idx = \
+                        asof_ops.asof_indices_searchsorted(
+                            l_ts_p, r_ts_p, r_valids,
+                            n_cols=len(right_value_cols))
+                planes = (list(np.asarray(per_col_idx)) if read_cols
+                          else [np.asarray(last_row_idx)])
+            fetched.rows = sum(a.size for a in (*planes, keep_mask_packed)
+                               if a is not None)
 
     # --- flatten back to left row coordinates --------------------------
     n_left = l_layout.n_rows
-    with span("tempo.unpack", rows=n_left):
-        pos = np.arange(n_left) - l_layout.starts[l_layout.key_ids]
-    k_ids = l_layout.key_ids
-
-    if use_binpack:
-        def flat_right_indices(packed_idx):
-            # bin-packed planes are indexed by (lane row, lane offset);
-            # returned positions are within-lane-row -> subtract the
-            # series' right-side offset for the per-series index
-            ridx = packed_idx[bp.row[k_ids], bp.l_off[k_ids] + pos]
-            ok = ridx >= 0
-            within = np.where(ok, ridx - bp.r_off[k_ids], 0)
-            return r_layout.starts[k_ids] + within, ok
-    else:
-        def flat_right_indices(packed_idx):
-            ridx = packed_idx[k_ids, pos]
-            ok = ridx >= 0
-            flat = r_layout.starts[k_ids] + np.where(ok, ridx, 0)
-            return flat, ok
+    with span("tempo.unpack"):
+        r_start = _right_starts(l_layout, r_layout, bp)
+    taken = []
+    for plane in planes:
+        with span("tempo.unpack", rows=n_left):
+            taken.append(_right_rows(plane, take, r_start))
 
     out = {}
     with span("tempo.frame", rows=n_left + len(r_sorted_take)):
@@ -588,12 +612,9 @@ def asof_join(
             out[lmap[c]] = left_sorted[c].to_numpy()
         r_sorted_df = right.df.iloc[r_sorted_take].reset_index(drop=True)
 
-    with span("tempo.unpack", rows=n_left):
+    with span("tempo.unpack"):
         for ci, c in enumerate(right_value_cols):
-            if skipNulls and not broadcast_path:
-                flat, ok = flat_right_indices(per_col_idx[ci])
-            else:
-                flat, ok = flat_right_indices(last_row_idx)
+            flat, ok = taken[ci] if read_cols else taken[0]
             vals = r_sorted_df[c].to_numpy()
             col_out = _gather(vals, flat, ok)
             if (not skipNulls) and not broadcast_path:
@@ -618,9 +639,9 @@ def asof_join(
     with span("tempo.frame", rows=n_left):
         res = pd.DataFrame(out)
         if broadcast_path:
-            # apply the inner-join filter while rows are still in packed
-            # order — keep_mask_packed is indexed by (k_ids, pos)
-            keep = keep_mask_packed[k_ids, pos]
+            # the inner-join filter, taken into left-row order like the
+            # index channels
+            keep = np.take(keep_mask_packed.reshape(-1), take)
             res = res[keep].reset_index(drop=True)
         if tsPartitionVal is not None or auto_bracketed:
             # the joint (key, bracket) layout emits rows in bracket order;

@@ -1255,6 +1255,17 @@ def asof_merge_values_chunked(l_ts, r_ts, r_valids, r_values,
     of length — the property the single-plan kernel had and the XLA
     ladders lose.  Outputs are bit-identical to the single-plan kernel
     and the XLA oracle: fills select values, they never compute."""
+    out, plan, meta = _chunked_run(
+        l_ts, r_ts, r_valids, r_values, l_sid, r_sid, l_seq, r_seq,
+        skip_nulls, max_lookback, chunk_lanes, interpret)
+    return chunked_outputs(out, plan, meta["C"], int(np.asarray(l_ts).shape[1]))
+
+
+def _chunked_run(l_ts, r_ts, r_valids, r_values, l_sid, r_sid, l_seq,
+                 r_seq, skip_nulls, max_lookback, chunk_lanes, interpret):
+    """Plan, pack and launch the chunked kernel: ``(out, plan, meta)``,
+    ``out`` the device outputs ``[K, n_chunks * S]`` f32 — the C value
+    channels, then the last-right-row channel."""
     with span("tempo.pack", rows=np.size(l_ts) + np.size(r_ts)):
         keys, planes, plan, meta = build_chunked_planes(
             l_ts, r_ts, r_valids, r_values, l_sid=l_sid, r_sid=r_sid,
@@ -1273,7 +1284,7 @@ def asof_merge_values_chunked(l_ts, r_ts, r_valids, r_values,
             windowed=ml > 0, ml=float(ml), depth=psr.dma_buffers(),
             interpret=interpret,
         )
-    return chunked_outputs(out, plan, meta["C"], int(np.asarray(l_ts).shape[1]))
+    return out, plan, meta
 
 
 def chunked_outputs(out, plan, C, Ll):
@@ -1447,31 +1458,40 @@ def asof_carry_init(n_cols: int, n_series: int):
     }
 
 
-def asof_merge_indices_chunked(l_ts, r_ts, r_valids,
+def asof_merge_indices_chunked(l_ts, r_ts, r_valids, l_lane,
                                l_sid=None, r_sid=None,
                                l_seq=None, r_seq=None,
+                               skip_nulls: bool = True,
                                max_lookback: int = 0,
                                chunk_lanes=None,
                                interpret: bool = False):
     """Index-returning chunked sibling (position-encoded payloads, like
-    :func:`asof_merge_indices_pallas`): ``(last_row_idx [K, Ll],
-    per_col_idx [C, K, Ll])``, -1 for none; within-lane-row positions
-    under bin-packing."""
+    :func:`asof_merge_indices_pallas`) for the host join: ``(take,
+    planes)``, host arrays only.
+
+    ``planes`` are the kernel's ``[K, n_chunks * S]`` f32 outputs of the
+    channels the join reads — the C per-column last-valid channels
+    under ``skip_nulls``, else the last-right-row channel alone (the
+    fill is per-column either way) — holding right positions within the
+    lane row, NaN for none.  ``take`` is the flat position in them of
+    every left row, ``l_lane`` being each row's flat lane in the packed
+    ``[K, Ll]`` left side: one ``np.take`` per plane lands a channel in
+    left-row order."""
+    from tempo_tpu.packing import chunk_take_index
+
     r_valids = np.asarray(r_valids)
     C, K, Lr = r_valids.shape
     with span("tempo.pack", rows=r_valids.size):
         pos = np.ascontiguousarray(np.broadcast_to(
             np.arange(Lr, dtype=np.float32), (K, Lr)))
         planes = np.ascontiguousarray(np.broadcast_to(pos, (C, K, Lr)))
-    vals, found, last_idx = asof_merge_values_chunked(
-        l_ts, r_ts, r_valids, planes, l_sid=l_sid, r_sid=r_sid,
-        l_seq=l_seq, r_seq=r_seq, max_lookback=max_lookback,
-        chunk_lanes=chunk_lanes, interpret=interpret,
-    )
-    found, vals = np.asarray(found), np.asarray(vals)
-    with span("tempo.unpack", rows=vals.size):
-        per_col = np.where(found, vals, -1).astype(np.int32)
-    return last_idx, jnp.asarray(per_col)
+    out, plan, _ = _chunked_run(
+        l_ts, r_ts, r_valids, planes, l_sid, r_sid, l_seq, r_seq, True,
+        max_lookback, chunk_lanes, interpret)
+    fetched = [np.asarray(o) for o in (out[:C] if skip_nulls else out[C:])]
+    with span("tempo.unpack"):
+        take = chunk_take_index(plan, l_lane)
+    return take, fetched
 
 
 def chunked_join_available(est_lanes: int, n_cols: int, r_seq=None,

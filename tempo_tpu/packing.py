@@ -483,12 +483,11 @@ def binpack_dest(starts: np.ndarray, row: np.ndarray, off: np.ndarray,
     """Flat destination slot of every row of a flat per-series-sorted
     column in the bin-packed [n_rows, width] grid — computed once and
     reused for every plane (one vectorised scatter per plane instead of
-    a Python per-series loop)."""
-    n = int(starts[-1])
-    key_ids = np.repeat(np.arange(len(row), dtype=np.int64),
-                        np.diff(starts))
-    pos = np.arange(n, dtype=np.int64) - starts[key_ids]
-    return row[key_ids].astype(np.int64) * width + off[key_ids] + pos
+    a Python per-series loop).  The dense [K, width] layout is the
+    packing with series ``s`` alone in row ``s`` from lane 0."""
+    base = np.asarray(row, np.int64) * width + off - starts[:-1]
+    return (np.arange(int(starts[-1]), dtype=np.int64)
+            + np.repeat(base, np.diff(starts)))
 
 
 def binpack_scatter(flat: np.ndarray, dest: np.ndarray, n_rows: int,
@@ -680,6 +679,16 @@ def chunk_scatter(src: np.ndarray, dest: np.ndarray, width: int, fill,
     m = dest >= 0
     out[rows[m], dest[m]] = src[m]
     return out
+
+
+def chunk_take_index(plan: AsofChunkPlan, l_lane: np.ndarray) -> np.ndarray:
+    """Flat position, in the kernel's [K, n_chunks * S] outputs, of every
+    left row whose flat lane in the packed [K, Ll] left side is
+    ``l_lane`` (from :func:`binpack_dest`): built once per join, so each
+    output channel reaches left-row order in one ``np.take``."""
+    K = plan.l_out.shape[0]
+    row_base = np.arange(K, dtype=np.int64) * (plan.n_chunks * plan.chunk_rows)
+    return np.take((plan.l_out + row_base[:, None]).reshape(-1), l_lane)
 
 
 def chunk_gather(plane: np.ndarray, dest: np.ndarray, fill,

@@ -392,6 +392,165 @@ def test_flag_matrix_per_combination_counts():
         {str(k): v for k, v in sorted(_matrix_runs.items())})
 
 
+# ----------------------------------------------------------------------
+# Unpacking: one flat take per channel the join reads
+# ----------------------------------------------------------------------
+
+def _take_frames(seed):
+    """Ragged series: 'e' only on the right (an empty left series), 'f'
+    only on the left (no right rows), lengths spanning several 128-row
+    chunks, rows given out of order, and a right column that is never
+    valid (an all-NaN channel)."""
+    rng = np.random.default_rng(seed)
+
+    def side(counts):
+        syms = np.repeat(list(counts), list(counts.values()))
+        n = len(syms)
+        return pd.DataFrame({
+            "sym": syms,
+            "event_ts": pd.to_datetime(
+                rng.integers(0, 400, n).astype("int64") * 10**9),
+        }).sample(frac=1, random_state=seed).reset_index(drop=True)
+
+    lt = side({"a": 300, "b": 37, "c": 410, "f": 25})
+    lt["x"] = rng.standard_normal(len(lt))
+    rt = side({"a": 200, "b": 90, "c": 333, "e": 40})
+    rt["v"] = np.where(rng.random(len(rt)) > 0.3,
+                       rng.standard_normal(len(rt)), np.nan)
+    rt["never"] = np.nan
+    rt["w"] = rng.standard_normal(len(rt))
+    from tempo_tpu import TSDF
+
+    return TSDF(lt, "event_ts", ["sym"]), TSDF(rt, "event_ts", ["sym"])
+
+
+def _force_chunked(monkeypatch, binpack):
+    monkeypatch.setenv("TEMPO_TPU_BINPACK", "1" if binpack else "0")
+    monkeypatch.setenv("TEMPO_TPU_JOIN_ENGINE", "chunked")
+    monkeypatch.setenv("TEMPO_TPU_JOIN_CHUNK_LANES", str(CHUNK))
+
+
+def _plane_chain(out, plan, l_layout, r_layout, bp, skip):
+    """The former unpack, kept as the oracle: every channel gathered into
+    a [K, Ll] plane (chunk_gather), -1 for no match, then read at each
+    left row's (lane row, lane) plus its series' right start."""
+    from tempo_tpu import packing
+
+    coded = [np.where(np.isnan(p), -1, p).astype(np.int32)
+             for p in (packing.chunk_gather(np.asarray(o), plan.l_out,
+                                            np.nan, np.float32)
+                       for o in out)]
+    C = len(coded) - 1
+    k = l_layout.key_ids
+    pos = np.arange(l_layout.n_rows) - l_layout.starts[k]
+    res = []
+    for p in (coded[:C] if skip else coded[C:]):
+        if bp is None:
+            ridx = p[k, pos]
+            ok = ridx >= 0
+            flat = r_layout.starts[k] + np.where(ok, ridx, 0)
+        else:
+            ridx = p[bp.row[k], bp.l_off[k] + pos]
+            ok = ridx >= 0
+            flat = r_layout.starts[k] + np.where(ok, ridx - bp.r_off[k], 0)
+        res.append((flat, ok))
+    return res
+
+
+@pytest.mark.parametrize("skip", [True, False])
+@pytest.mark.parametrize("binpack", [False, True])
+def test_flat_take_matches_plane_chain(monkeypatch, binpack, skip):
+    """The per-join take index and one take per channel give the same
+    (flat, ok) as the [K, Ll] plane chain, dense and bin-packed, and the
+    same joined frame as the default engine.  Where ok is false the
+    flat row is never read (``_gather`` masks it; the bin-packed chain
+    left it at the series start), so it is compared where ok only."""
+    from tempo_tpu import join
+
+    L, R = _take_frames(5 + 2 * binpack + skip)
+    monkeypatch.setenv("TEMPO_TPU_BINPACK", "1" if binpack else "0")
+    monkeypatch.delenv("TEMPO_TPU_JOIN_ENGINE", raising=False)
+    want = L.asofJoin(R, skipNulls=skip).df
+
+    seen = {"rows": []}
+    real_run, real_starts, real_rows = (
+        pm._chunked_run, join._right_starts, join._right_rows)
+
+    def run_spy(*a):
+        seen["run"] = real_run(*a)
+        return seen["run"]
+
+    def starts_spy(*a):
+        seen["layouts"] = a
+        return real_starts(*a)
+
+    def rows_spy(*a):
+        seen["rows"].append(real_rows(*a))
+        return seen["rows"][-1]
+
+    monkeypatch.setattr(pm, "_chunked_run", run_spy)
+    monkeypatch.setattr(join, "_right_starts", starts_spy)
+    monkeypatch.setattr(join, "_right_rows", rows_spy)
+    _force_chunked(monkeypatch, binpack)
+    got = L.asofJoin(R, skipNulls=skip).df
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+
+    out, plan, _ = seen["run"]
+    l_layout, _, bp = seen["layouts"]
+    assert (bp is not None) == binpack
+    assert plan.n_chunks >= 3                  # left runs cross chunk edges
+    assert 0 in l_layout.lengths               # the empty left series
+    oracle = _plane_chain(out, plan, *seen["layouts"], skip)
+    assert len(seen["rows"]) == len(oracle) == (4 if skip else 1)
+    for i, ((flat, ok), (wflat, wok)) in enumerate(zip(seen["rows"],
+                                                       oracle)):
+        np.testing.assert_array_equal(ok, wok, err_msg=f"channel {i} ok")
+        np.testing.assert_array_equal(flat[ok], wflat[wok],
+                                      err_msg=f"channel {i} flat")
+        assert flat.dtype == np.int64 and len(flat) == l_layout.n_rows
+    if skip:                                   # the all-NaN channel
+        never = list(R.df.columns).index("never") - 1
+        assert not seen["rows"][never][1].any()
+        assert all(ok.any() for i, (_, ok) in enumerate(seen["rows"])
+                   if i != never)
+
+
+@pytest.mark.parametrize("skip", [True, False])
+@pytest.mark.parametrize("binpack", [False, True])
+def test_chunked_join_reads_host_planes_of_read_channels(
+        monkeypatch, binpack, skip):
+    """The chunked index path hands the join host numpy arrays (no
+    device round trip), and only the channels the join reads are
+    unpacked: the per-column channels under skipNulls, else the
+    last-row channel alone — counted from the ``tempo.unpack`` rows,
+    left rows per channel taken."""
+    import jax
+
+    L, R = _take_frames(11 + binpack)
+    calls = []
+    real = pm.asof_merge_indices_chunked
+
+    def spy(*a, **k):
+        calls.append(real(*a, **k))
+        return calls[-1]
+
+    monkeypatch.setattr(pm, "asof_merge_indices_chunked", spy)
+    _force_chunked(monkeypatch, binpack)
+    first = max((s.id for s in profiling.recent_spans()[0]), default=0)
+    L.asofJoin(R, skipNulls=skip)
+    spans = [s for s in profiling.recent_spans()[0] if s.id > first]
+
+    n_read = len(R.df.columns) - 1 if skip else 1
+    (take, planes), = calls
+    for a in (take, *planes):
+        assert type(a) is np.ndarray and not isinstance(a, jax.Array)
+    assert len(planes) == n_read
+    op, = [s for s in spans if s.name == "tempo.asofJoin"]
+    unpacked = sum(s.rows for s in spans
+                   if s.root == op.id and s.name == "tempo.unpack")
+    assert unpacked == n_read * len(L.df)
+
+
 def test_chunked_ring_depth_bitwise(monkeypatch):
     """TEMPO_TPU_DMA_BUFFERS > 2 streams the payload planes through
     the explicit chunk-axis prefetch ring (ISSUE 6) — outputs must be
