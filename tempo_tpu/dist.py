@@ -1465,7 +1465,10 @@ def _pick_range_engine_for_shard(shard_k: int, L: int, rb):
     plan-time pick (:func:`plan_range_engine_choice`) can never
     diverge — a hoisted hint that disagreed with the run-time pick
     would silently change which kernel (and which float rounding) a
-    planned chain runs."""
+    planned chain runs.  The lane-chunked form is not a candidate here
+    (``chunked_ok`` stays False): it is driven from the host in calls
+    of one shape, while a mesh shard's stats run inside one device
+    program over its planes."""
     from tempo_tpu.ops import pallas_stats as _ps
     from tempo_tpu.ops import pallas_window as _pw
 

@@ -422,7 +422,8 @@ def asof_join(
         from tempo_tpu.ops import pallas_merge as pm
 
         limit = resilience.max_merged_lanes()
-        est = _estimate_merged_lanes(l_codes, r_codes, n_series)
+        with span("tempo.pack", rows=len(l_codes) + len(r_codes)):
+            est = _estimate_merged_lanes(l_codes, r_codes, n_series)
         # the availability probe scans the seq column (seq_kernel_form)
         # — only pay it when the engine decision actually needs it
         # (oversize, or an explicit TEMPO_TPU_JOIN_ENGINE override)
@@ -653,4 +654,8 @@ def asof_join(
             res = res.iloc[perm].reset_index(drop=True)
 
         new_ts = lmap[left.ts_col]
-        return TSDF(res, ts_col=new_ts, partition_cols=pcols)
+        joined = TSDF(res, ts_col=new_ts, partition_cols=pcols)
+        # free the sorted copies and the join's planes (GBs at full
+        # size) inside the phase, not after it in the op's own time
+        del left_sorted, r_sorted_df, out, res, taken, planes, take
+        return joined

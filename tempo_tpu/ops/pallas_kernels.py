@@ -20,6 +20,12 @@ Kernels:
   reference truncates and this stack never has to:
   resample.py:resample_ema, "Truncated-lag EMA — the canonical
   note").  Wired into the flagship fused pipeline (__graft_entry__).
+* ``ema_chunked`` - the same recurrence over series too long for one
+  VMEM block: a grid over (row block, lane chunk), the lane axis
+  sequential, each chunk's ladder followed by ``y = v + D * carry``
+  with the running ``y`` in VMEM scratch.  Host-driven in calls of a
+  fixed shape that pass the carry on, so one compile serves every
+  series length.
 * ``last_valid_index_scan`` / ``first_valid_index_scan`` - running
   index of the last/next valid element, the engine under
   ``window_utils.last_valid_index``/``first_valid_index`` (which back
@@ -258,6 +264,148 @@ def _ema_call(x, valid, alpha, interpret=False):
             interpret=interpret,
         )(jnp.asarray([alpha], jnp.float32), x, valid)
     return out[:K]
+
+
+#: lanes of one exact-EMA chunk at the most; the VMEM plan may give
+#: fewer (:func:`ema_chunk_plan`)
+EMA_CHUNK_LANES = 1 << 15
+#: lane chunks per call of the carry-passing EMA program: every call
+#: has the same shape, so one compile serves every series length
+EMA_CALL_CHUNKS = 8
+#: live [bk, Lc] buffers of the chunk kernel: measured 13.8 on v5e for
+#: a [9, 32768] block (rows pad to whole 8-row tiles, validity to i32)
+_EMA_ARRAYS = 16
+#: VMEM the chunk plan budgets, under the kernel's raised limit
+_EMA_VMEM_BUDGET = 32 * 2**20
+_EMA_VMEM_LIMIT = 48 * 2**20
+
+
+def _ema_chunk_kernel(alpha_ref, x_ref, valid_ref, carry_ref, out_ref,
+                      carry_out_ref, y_ref):
+    """One (row block, lane chunk) step: the chunk's own ladder gives
+    ``v`` (the EMA from a zero start) and ``d`` (the chunk's cumulative
+    decay); ``y = v + d * carry`` continues the running EMA, whose last
+    lane is the next chunk's carry.  ``y_ref`` holds the carry broadcast
+    over 128 lanes."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        y_ref[:] = carry_ref[:]
+
+    a = alpha_ref[0]
+    valid = valid_ref[:]
+    f0 = jnp.float32(0.0)
+    f1 = jnp.float32(1.0)
+    d = jnp.where(valid, f1 - a, f1)
+    v = jnp.where(valid, a * x_ref[:], f0)
+    for span in _ladder_levels(d.shape[1]):
+        d_prev = _shift_with_identity(d, span, f1)
+        v_prev = _shift_with_identity(v, span, f0)
+        v = v + d * v_prev
+        d = d * d_prev
+    carry = jnp.max(y_ref[:], axis=1, keepdims=True)
+    y = v + d * carry
+    out_ref[:] = y
+    lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, dimension=1)
+    last = jnp.sum(jnp.where(lane == y.shape[1] - 1, y, f0), axis=1,
+                   keepdims=True)
+    y_ref[:] = jnp.broadcast_to(last, y_ref.shape)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        carry_out_ref[:] = y_ref[:]
+
+
+def ema_chunk_plan(K: int):
+    """``(bk, K_pad, Lc)``: the row block, the padded row count and the
+    chunk lanes of the carry-passing EMA over ``K`` series.  Up to
+    ``_BK`` series share one block (the whole row axis, so none is
+    padded); more take blocks of 8.  ``Lc`` is the largest power of
+    two up to :data:`EMA_CHUNK_LANES` whose ``[bk, Lc]`` block, its
+    rows rounded up to whole 8-row tiles, fits the VMEM budget."""
+    K = max(int(K), 1)
+    bk = K if K <= _BK else 8
+    K_pad = -(-K // bk) * bk
+    fit = _EMA_VMEM_BUDGET // (-(-bk // 8) * 8 * 4 * _EMA_ARRAYS)
+    Lc = min(EMA_CHUNK_LANES, 1 << (max(fit, LANE).bit_length() - 1))
+    return bk, K_pad, max(Lc, LANE)
+
+
+def ema_chunked_ok(x) -> bool:
+    """Whether the exact EMA of this [K, L] plane takes the chunked
+    form: f32 on TPU where the whole-series block does not fit
+    (:func:`_plan`)."""
+    return (x.dtype == jnp.float32 and x.ndim == 2
+            and jax.default_backend() == "tpu"
+            and _plan(int(x.shape[0]), int(x.shape[1])) is None)
+
+
+def ema_chunked_lanes(K: int, L: int) -> int:
+    """Lanes the chunked EMA computes over a [K, L] plane, pads
+    included."""
+    _, K_pad, Lc = ema_chunk_plan(K)
+    call = Lc * EMA_CALL_CHUNKS
+    return K_pad * max(1, -(-int(L) // call)) * call
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ema_chunk_call(x, valid, carry, alpha, interpret=False):
+    K_pad, Lcall = x.shape
+    bk, _, Lc = ema_chunk_plan(K_pad)
+    with jax.enable_x64(False):
+        spec = pl.BlockSpec((bk, Lc), lambda i, j: (i, j),
+                            memory_space=pltpu.VMEM)
+        cspec = pl.BlockSpec((bk, LANE), lambda i, j: (i, 0),
+                             memory_space=pltpu.VMEM)
+        return pl.pallas_call(
+            _ema_chunk_kernel,
+            grid=(K_pad // bk, Lcall // Lc),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec,
+                      cspec],
+            out_specs=[spec, cspec],
+            out_shape=[jax.ShapeDtypeStruct((K_pad, Lcall), jnp.float32),
+                       jax.ShapeDtypeStruct((K_pad, LANE), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bk, LANE), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_EMA_VMEM_LIMIT),
+            interpret=interpret,
+        )(alpha, x, valid, carry)
+
+
+def ema_chunked(x, valid, alpha: float, interpret: bool = None):
+    """Exact EMA of host [K, L] planes (f32 values, bool validity) as a
+    host [K, L] f32 array.  The planes are padded to whole calls of
+    ``EMA_CALL_CHUNKS`` chunks and the calls run in lane order, each
+    starting from the carry the one before it ended with.  The same
+    recurrence as :func:`ema_scan` and ``ops/rolling.ema_exact``,
+    bracketed by chunk.  ``interpret`` defaults to the Pallas
+    interpreter off TPU."""
+    import numpy as np
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    x = np.asarray(x, np.float32)
+    valid = np.asarray(valid, bool)
+    K, L = x.shape
+    _, K_pad, Lc = ema_chunk_plan(K)
+    call = Lc * EMA_CALL_CHUNKS
+    n_calls = max(1, -(-L // call))
+    xp = np.zeros((K_pad, n_calls * call), np.float32)
+    vp = np.zeros((K_pad, n_calls * call), bool)
+    xp[:K, :L] = x
+    vp[:K, :L] = valid
+    a = jnp.asarray([alpha], jnp.float32)
+    carry = jnp.zeros((K_pad, LANE), jnp.float32)
+    parts = []
+    with interpret_scope(interpret):
+        for c in range(n_calls):
+            lanes = slice(c * call, (c + 1) * call)
+            y, carry = _ema_chunk_call(xp[:, lanes], vp[:, lanes], carry,
+                                       a, interpret=interpret)
+            parts.append(y)
+    return np.concatenate(jax.device_get(parts), axis=1)[:K, :L]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

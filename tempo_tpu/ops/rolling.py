@@ -21,6 +21,7 @@ Replaces the reference's Spark Window scans:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -28,30 +29,41 @@ import jax.numpy as jnp
 
 from tempo_tpu.ops import window_utils as wu
 
-# Three-way auto-pick between the range-stats engines (the measured
-# evidence is bench.py's ``rolling_crossover`` record):
+LANE = 128
+
+# The range-stats engines, and the property of the input that picks
+# each one (``pick_range_engine``; the frame's row bounds come from
+# ``packing.layout_rowbounds``, the shapes from the packed planes):
 #
-# 1. **shifted** — W static masked shifted passes
+# 1. **shifted**: W static masked shifted passes
 #    (ops/sortmerge.py:range_stats_shifted; VMEM-resident via the
-#    unrolled ops/pallas_window.py kernel on TPU).  Wins every extent
-#    it can legally reach (shifted 175M rows/s vs windowed 8.0M on
-#    identical ~140-row windows, the pre-PR-1 chip bench) but is
-#    bounded by resources: compile-time growth on small shards
-#    (SHIFTED_MAX_ROWS) and HBM shifted-copy materialisation on large
-#    ones (:func:`shifted_row_budget`).
-# 2. **stream** — the streaming VMEM sweep
-#    (ops/pallas_window.py:range_stats_stream): same O(W) work but the
-#    width is a runtime scalar, O(1) live planes, one HBM read — it
-#    serves every extent the unrolled forms cannot, up to
-#    TEMPO_TPU_STREAM_MAX_ROWS.
-# 3. **windowed** — the general prefix-scan + RMQ form
-#    (:func:`windowed_stats`).  Gather-bound on TPU (~96 ms per RMQ
-#    take_along_axis at [1024, 8192]) — the last resort there, the
-#    default off-TPU.
+#    unrolled ops/pallas_window.py kernel on TPU).  Picked when the row
+#    extent W = max_behind + max_ahead is at most
+#    :func:`shifted_row_budget`: its work and compile grow with W, and
+#    its XLA form materialises shifted copies in HBM.
+# 2. **stream**: the streaming VMEM sweep
+#    (ops/pallas_window.py:range_stats_stream), O(W) rotate passes with
+#    the width a runtime scalar.  Picked for wider extents, up to
+#    TEMPO_TPU_STREAM_MAX_ROWS, where a whole [bk, L] series block fits
+#    its VMEM plan (``stream_block_feasible``: TPU, f32, L % 128 == 0).
+# 3. **chunked**: fixed lane chunks with halos
+#    (:func:`range_stats_chunk`, driven by rolling.py): each chunk's
+#    block carries ``max_behind`` lanes before its core and at least
+#    ``max_ahead`` after, so every window lies inside its block; prefix
+#    sums restart per block and min/max come from a sparse table capped
+#    at the levels the row bound needs.  One program per (block, halo)
+#    shape, whatever the series' length.  Picked when neither form
+#    above takes the frame and its series are longer than one block
+#    (:func:`range_chunk_plan`); the caller says so (``chunked_ok``).
+# 4. **windowed**: the whole-series prefix-scan + RMQ form
+#    (:func:`windowed_stats`).  Everything else: series no longer than
+#    one chunk block, frames without row bounds (int64 spans, the CPU
+#    backend, which keeps the search-and-gather forms), and the mesh
+#    path's shard programs.
 #
-# TEMPO_TPU_WINDOW_ENGINE forces a choice (auto | shifted | stream |
-# windowed | legacy — legacy keeps the pre-streaming pallas_stats
-# kernel on the shifted path).
+# TEMPO_TPU_WINDOW_ENGINE forces one of shifted | stream | windowed
+# (legacy keeps the pre-streaming pallas_stats kernel on the shifted
+# path).
 SHIFTED_MAX_ROWS = 512
 
 
@@ -63,59 +75,64 @@ def window_engine_override() -> str:
 
 def pick_range_engine(n_elems: int, max_behind: int, max_ahead: int,
                       pallas_small_ok: bool = False,
-                      stream_ok: bool = False) -> str:
-    """'shifted' | 'stream' | 'windowed' for a frame whose row extent
-    is (max_behind, max_ahead) on a shard of ``n_elems`` values.
-    ``pallas_small_ok``/``stream_ok``: the caller verified the
+                      stream_ok: bool = False,
+                      chunked_ok: bool = False) -> str:
+    """'shifted' | 'stream' | 'chunked' | 'windowed' for a frame whose
+    row extent is (max_behind, max_ahead) on a shard of ``n_elems``
+    values.  ``pallas_small_ok``/``stream_ok``: the caller verified the
     respective VMEM kernels can take this shard shape/dtype.
+    ``chunked_ok``: the caller runs the lane-chunked form and its series
+    are longer than one chunk block (:func:`range_chunk_plan`).
 
     When the lazy planner replays a node whose engine was hoisted to
     plan time (tempo_tpu/plan/optimizer.py), the decision arrives as a
     hint and wins — skipping the knob read — but only while it still
-    matches what the current shard's bounds would pick.  The three
-    engines differ in FMA/rounding order, so a cached plan replayed
-    over different data (same shapes, different row bounds) must
-    re-pick rather than force an engine eager execution would not
-    choose — that would break the planned==eager bit-identity contract
+    matches what the current shard's bounds would pick.  The engines
+    differ in FMA/rounding order, so a cached plan replayed over
+    different data (same shapes, different row bounds) must re-pick
+    rather than force an engine eager execution would not choose —
+    that would break the planned==eager bit-identity contract
     (MIGRATION.md v0.7).  Join hints have no such guard because every
     join engine is bit-identical to the others."""
     from tempo_tpu.ops import pallas_window as pw
     from tempo_tpu.plan import hints as plan_hints
 
     W = int(max_behind) + int(max_ahead)
+    fits_shifted = W <= shifted_row_budget(n_elems, pallas_small_ok)
+    fits_stream = stream_ok and W <= pw._stream_max_rows()
     hinted = plan_hints.get("range_engine")
-    if hinted in ("shifted", "stream", "windowed"):
-        fits_shifted = W <= shifted_row_budget(n_elems, pallas_small_ok)
-        fits_stream = stream_ok and W <= pw._stream_max_rows()
-        if hinted == "shifted" and fits_shifted:
-            return "shifted"
-        if hinted == "stream" and not fits_shifted and fits_stream:
-            return "stream"
-        if hinted == "windowed" and not fits_shifted and not fits_stream:
-            return "windowed"
+    if hinted in ("shifted", "stream", "chunked", "windowed"):
+        rule = _rule_pick(fits_shifted, fits_stream, chunked_ok)
+        if hinted == rule:
+            return hinted
         # the data moved out from under the hoisted decision: fall
         # through and re-pick (knob read included)
     forced = window_engine_override()
     if forced in ("shifted", "stream", "windowed"):
         return forced
-    fits_shifted = W <= shifted_row_budget(n_elems, pallas_small_ok)
-    fits_stream = stream_ok and W <= pw._stream_max_rows()
     from tempo_tpu.plan import cost as plan_cost
 
     if plan_cost.enabled():
         # cost-decided, but over the BITWISE-SAFE candidate set only:
-        # the three engines differ in f32 rounding order, so the
-        # revalidation lattice above admits exactly one engine per
-        # shape and a cost argmin cannot drift from the rule pick —
-        # the cost numbers surface in explain() via the plan-time
-        # hoist, not on this per-call path
-        # (plan/cost.py:decide_range_engine documents the contract)
+        # the engines differ in f32 rounding order, so the revalidation
+        # lattice above admits exactly one engine per shape and a cost
+        # argmin cannot drift from the rule pick — the cost numbers
+        # surface in explain() via the plan-time hoist, not on this
+        # per-call path (plan/cost.py:decide_range_engine documents the
+        # contract)
         return plan_cost.decide_range_engine(W, n_elems, fits_shifted,
-                                             fits_stream)
+                                             fits_stream, chunked_ok)
+    return _rule_pick(fits_shifted, fits_stream, chunked_ok)
+
+
+def _rule_pick(fits_shifted: bool, fits_stream: bool,
+               chunked_ok: bool) -> str:
     if fits_shifted:
         return "shifted"
     if fits_stream:
         return "stream"
+    if chunked_ok:
+        return "chunked"
     return "windowed"
 
 
@@ -347,7 +364,21 @@ def windowed_stats(
     # window query uses C[e-1] - C[s-1] with C[-1] = 0
     from tempo_tpu.ops import pallas_kernels as pk
 
-    P1, P2, Pc = pk.cumsum3(xc, valid)
+    prefix = pk.cumsum3(xc, valid)
+    nlev = (max(1, int(max_window)) - 1).bit_length() + 1 if max_window else 0
+    return _stats_over_windows(x, valid, center, prefix, start, end, nlev,
+                               x, valid)
+
+
+def _stats_over_windows(x, valid, center, prefix, start, end, nlev,
+                        x_row, valid_row) -> Dict[str, jnp.ndarray]:
+    """The seven stats of each row from its window ``[start, end)`` over
+    the lanes of ``x``: ``prefix`` holds the inclusive prefix sums of
+    the centred values, their squares and the valid count; min and max
+    come from sparse tables of ``nlev`` levels (0: every level).
+    ``x_row``/``valid_row`` are the rows' own values, for the zscore,
+    lane for lane with ``start``/``end``."""
+    P1, P2, Pc = prefix
 
     def win(P):
         P = P.astype(x.dtype)
@@ -366,7 +397,6 @@ def windowed_stats(
     std = jnp.sqrt(jnp.maximum(var, 0.0))
     std = jnp.where(cnt > 1, std, jnp.nan)
 
-    nlev = (max(1, int(max_window)) - 1).bit_length() + 1 if max_window else 0
     pinf = jnp.array(jnp.inf, x.dtype)
     tmin = _sparse_table(jnp.where(valid, x, pinf), pinf, jnp.minimum, nlev)
     tmax = _sparse_table(jnp.where(valid, x, -pinf), -pinf, jnp.maximum, nlev)
@@ -375,7 +405,7 @@ def windowed_stats(
     wmin = jnp.where(cnt > 0, wmin, jnp.nan)
     wmax = jnp.where(cnt > 0, wmax, jnp.nan)
 
-    zscore = (x - mean) / std
+    zscore = (x_row - mean) / std
     return {
         "mean": mean,
         "count": cnt,
@@ -383,8 +413,64 @@ def windowed_stats(
         "max": wmax,
         "sum": jnp.where(cnt > 0, total, jnp.nan),
         "stddev": std,
-        "zscore": jnp.where(valid, zscore, jnp.nan),
+        "zscore": jnp.where(valid_row, zscore, jnp.nan),
     }
+
+
+#: lanes of a range-stats chunk block, halos included, at the least
+RANGE_BLOCK_LANES = 1 << 17
+#: a chunk block is at least this many halos long, so halos stay a
+#: small share of the lanes computed
+RANGE_BLOCK_HALOS = 8
+#: chunk blocks per call of :func:`range_stats_chunk`: every call has
+#: the same shape, so one compile serves every series length
+RANGE_CHUNK_ROWS = 8
+#: the stats of :func:`range_stats_chunk`'s output, in its order
+CHUNK_STATS = ("count", "max", "mean", "min", "stddev", "sum", "zscore")
+
+
+def range_chunk_plan(max_behind: int, max_ahead: int) -> Tuple[int, int, int]:
+    """``(block, halo, nlev)`` of the lane-chunked range stats for a
+    frame whose windows reach at most ``max_behind`` rows back and
+    ``max_ahead`` tie rows ahead (``packing.layout_rowbounds``).
+
+    ``halo`` is the power of two (at least 128) that holds both: a
+    chunk's block starts ``max_behind`` lanes before its core and ends
+    ``halo - max_behind >= max_ahead`` lanes after it, so every core
+    row's window lies inside the block.  ``block`` is a multiple of 128,
+    at least :data:`RANGE_BLOCK_LANES` and :data:`RANGE_BLOCK_HALOS`
+    halos; the core is ``block - halo`` lanes.  ``nlev`` sparse-table
+    levels reach a window of ``halo + 1`` rows.  One compiled program
+    serves each plan, whatever the series' lengths."""
+    reach = int(max_behind) + int(max_ahead)
+    halo = max(LANE, 1 << max(reach - 1, 0).bit_length())
+    block = max(RANGE_BLOCK_LANES, int(math.ceil(RANGE_BLOCK_HALOS * halo)),
+                halo + LANE)
+    block = -(-block // LANE) * LANE
+    return block, halo, halo.bit_length()
+
+
+@functools.partial(jax.jit, static_argnames=("nlev",))
+def range_stats_chunk(x, valid, start, end, center, core_start, nlev: int):
+    """The seven range stats of the core lanes of ``G`` chunk blocks, as
+    one ``[7, G, Lc]`` array in :data:`CHUNK_STATS` order.
+
+    ``x``/``valid`` are ``[G, B]`` blocks (pads invalid), ``start`` and
+    ``end`` the ``[G, Lc]`` windows of the core lanes as block lanes,
+    ``center`` the ``[G, 1]`` mean of each block's series, which is
+    subtracted before the prefix sums as :func:`windowed_stats` does,
+    and ``core_start`` the block lane where the core begins (a runtime
+    scalar).  The prefix sums restart in every block, so their
+    magnitudes stay those of one block whatever the series' length."""
+    Lc = start.shape[-1]
+    xc = jnp.where(valid, x - center, 0.0)
+    prefix = (jax.lax.cumsum(xc, axis=1), jax.lax.cumsum(xc * xc, axis=1),
+              jax.lax.cumsum(valid.astype(x.dtype), axis=1))
+    x_row = jax.lax.dynamic_slice_in_dim(x, core_start, Lc, axis=1)
+    valid_row = jax.lax.dynamic_slice_in_dim(valid, core_start, Lc, axis=1)
+    stats = _stats_over_windows(x, valid, center, prefix, start, end, nlev,
+                                x_row, valid_row)
+    return jnp.stack([stats[k] for k in CHUNK_STATS])
 
 
 def bucket_stats(bid, x, valid, start, end):

@@ -336,6 +336,73 @@ def row_mask(layout: FlatLayout, padded_len: int) -> np.ndarray:
         return np.arange(padded_len)[None, :] < layout.lengths[:, None]
 
 
+def _window_runs(layout: "FlatLayout", window_secs: float):
+    """The rangeBetween(-window_secs, 0) windows of a layout, one per
+    (series, second) run, or None when a series' seconds span plus the
+    window would overflow int32 (see :func:`layout_rowbounds`).
+
+    Rows of one series that share a second share a window, so the
+    windows are found per run: one ``searchsorted`` over the runs, not
+    one per row.  Returns ``(first, lo, end)``: each run's first row,
+    the first row of its window and one past its window's last row (the
+    run's own end: ties to the second are in), all global row indices.
+    Cached per (layout, window): chained frames sharing a layout reuse
+    them."""
+    cache = layout.__dict__.setdefault("_window_run_cache", {})
+    key = float(window_secs)
+    if key not in cache:
+        with span("tempo.pack", rows=layout.n_rows):
+            cache[key] = _find_window_runs(layout, np.int64(window_secs))
+    return cache[key]
+
+
+def _find_window_runs(layout: "FlatLayout", w: np.int64):
+    n = layout.n_rows
+    if n == 0:
+        empty = np.zeros(0, np.int64)
+        return empty, empty, empty
+    secs = layout.ts_ns // NS_PER_S
+    kid = layout.key_ids
+    new = np.empty(n, dtype=bool)
+    new[0] = True
+    np.not_equal(secs[1:], secs[:-1], out=new[1:])
+    new[1:] |= kid[1:] != kid[:-1]
+    first = np.flatnonzero(new)
+    end = np.append(first[1:], n)
+    run_key = kid[first]
+    # seconds from the series' first second: the window test compares
+    # within a series only
+    rel = secs[first] - secs[layout.starts[run_key]]
+    if int(rel.max()) + int(w) >= 2**31 - 2:
+        return None
+    # (series, second) as one ascending int64 key; a window reaching
+    # before its series' first second clamps to it
+    stride = np.int64(int(rel.max()) + max(int(w), 0) + 1)
+    run_pos = run_key * stride + rel
+    lo = np.searchsorted(run_pos,
+                         run_key * stride + np.maximum(rel - w, 0),
+                         side="left")
+    # a negative width gives an empty window (Spark's rangeBetween with
+    # its start after its end)
+    lo_row = np.minimum(first[np.minimum(lo, len(first) - 1)], end)
+    lo_row = np.where(lo < len(first), lo_row, end)
+    return first, lo_row, end
+
+
+def layout_window_bounds(layout: "FlatLayout", window_secs: float):
+    """Each row's rangeBetween(-window_secs, 0) window as ``(start,
+    end)`` int64 positions within its series (``start`` inclusive,
+    ``end`` exclusive), or None where :func:`layout_rowbounds` is."""
+    runs = _window_runs(layout, window_secs)
+    if runs is None:
+        return None
+    first, lo, end = runs
+    with span("tempo.pack", rows=layout.n_rows):
+        lens = np.diff(np.append(first, layout.n_rows))
+        base = layout.starts[layout.key_ids]
+        return (np.repeat(lo, lens) - base, np.repeat(end, lens) - base)
+
+
 def layout_rowbounds(layout: "FlatLayout", window_secs: float):
     """Static (max rows back, max tie rows ahead) any
     rangeBetween(-window_secs, 0) frame spans over this layout, or
@@ -346,32 +413,17 @@ def layout_rowbounds(layout: "FlatLayout", window_secs: float):
     window) — chained frames sharing a layout reuse the bounds.
     Shared by the host frame auto-pick (rolling.with_range_stats) and
     the mesh path (dist._window_rowbounds)."""
-    cache = layout.__dict__.setdefault("_rowbound_cache", {})
-    key = float(window_secs)
-    if key not in cache:
-        with span("tempo.pack", rows=layout.n_rows):
-            secs = layout.ts_ns // NS_PER_S
-            w = np.int64(window_secs)
-            behind = 0
-            ahead = 0
-            span_i32 = True
-            for k in range(layout.n_series):
-                s = secs[layout.starts[k]: layout.starts[k + 1]]
-                if len(s) == 0:
-                    continue
-                idx = np.arange(len(s))
-                behind = max(
-                    behind,
-                    int((idx - np.searchsorted(s, s - w, side="left")).max()),
-                )
-                ahead = max(
-                    ahead,
-                    int((np.searchsorted(s, s, side="right") - 1 - idx).max()),
-                )
-                if int(s[-1] - s[0]) + int(w) >= 2**31 - 2:
-                    span_i32 = False
-            cache[key] = (behind, ahead) if span_i32 else None
-    return cache[key]
+    runs = _window_runs(layout, window_secs)
+    if runs is None:
+        return None
+    first, lo, end = runs
+    if len(first) == 0:
+        return 0, 0
+    # a run's farthest reach back is from its last row, its farthest
+    # tie ahead from its first
+    behind = max(0, int((end - 1 - lo).max()))
+    ahead = max(0, int((end - 1 - first).max()))
+    return behind, ahead
 
 
 SID_PAD = np.int32(2**31 - 1)

@@ -27,10 +27,11 @@ runs over the *bitwise-equal candidate set* only:
   to each other (round 3), so the join argmin is free within resource
   feasibility — this is the pick that genuinely flips when the cost
   inputs change.
-* The range-stats engines (shifted / stream / windowed) differ in f32
-  rounding order, so the revalidation lattice from round 5
-  (``ops/rolling.pick_range_engine``: shifted iff it fits, else stream
-  iff it fits, else windowed) admits exactly ONE bitwise-safe engine
+* The range-stats engines (shifted / stream / chunked / windowed)
+  differ in f32 rounding order, so the revalidation lattice from round
+  5 (``ops/rolling.pick_range_engine``: shifted iff it fits, else
+  stream iff it fits, else chunked iff the series outgrow one chunk
+  block, else windowed) admits exactly ONE bitwise-safe engine
   per shape — the cost numbers are computed and rendered
   (``explain()``), but the argmin is over that singleton by
   construction.
@@ -286,24 +287,27 @@ def range_costs(W: int, n_elems: int) -> Dict[str, float]:
 
 
 def decide_range_engine(W: int, n_elems: int, fits_shifted: bool,
-                        fits_stream: bool) -> str:
-    """Cheapest *bitwise-safe* range engine.  The three engines differ
-    in f32 rounding order (MIGRATION v0.7), so the candidate set is the
+                        fits_stream: bool, fits_chunked: bool = False) -> str:
+    """Cheapest *bitwise-safe* range engine.  The engines differ in f32
+    rounding order (MIGRATION v0.7), so the candidate set is the
     revalidation lattice's singleton — shifted iff it fits, else stream
-    iff it fits, else windowed — and a cost argmin over one candidate
-    can never flip the engine away from the rule-based pick (the
-    bitwise contract wins over the cost model by design).  The
-    :func:`range_costs` estimates are therefore NOT computed on this
-    per-call path; they surface once per plan via the optimizer's
-    engine hoist, which annotates the node for ``explain()``.  ``W``
-    and ``n_elems`` stay in the signature as the decision's cost-model
-    inputs — a future bitwise-equal engine pair would argmin over
-    them."""
+    iff it fits, else the lane-chunked form iff the caller runs it and
+    the series outgrow one chunk block, else windowed — and a cost
+    argmin over one candidate can never flip the engine away from the
+    rule-based pick (the bitwise contract wins over the cost model by
+    design).  The :func:`range_costs` estimates are therefore NOT
+    computed on this per-call path; they surface once per plan via the
+    optimizer's engine hoist, which annotates the node for
+    ``explain()``.  ``W`` and ``n_elems`` stay in the signature as the
+    decision's cost-model inputs — a future bitwise-equal engine pair
+    would argmin over them."""
     del W, n_elems                       # singleton candidate set
     if fits_shifted:
         return "shifted"
     if fits_stream:
         return "stream"
+    if fits_chunked:
+        return "chunked"
     return "windowed"
 
 

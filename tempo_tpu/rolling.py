@@ -81,8 +81,13 @@ def plan_range_engine(tsdf, cols: List[str], rangeBackWindowSecs: int):
     # pick (dist._pick_range_engine_for_shard).
     pallas_ok = f32 and _ps.pallas_block_feasible(K, L)
     stream_ok = f32 and _pw.stream_block_feasible(K, L)
+    # the lane-chunked form takes series longer than one chunk's core
+    chunked_ok = False
+    if rb is not None:
+        block, halo, _ = rk.range_chunk_plan(*rb)
+        chunked_ok = int(layout.lengths.max()) > block - halo
     engine = ("windowed" if rb is None else rk.pick_range_engine(
-        K * L, rb[0], rb[1], pallas_ok, stream_ok))
+        K * L, rb[0], rb[1], pallas_ok, stream_ok, chunked_ok))
     return engine, rb, ts_long, w
 
 
@@ -106,62 +111,47 @@ def with_range_stats(tsdf, type: str = "range", colsToSummarize=None,
                     0, dtype=np.int64 if stat == "count" else np.float64
                 )
         return TSDF(out, tsdf.ts_col, tsdf.partitionCols, tsdf.sequence_col or None)
-    vals, valids = _packed_metric_stack(tsdf, cols)
-    C, K, L = vals.shape
-    flat = lambda a: jnp.asarray(a).reshape(C * K, L)
-    tile = lambda a: jnp.broadcast_to(a[None], (C, K, L)).reshape(C * K, L)
 
-    # three-way auto-pick (bench.py rolling_crossover is the measured
-    # evidence): row-boundable frames take the static-shift form — W
-    # masked shifted passes, VMEM-resident on TPU; wider frames the
-    # streaming VMEM sweep (runtime-width, ops/pallas_window.py); the
-    # general prefix-scan + RMQ form covers whatever remains (spans
-    # past int32, no TPU, extents past TEMPO_TPU_STREAM_MAX_ROWS).
-    # Same picker as the mesh path (dist.withRangeStats); under the
-    # lazy planner the decision is hoisted to plan time and arrives
-    # here as a hint (plan_range_engine + ops/rolling.pick_range_engine)
-    from tempo_tpu.ops import sortmerge as sm
-
+    # the engine pick (ops/rolling.py lists the engines and what picks
+    # each): row-boundable frames take the static-shift form, wider
+    # ones the streaming VMEM sweep, series longer than one chunk block
+    # the lane-chunked form; the prefix-scan + RMQ form covers whatever
+    # remains.  Same picker as the mesh path (dist.withRangeStats);
+    # under the lazy planner the decision is hoisted to plan time and
+    # arrives here as a hint (plan_range_engine +
+    # ops/rolling.pick_range_engine)
     engine, rb, ts_long, w = plan_range_engine(tsdf, cols,
                                                rangeBackWindowSecs)
+    if engine == "chunked":
+        flat = _range_stats_chunked(tsdf, cols, rb, w)
+    else:
+        flat = _range_stats_whole(tsdf, cols, engine, rb, ts_long, w)
+    with span("tempo.frame", rows=layout.n_rows):
+        for name, col in flat.items():
+            out[name] = col
+        return TSDF(out, tsdf.ts_col, tsdf.partitionCols,
+                    tsdf.sequence_col or None)
+
+
+def _stat_columns(cols, stats):
+    """Output columns from ``stats[stat][ci]`` flat rows: Spark emits
+    DoubleType stats regardless of input width, counts as long."""
+    return {f"{stat}_{c}": stats[stat][ci].astype(
+                np.int64 if stat == "count" else np.float64)
+            for ci, c in enumerate(cols) for stat in packing.RANGE_STATS}
+
+
+def _range_stats_whole(tsdf, cols, engine, rb, ts_long, w):
+    """Range stats by an engine that holds whole series: [C, K, L]
+    planes up, one stacked fetch, then unpacked to flat rows."""
+    layout = tsdf.layout
+    vals, valids = _packed_metric_stack(tsdf, cols)
+    C, K, L = vals.shape
     with span("tempo.dispatch") as fetched:
-        if engine == "shifted":
-            # multi-column payload packing: the [C, K, L] metric stack
-            # shares ONE [K, L] key plane — the packed kernels read it once
-            # per pack where the seed path materialised a C-wide broadcast
-            # copy of the timestamps (`tile`) and streamed it per column
-            stats = dict(sm.range_stats_shifted_packed(
-                jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
-                jnp.asarray(np.int32(w)),
-                max_behind=int(rb[0]), max_ahead=int(rb[1]),
-            ))
-            # the truncation audit rides the SAME stacked fetch as the
-            # stats below (one device->host round trip, not two)
-        elif engine == "stream":
-            stats = dict(rk.range_stats_streaming_packed(
-                jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
-                jnp.asarray(np.int32(w)),
-                max_behind=int(rb[0]), max_ahead=int(rb[1]),
-            ))
-        else:
-            ts_arr = jnp.asarray(ts_long)
-            start, end = rk.range_window_bounds(
-                ts_arr, rk.range_window_width(ts_arr, w)
-            )
-            # static row bound for the min/max sparse tables: a 10s window
-            # over 1Hz data needs 4 levels, not log2(L); bucket to a power
-            # of two so distinct datasets reuse the compiled kernel.
-            # Padded slots all share the clamped sentinel timestamp, so
-            # their windows span the whole pad run — mask them out of the
-            # bound or ragged series inflate it toward L
-            real = jnp.asarray(tsdf.packed_mask())
-            max_w = max(1, int(jax.device_get(
-                jnp.max(jnp.where(real, end - start, 0)))))
-            max_w = 1 << (max_w - 1).bit_length()
-            stats = rk.windowed_stats(
-                flat(vals), flat(valids), tile(start), tile(end),
-                max_window=max_w
-            )
+        # the lanes the engine computes (the nested span's rows)
+        with span("tempo.dispatch", rows=C * K * L):
+            stats = _whole_engine_call(engine, tsdf, vals, valids, rb,
+                                       ts_long, w)
         # one stacked device->host transfer instead of one per stat: each
         # transfer pays a fixed latency.  The shifted path's
         # truncation-audit scalar piggybacks as one extra element on the
@@ -186,21 +176,171 @@ def with_range_stats(tsdf, type: str = "range", colsToSummarize=None,
     # packed engines yield [C, K, L] planes, the windowed fallback
     # [C*K, L] — the element order is identical either way
     stacked = buf.reshape(len(names), C, K, L)
-    stats = {k: stacked[i] for i, k in enumerate(names)}
-
-    new_cols = {}
     with span("tempo.unpack", rows=layout.n_rows * len(cols)):
-        for ci, c in enumerate(cols):
-            for stat in packing.RANGE_STATS:
-                flat = packing.unpack_column(stats[stat][ci], layout)
-                # Spark emits DoubleType stats regardless of input width
-                new_cols[f"{stat}_{c}"] = flat.astype(
-                    np.int64 if stat == "count" else np.float64)
-    with span("tempo.frame", rows=layout.n_rows):
-        for name, col in new_cols.items():
-            out[name] = col
-        return TSDF(out, tsdf.ts_col, tsdf.partitionCols,
-                    tsdf.sequence_col or None)
+        stats = {k: [packing.unpack_column(stacked[i][ci], layout)
+                     for ci in range(C)] for i, k in enumerate(names)}
+        return _stat_columns(cols, stats)
+
+
+def _whole_engine_call(engine, tsdf, vals, valids, rb, ts_long, w):
+    """The stats planes of one whole-series engine, on the device."""
+    from tempo_tpu.ops import sortmerge as sm
+
+    if engine == "shifted":
+        # multi-column payload packing: the [C, K, L] metric stack
+        # shares ONE [K, L] key plane — the packed kernels read it once
+        # per pack where the seed path materialised a C-wide broadcast
+        # copy of the timestamps (`tile`) and streamed it per column
+        return dict(sm.range_stats_shifted_packed(
+            jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
+            jnp.asarray(np.int32(w)),
+            max_behind=int(rb[0]), max_ahead=int(rb[1]),
+        ))
+    if engine == "stream":
+        return dict(rk.range_stats_streaming_packed(
+            jnp.asarray(ts_long), jnp.asarray(vals), jnp.asarray(valids),
+            jnp.asarray(np.int32(w)),
+            max_behind=int(rb[0]), max_ahead=int(rb[1]),
+        ))
+    ts_arr = jnp.asarray(ts_long)
+    start, end = rk.range_window_bounds(
+        ts_arr, rk.range_window_width(ts_arr, w)
+    )
+    # static row bound for the min/max sparse tables: a 10s window
+    # over 1Hz data needs 4 levels, not log2(L); bucket to a power
+    # of two so distinct datasets reuse the compiled kernel.
+    # Padded slots all share the clamped sentinel timestamp, so
+    # their windows span the whole pad run — mask them out of the
+    # bound or ragged series inflate it toward L
+    real = jnp.asarray(tsdf.packed_mask())
+    max_w = max(1, int(jax.device_get(
+        jnp.max(jnp.where(real, end - start, 0)))))
+    max_w = 1 << (max_w - 1).bit_length()
+    C, K, L = vals.shape
+    flat = lambda a: jnp.asarray(a).reshape(C * K, L)
+    tile = lambda a: jnp.broadcast_to(a[None], (C, K, L)).reshape(C * K, L)
+    return rk.windowed_stats(
+        flat(vals), flat(valids), tile(start), tile(end),
+        max_window=max_w
+    )
+
+
+def _range_stats_chunked(tsdf, cols, rb, w):
+    """Range stats over fixed lane chunks (``ops/rolling.
+    range_stats_chunk``): each series is cut into cores of ``Lc`` lanes,
+    and each core's block carries ``max_behind`` lanes before it and
+    at least ``max_ahead`` after, so every window lies inside its block
+    and the stats are exact.  The blocks are built on the host from the
+    flat sorted columns, with each row's window as block lanes
+    (``packing.layout_window_bounds``), and run ``RANGE_CHUNK_ROWS``
+    blocks a call; the calls all have one shape.  Returns the output
+    columns, flat rows."""
+    layout = tsdf.layout
+    block, halo, nlev = rk.range_chunk_plan(*rb)
+    behind, core = int(rb[0]), block - halo
+    G = rk.RANGE_CHUNK_ROWS
+    dt = packing.compute_dtype()
+    with span("tempo.pack", rows=layout.n_rows):
+        plan = _ChunkPlan(layout, w, core, behind, halo, G)
+    blocks = []
+    for c in cols:
+        v, m = tsdf.numeric_flat(c)
+        with span("tempo.pack", rows=layout.n_rows):
+            blocks.append(plan.blocks(v.astype(dt), m))
+    n_lanes = len(cols) * plan.rows * block
+    core_start = np.int32(behind)
+    with span("tempo.dispatch") as fetched:
+        with span("tempo.dispatch", rows=n_lanes):
+            parts = [[rk.range_stats_chunk(
+                x[g:g + G], ok[g:g + G], plan.start[g:g + G],
+                plan.end[g:g + G], center[g:g + G], core_start, nlev=nlev)
+                for g in range(0, plan.rows, G)]
+                for x, ok, center in blocks]
+        got = jax.device_get(parts)
+        fetched.rows = sum(p.size for col in got for p in col)
+    with span("tempo.unpack", rows=layout.n_rows * len(cols)):
+        stats = {k: [] for k in rk.CHUNK_STATS}
+        for col in got:
+            planes = np.concatenate(col, axis=1)    # [7, rows, Lc]
+            for i, k in enumerate(rk.CHUNK_STATS):
+                stats[k].append(packing.take(planes[i].reshape(-1),
+                                             plan.out_index))
+        flat = _stat_columns(cols, stats)
+        # free the chunk buffers (GBs at full size) inside the phase,
+        # not after it in the op's own time
+        del plan, blocks, parts, got, planes, stats
+        return flat
+
+
+class _ChunkPlan:
+    """Where each row of a layout sits in the chunk blocks of
+    :func:`_range_stats_chunked`.
+
+    Series ``k`` of length ``n_k`` takes ``ceil(n_k / Lc)`` chunk rows,
+    one after another (ragged series pad only their last chunk); the
+    rows are padded to a whole number of calls.  Chunk ``c`` of series
+    ``k`` covers series lanes ``[c*Lc - behind, c*Lc - behind +
+    block)``; lanes outside the series are invalid pads.  ``start`` /
+    ``end`` hold each core lane's window as block lanes, ``out_index``
+    each flat row's place in the ``[rows, Lc]`` outputs."""
+
+    def __init__(self, layout, w, core, behind, halo, G):
+        lens = layout.lengths
+        n_ch = -(-lens // core)
+        ch_base = np.concatenate([[0], np.cumsum(n_ch)[:-1]])
+        used = int(n_ch.sum())
+        self.rows = max(G, -(-used // G) * G)
+        self.core, self.block = core, core + halo
+        starts = layout.starts[:-1]
+        key = layout.key_ids
+        pos = np.arange(layout.n_rows, dtype=np.int64) - starts[key]
+        self.out_index = np.repeat(ch_base * core - starts, lens) + \
+            np.arange(layout.n_rows, dtype=np.int64)
+        # each series' stretch of the block buffer: ``behind`` pads,
+        # its rows, then pads up to whole cores with at least one halo
+        # after the last core, so chunk c's block starts at the
+        # stretch's start plus c cores
+        extra = -(-halo // core)
+        seg = np.where(n_ch > 0, (n_ch + extra) * core, 0)
+        seg_base = np.concatenate([[0], np.cumsum(seg)[:-1]])
+        self.buf_len = int(seg.sum())
+        self.buf_index = np.repeat(seg_base + behind - starts, lens) + \
+            np.arange(layout.n_rows, dtype=np.int64)
+        self.block_row = (np.repeat(seg_base // core, n_ch)
+                          + np.arange(used) - np.repeat(ch_base, n_ch))
+        start, end = packing.layout_window_bounds(layout, w)
+        lane0 = pos - pos % core - behind      # series lane of block lane 0
+        bounds = np.zeros((2, self.rows * core), np.int32)
+        bounds[0, self.out_index] = start - lane0
+        bounds[1, self.out_index] = end - lane0
+        self.start = bounds[0].reshape(self.rows, core)
+        self.end = bounds[1].reshape(self.rows, core)
+        self.series_rows = n_ch
+        self.key = key
+
+    def blocks(self, values, valid):
+        """``([rows, block] values, [rows, block] validity, [rows, 1]
+        centre)`` of one column (flat sorted rows)."""
+        from numpy.lib.stride_tricks import sliding_window_view
+
+        dt = values.dtype
+        x = np.where(valid, values, 0).astype(dt)
+        n = np.bincount(self.key, weights=valid,
+                        minlength=len(self.series_rows))
+        total = np.bincount(self.key, weights=x,
+                            minlength=len(self.series_rows))
+        center = (total / np.maximum(n, 1)).astype(dt)
+        out = []
+        for arr in (x, valid):
+            buf = np.zeros(self.buf_len + self.block, arr.dtype)
+            buf[self.buf_index] = arr
+            win = sliding_window_view(buf, self.block)[::self.core]
+            blk = np.zeros((self.rows, self.block), arr.dtype)
+            blk[:len(self.block_row)] = win[self.block_row]
+            out.append(blk)
+        c = np.zeros((self.rows, 1), dt)
+        c[:len(self.block_row), 0] = np.repeat(center, self.series_rows)
+        return out[0], out[1], c
 
 
 def _bucket_ns(ts_ns: np.ndarray, freq_sec: int) -> np.ndarray:
@@ -272,7 +412,16 @@ def ema(tsdf, colName: str, window: int = 30, exp_factor: float = 0.2,
         if exact:
             from tempo_tpu.ops import pallas_kernels as pk
 
-            y = pk.ema_scan(jnp.asarray(v), jnp.asarray(m), exp_factor)
+            # series too long for one VMEM block take the carry-passing
+            # chunked kernel, in calls of one shape; the rest the whole
+            # series at once
+            chunked = pk.ema_chunked_ok(v)
+            lanes = (pk.ema_chunked_lanes(*v.shape) if chunked
+                     else v.size)
+            with span("tempo.dispatch", rows=lanes):
+                y = (pk.ema_chunked(v, m, exp_factor) if chunked else
+                     pk.ema_scan(jnp.asarray(v), jnp.asarray(m),
+                                 exp_factor))
         else:
             y = rk.ema_compat(jnp.asarray(v), jnp.asarray(m), n_taps,
                               float(exp_factor))

@@ -101,6 +101,16 @@ def _ema(sh, L):
     return pk._ema_call.lower(x, v, _s(sh, (), jnp.float32))
 
 
+def _ema_chunk(sh, L):
+    """The carry-passing EMA over the quickstart's 9 series, one chunk
+    of its plan's width (whatever ``L``): one grid step, the whole
+    kernel body."""
+    _, _, Lc = pk.ema_chunk_plan(9)
+    x, v = _planes(sh, Lc, jnp.float32, jnp.bool_, K=9)
+    return pk._ema_chunk_call.lower(x, v, _s(sh, (9, pk.LANE), jnp.float32),
+                                    _s(sh, (1,), jnp.float32))
+
+
 def _cumsum3(sh, L):
     x, v = _planes(sh, L, jnp.float32, jnp.bool_)
     return pk._cumsum3_call.lower(x, v)
@@ -200,6 +210,7 @@ def _ring(sh, L):
 
 KERNELS = {
     "pallas_kernels._ema_call": _ema,
+    "pallas_kernels._ema_chunk_call": _ema_chunk,
     "pallas_kernels._cumsum3_call": _cumsum3,
     "pallas_kernels._last_valid_call": _last_valid,
     "pallas_kernels._index_scan_call": _index_scan,
@@ -221,6 +232,22 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = KERNELS[name](one_chip, LANES).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_range_chunk_program_compiles_for_v5e(one_chip):
+    """The lane-chunked range-stats program (plain XLA, no kernel) at
+    the plan of the full-size quickstart chain: 10 s windows of ~12k
+    rows back and ~1k tie rows ahead."""
+    from tempo_tpu.ops import rolling as rk
+
+    block, halo, nlev = rk.range_chunk_plan(12000, 1100)
+    G, core = rk.RANGE_CHUNK_ROWS, block - halo
+    rk.range_stats_chunk.lower(
+        _s(one_chip, (G, block), jnp.float32),
+        _s(one_chip, (G, block), jnp.bool_),
+        _s(one_chip, (G, core), jnp.int32), _s(one_chip, (G, core), jnp.int32),
+        _s(one_chip, (G, 1), jnp.float32), _s(one_chip, (), jnp.int32),
+        nlev=nlev).compile()
 
 
 def main() -> int:
