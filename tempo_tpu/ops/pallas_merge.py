@@ -1194,11 +1194,11 @@ def _chunked_call(keys, payload, n_payload, n_out, Cm, segmented,
 
 
 def _split_ts_np(ts):
-    """Numpy mirror of ``_split_ts``."""
-    ts = ts.astype(np.int64)
-    hi = (ts >> 32).astype(np.int32)
-    lo = ((ts & 0xFFFFFFFF) - (1 << 31)).astype(np.int32)
-    return hi, lo
+    """Numpy mirror of ``_split_ts``, read off the two 32-bit words:
+    ``hi`` is the high word (a strided view, no copy) and the
+    bias-corrected ``lo`` the low word with its top bit flipped."""
+    words = np.ascontiguousarray(ts, dtype="<i8").view("<i4")
+    return words[..., 1::2], words[..., 0::2] ^ np.int32(-(2**31))
 
 
 def _seq_key_planes_np(seq):
@@ -1214,15 +1214,6 @@ def _seq_key_planes_np(seq):
                          np.int64(-(2**31)) - b.astype(np.int64)
                          ).astype(np.int32)]
     raise TypeError(f"unsupported sequence dtype {seq.dtype}")
-
-
-def _scatter_into(base, src, dest):
-    """In-place scatter of real lanes into an already-filled chunked
-    plane (``dest`` from packing.asof_chunk_plan; -1 entries dropped)."""
-    rows = np.broadcast_to(np.arange(base.shape[0])[:, None], dest.shape)
-    m = dest >= 0
-    base[rows[m], dest[m]] = src[m]
-    return base
 
 
 def _require_concrete(name, a):
@@ -1246,9 +1237,12 @@ def asof_merge_values_chunked(l_ts, r_ts, r_valids, r_values,
     single-plan kernel never supported), at any length under 2^24
     merged rows per lane row.
 
-    Host-orchestrated: the merge-path chunk split and the chunk-major
-    scatter/unscatter are numpy (packing.asof_chunk_plan — the same
-    cost class as the packing every join already pays), the join itself
+    Host-orchestrated: the merge-path chunk split is one vectorised
+    bisection per (row, chunk boundary) (packing.asof_chunk_plan — no
+    row is sorted or searched), the chunk-major planes are slice copies
+    of each chunk's two runs (packing.chunk_layout_plane) and the
+    unscatter a numpy gather, all cheaper than the packing every join
+    already pays; the join itself
     is ONE pallas_call gridded (row blocks × chunks) with the fill
     state carried across chunks in VMEM scratch.  HBM traffic stays
     one read + one write of the (≤2x padded) chunk layout regardless
@@ -1309,9 +1303,12 @@ def build_chunked_planes(l_ts, r_ts, r_valids, r_values,
                          skip_nulls: bool = True,
                          max_lookback: int = 0,
                          chunk_lanes=None):
-    """Host side of the chunked engine: chunk plan + key/payload plane
-    construction.  Split out so bench.py can time the device program
-    on prebuilt planes.  Returns ``(keys, planes, plan, meta)``."""
+    """Host side of the chunked engine: chunk plan (per-chunk run
+    bounds) + key/payload plane construction, each plane written chunk
+    by chunk with slice copies (``packing.chunk_layout_plane``), each
+    source converted to its plane dtype at most once.  Split out so
+    bench.py can time the device program on prebuilt planes.  Returns
+    ``(keys, planes, plan, meta)``."""
     from tempo_tpu import packing
 
     l_ts = _require_concrete("l_ts", l_ts)
@@ -1338,7 +1335,7 @@ def build_chunked_planes(l_ts, r_ts, r_valids, r_values,
         r_sid = np.asarray(r_sid)
 
     ls = rs = None
-    nsq = 0
+    seq_pairs = []
     if l_seq is not None or r_seq is not None:
         l_seq_k = seq_kernel_form(jnp.asarray(l_seq)) \
             if l_seq is not None else None
@@ -1353,10 +1350,10 @@ def build_chunked_planes(l_ts, r_ts, r_valids, r_values,
             np.asarray(l_seq_k) if l_seq_k is not None else None,
             np.asarray(r_seq_k) if r_seq_k is not None else None,
             K, Ll, Lr)
-        nsq = len(_seq_key_planes_np(ls))
+        seq_pairs = list(zip(_seq_key_planes_np(ls), _seq_key_planes_np(rs)))
 
     n_keys, n_payload, n_out = _chunk_plane_counts(
-        C, nsq, segmented, keyed, ml)
+        C, len(seq_pairs), segmented, keyed, ml)
     Cm = _plan_chunk_lanes(n_payload, n_keys,
                            chunk_lanes or join_chunk_lanes_override())
     if Cm is None:
@@ -1364,54 +1361,39 @@ def build_chunked_planes(l_ts, r_ts, r_valids, r_values,
             f"chunked asof merge infeasible: no chunk width fits "
             f"{n_payload} payload + {n_keys} key planes in VMEM")
     plan = packing.asof_chunk_plan(l_ts, r_ts, Cm, l_sid, r_sid, ls, rs)
-    nc, S, W = plan.n_chunks, plan.chunk_rows, plan.n_chunks * Cm
+    nc = plan.n_chunks
     imax = np.int32(_I32_MAX)
+    layout = functools.partial(packing.chunk_layout_plane, plan)
 
     keys = []
     if segmented:
-        sid_pl = np.repeat(plan.chunk_pad_sid, Cm,
-                           axis=1).astype(np.int32)
-        _scatter_into(sid_pl, l_sid.astype(np.int32), plan.l_dest)
-        _scatter_into(sid_pl, r_sid.astype(np.int32), plan.r_dest)
-        keys.append(sid_pl)
-    for (a, b) in zip(_split_ts_np(l_ts), _split_ts_np(r_ts)):
-        p = np.full((K, W), imax, np.int32)
-        _scatter_into(p, a, plan.l_dest)
-        _scatter_into(p, b, plan.r_dest)
-        keys.append(p)
-    if nsq:
-        for pa, pb in zip(_seq_key_planes_np(ls), _seq_key_planes_np(rs)):
-            p = np.full((K, W), imax, np.int32)
-            _scatter_into(p, pa, plan.l_dest)
-            _scatter_into(p, pb, plan.r_dest)
-            keys.append(p)
+        keys.append(layout(l_sid, r_sid, plan.chunk_pad_sid, np.int32))
+    for a, b in [*zip(_split_ts_np(l_ts), _split_ts_np(r_ts)), *seq_pairs]:
+        keys.append(layout(a, b, imax, np.int32))
     # the side/pos plane is a pure function of the chunk layout: left
     # half ascending above _SIDE, right half the pre-reversal iota
     w = np.tile(np.arange(Cm, dtype=np.int32), nc)
     sec = np.where(w < Cm // 2, _SIDE + w, Cm - 1 - w).astype(np.int32)
-    keys.append(np.ascontiguousarray(np.broadcast_to(sec, (K, W))))
+    keys.append(np.ascontiguousarray(np.broadcast_to(sec, (K, nc * Cm))))
 
-    val_srcs = [
-        np.where(r_valids[c], r_values[c].astype(np.float32),
-                 np.float32(np.nan)).astype(np.float32)
-        for c in range(C)
-    ]
-    rscat = lambda src: packing.chunk_scatter(
-        src.astype(np.float32), plan.r_dest, W, np.nan, np.float32)
-    planes = [rscat(src) for src in val_srcs]
-    planes.append(rscat(np.ascontiguousarray(np.broadcast_to(
-        np.arange(Lr, dtype=np.float32), (K, Lr)))))
+    nan = np.float32(np.nan)
+    val_srcs = [np.where(r_valids[c],
+                         r_values[c].astype(np.float32, copy=False), nan)
+                for c in range(C)]
+    rplane = lambda src: layout(None, src, nan, np.float32)
+    planes = [rplane(src) for src in val_srcs]
+    planes.append(rplane(np.broadcast_to(
+        np.arange(Lr, dtype=np.float32), (K, Lr))))
     if ml:
         rpos = plan.r_pos.astype(np.float32)
         if keyed:
-            planes.append(rscat(rpos))
+            planes.append(rplane(rpos))
         else:
             # each channel's psrc shares its value plane's NaN pattern
             # exactly, so the independent fills stay in lockstep pairs
-            planes.extend(
-                rscat(np.where(np.isnan(src), np.float32(np.nan), rpos))
-                for src in val_srcs)
-            planes.append(rscat(rpos))
+            planes.extend(rplane(np.where(np.isnan(src), nan, rpos))
+                          for src in val_srcs)
+            planes.append(rplane(rpos))
 
     meta = {"C": C, "n_keys": n_keys, "n_payload": n_payload,
             "n_out": n_out}
@@ -1481,10 +1463,9 @@ def asof_merge_indices_chunked(l_ts, r_ts, r_valids, l_lane,
 
     r_valids = np.asarray(r_valids)
     C, K, Lr = r_valids.shape
-    with span("tempo.pack", rows=r_valids.size):
-        pos = np.ascontiguousarray(np.broadcast_to(
-            np.arange(Lr, dtype=np.float32), (K, Lr)))
-        planes = np.ascontiguousarray(np.broadcast_to(pos, (C, K, Lr)))
+    # every channel's payload is the right row's position (a view: the
+    # plane build reads it once, masked by each channel's validity)
+    planes = np.broadcast_to(np.arange(Lr, dtype=np.float32), (C, K, Lr))
     out, plan, _ = _chunked_run(
         l_ts, r_ts, r_valids, planes, l_sid, r_sid, l_seq, r_seq, True,
         max_lookback, chunk_lanes, interpret)

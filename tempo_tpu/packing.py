@@ -20,6 +20,7 @@ divergence: int64 ns is exact where Spark's double cast is not.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -589,34 +590,146 @@ class AsofChunkPlan:
 
     The streaming merge kernel (ops/pallas_merge.py chunked form) grids
     over the merged-lane axis: chunk ``c`` of a lane row holds merged
-    rows [c*S, (c+1)*S) of that row's (ts [, seq], side) total order —
-    the exact split points are per-row data, so the host computes them
-    once (numpy searchsorted over the already-sorted packed sides, the
-    same cost class as the packing itself) and scatters both sides into
-    a ``[K, n_chunks * Cm]`` chunk-major layout, ``Cm = 2 * S`` lanes
-    per chunk: ``[left rows (<= S, ascending) | reversed right rows
-    (<= S)]`` — a bitonic sequence per chunk, like the single-plan
-    layout per full row.  Greedy packing guarantees every chunk before
-    a non-empty one is full, so a real slot's global merged position is
-    ``c * S + lane`` (what the maxLookback horizon counts).
+    rows [c*S, (c+1)*S) of that row's (sid?, ts, seq?, side) total
+    order.  Both sides arrive sorted, so each chunk holds one contiguous
+    run of each side, and the plan is just the run bounds:
+    ``l_bounds[k, c]`` left rows of row ``k`` lie among its first
+    ``c*S`` merged rows, ``r_bounds[k, c] = min(c*S, n_k) - l_bounds``
+    right rows (both ``[K, n_chunks + 1]``, the last column the row's
+    counts).  The chunk-major ``[K, n_chunks * Cm]`` layout, ``Cm = 2 *
+    S`` lanes per chunk, is ``[left run (ascending) | pad | right run
+    (reversed)]`` — a bitonic sequence per chunk, like the single-plan
+    layout per full row — built by slice copies
+    (:func:`chunk_layout_plane`).  Greedy packing guarantees every chunk
+    before a non-empty one is full, so a real slot's global merged
+    position is ``c * S + lane`` (what the maxLookback horizon counts).
 
-    ``l_dest``/``r_dest`` are lane destinations inside [K, n_chunks*Cm]
-    (-1 at padding); ``l_out`` the destination inside the kernel's
-    [K, n_chunks*S] output; ``r_pos`` each right row's global merged
-    position (the psrc planes of the maxLookback form);
-    ``chunk_pad_sid`` the per-(row, chunk) series id given to pad
+    ``chunk_pad_sid`` is the per-(row, chunk) series id given to pad
     lanes so the segmented fill flows into the chunk tail and the
     cross-chunk carry can be read at the last lane (SID_PAD when the
-    chunk is empty)."""
+    chunk is empty).  The per-row arrays are derived on first read, for
+    the consumers that want them: ``l_dest``/``r_dest`` lane
+    destinations inside [K, n_chunks*Cm] (-1 at padding), ``l_out`` the
+    destination inside the kernel's [K, n_chunks*S] output, ``r_pos``
+    each right row's global merged position (the psrc planes of the
+    maxLookback form)."""
 
     n_chunks: int
     chunk_rows: int                 # S = real merged rows per full chunk
     merged_lanes: int               # Cm = 2 * S (power of two)
-    l_dest: np.ndarray              # [K, Ll] int64, -1 pads
-    r_dest: np.ndarray              # [K, Lr] int64, -1 pads
-    l_out: np.ndarray               # [K, Ll] int64, -1 pads
-    r_pos: np.ndarray               # [K, Lr] int64, -1 pads
+    l_bounds: np.ndarray            # [K, n_chunks + 1] int64
+    r_bounds: np.ndarray            # [K, n_chunks + 1] int64
     chunk_pad_sid: Optional[np.ndarray]   # [K, n_chunks] int32 or None
+    # flat composite merge keys (sid?, ts, seq?), major first, per side
+    merge_keys: Tuple[list, list] = dataclasses.field(repr=False)
+    l_width: int                    # Ll
+    r_width: int                    # Lr
+
+    @functools.cached_property
+    def _l_lanes(self):
+        return _lane_chunks(self.l_bounds, self.l_width)
+
+    @functools.cached_property
+    def _r_lanes(self):
+        return _lane_chunks(self.r_bounds, self.r_width)
+
+    @functools.cached_property
+    def l_dest(self) -> np.ndarray:          # [K, Ll] int64, -1 pads
+        c, rank = self._l_lanes
+        return np.where(c >= 0, c * self.merged_lanes + rank, -1)
+
+    @functools.cached_property
+    def r_dest(self) -> np.ndarray:          # [K, Lr] int64, -1 pads
+        c, rank = self._r_lanes
+        return np.where(c >= 0, (c + 1) * self.merged_lanes - 1 - rank, -1)
+
+    @functools.cached_property
+    def l_out(self) -> np.ndarray:           # [K, Ll] int64, -1 pads
+        c, rank = self._l_lanes
+        return np.where(c >= 0, c * self.chunk_rows + rank, -1)
+
+    @functools.cached_property
+    def r_pos(self) -> np.ndarray:           # [K, Lr] int64, -1 pads
+        """Right row ``b`` of chunk ``c`` follows exactly the left rows
+        ordered before it, and those are bounded by the chunk's left
+        run: one bisection inside that run, per right row."""
+        c, _ = self._r_lanes
+        K, Lr = c.shape
+        k, b = np.nonzero(c >= 0)
+        cc = c[k, b]
+        l_keys, r_keys = self.merge_keys
+        rf = k * Lr + b
+        before = _bisect(
+            self.l_bounds[k, cc], self.l_bounds[k, cc + 1],
+            lambda sel, i: _left_first(l_keys, r_keys,
+                                       k[sel] * self.l_width + i, rf[sel]))
+        out = np.full((K, Lr), -1, np.int64)
+        out[k, b] = b + before
+        return out
+
+
+def _bisect(lo, hi, first) -> np.ndarray:
+    """Vectorised bisection: per element ``e``, the least ``i`` in
+    ``[lo[e], hi[e])`` where the monotone predicate ``first(sel, i)``
+    (True…True False…False along ``i``, evaluated for the elements
+    ``sel``) is False, ``hi[e]`` where it never is."""
+    lo = np.array(lo, np.int64)
+    hi = np.array(hi, np.int64)
+    act = np.flatnonzero(lo < hi)
+    while act.size:
+        mid = (lo[act] + hi[act]) >> 1
+        t = first(act, mid)
+        lo[act[t]] = mid[t] + 1
+        hi[act[~t]] = mid[~t]
+        act = act[lo[act] < hi[act]]
+    return lo
+
+
+def _left_first(l_keys, r_keys, lf, rf) -> np.ndarray:
+    """Whether left row ``lf`` precedes right row ``rf`` (flat lanes) in
+    the merged order: its composite key is strictly smaller — a full
+    tie puts the right row first."""
+    lt = np.zeros(len(lf), bool)
+    eq = np.ones(len(lf), bool)
+    for lk, rk in zip(l_keys, r_keys):
+        a, b = lk[lf], rk[rf]
+        lt |= eq & (a < b)
+        eq &= a == b
+    return lt
+
+
+def _per_lane(bounds: np.ndarray, width: int, per_chunk, tail) -> np.ndarray:
+    """A per-(row, chunk) value spread over every lane of that chunk's
+    run of a packed ``[K, width]`` side (``tail`` past the side's real
+    rows): one repeat, flat ``[K * width]``."""
+    K = bounds.shape[0]
+    counts = np.concatenate(
+        [np.diff(bounds, axis=1), width - bounds[:, -1:]], axis=1)
+    vals = np.concatenate(
+        [np.broadcast_to(per_chunk, (K, bounds.shape[1] - 1)),
+         np.full((K, 1), tail, np.int64)], axis=1)
+    return np.repeat(vals.ravel(), counts.ravel())
+
+
+def _lane_chunks(bounds: np.ndarray, width: int):
+    """Chunk index (-1 past the side's real rows) and rank inside the
+    chunk's run of every lane of a packed ``[K, width]`` side."""
+    K, n1 = bounds.shape
+    c = _per_lane(bounds, width, np.arange(n1 - 1, dtype=np.int64), -1)
+    start = _per_lane(bounds, width, bounds[:, :-1], 0)
+    return (c.reshape(K, width),
+            np.arange(width, dtype=np.int64) - start.reshape(K, width))
+
+
+def _run_last(side: np.ndarray, bounds: np.ndarray) -> np.ndarray:
+    """Per (row, chunk): the side's value at the run's last row, -1
+    where the chunk holds none of the side."""
+    end = bounds[:, 1:]
+    has = end > bounds[:, :-1]
+    if not has.any():
+        return np.full(end.shape, -1, np.int64)
+    last = np.take_along_axis(side, np.where(has, end - 1, 0), axis=1)
+    return np.where(has, last.astype(np.int64), -1)
 
 
 def _seq_merge_sides_np(l_seq, r_seq, K, Ll, Lr):
@@ -629,7 +742,7 @@ def _seq_merge_sides_np(l_seq, r_seq, K, Ll, Lr):
     ls = l_seq if l_seq is not None else np.full((K, Ll), neg, sdt)
     rs = r_seq if r_seq is not None else np.full((K, Lr), neg, sdt)
     pdt = np.promote_types(ls.dtype, rs.dtype)
-    return ls.astype(pdt), rs.astype(pdt)
+    return ls.astype(pdt, copy=False), rs.astype(pdt, copy=False)
 
 
 def asof_chunk_plan(
@@ -644,10 +757,15 @@ def asof_chunk_plan(
     """Split packed AS-OF sides along each row's merged stream.
 
     REQUIRES the packed-layout invariant (real rows lead, ascending in
-    (sid?, ts, seq); TS_PAD tails).  The merged order replicated here —
-    lexicographic (sid?, ts, seq, side) with right rows before left on
-    full ties, stable within a side — must match the kernels' key-plane
-    order exactly or chunk boundaries would disagree with the fill."""
+    (sid?, ts, seq); TS_PAD tails).  The merged order — lexicographic
+    (sid?, ts, seq, side) with right rows before left on full ties,
+    stable within a side — must match the kernels' key-plane order
+    exactly or chunk boundaries would disagree with the fill.  No row
+    is sorted or searched: each side's real-row count and each chunk
+    boundary's left count is one merge-path bisection, vectorised over
+    every (row, boundary)."""
+    l_ts = np.asarray(l_ts)
+    r_ts = np.asarray(r_ts)
     K, Ll = l_ts.shape
     Lr = r_ts.shape[1]
     Cm = int(merged_lanes)
@@ -660,93 +778,110 @@ def asof_chunk_plan(
             np.asarray(l_seq) if l_seq is not None else None,
             np.asarray(r_seq) if r_seq is not None else None, K, Ll, Lr)
 
-    l_real = np.asarray(l_ts) < TS_REAL_MAX
-    r_real = np.asarray(r_ts) < TS_REAL_MAX
-    l_counts = l_real.sum(axis=1)
-    r_counts = r_real.sum(axis=1)
-    n_chunks = max(int(-(-int((l_counts + r_counts).max(initial=0)) // S)),
-                   1)
-
-    l_dest = np.full((K, Ll), -1, np.int64)
-    r_dest = np.full((K, Lr), -1, np.int64)
-    l_out = np.full((K, Ll), -1, np.int64)
-    r_pos = np.full((K, Lr), -1, np.int64)
-    pad_sid = (np.full((K, n_chunks), -1, np.int64) if segmented else None)
-
-    for k in range(K):
-        nl, nr = int(l_counts[k]), int(r_counts[k])
-        n = nl + nr
-        if n == 0:
-            continue
-        ts = np.concatenate([l_ts[k, :nl], r_ts[k, :nr]])
-        side = np.concatenate([np.ones(nl, np.int8), np.zeros(nr, np.int8)])
-        lex = [side]
-        if l_seq is not None:
-            lex.append(np.concatenate([l_seq[k, :nl], r_seq[k, :nr]]))
-        lex.append(ts)
-        if segmented:
-            lex.append(np.concatenate([l_sid[k, :nl], r_sid[k, :nr]]))
-        order = np.lexsort(tuple(lex))
-        mpos = np.empty(n, np.int64)
-        mpos[order] = np.arange(n, dtype=np.int64)
-        l_mpos, r_mpos = mpos[:nl], mpos[nl:]
-
-        lc = l_mpos // S
-        rc = r_mpos // S
-        # within-chunk per-side rank: both sides' mpos are ascending
-        # (each side was sorted and the merge is stable), so the first
-        # same-side row of a chunk is one searchsorted away
-        l_rank = np.arange(nl) - np.searchsorted(l_mpos, lc * S)
-        r_rank = np.arange(nr) - np.searchsorted(r_mpos, rc * S)
-        l_dest[k, :nl] = lc * Cm + l_rank
-        # the right part sits reversed at the chunk tail (the bitonic
-        # [ascending | descending] precondition): ascending rank j
-        # lands at offset S + (S - 1 - j)
-        r_dest[k, :nr] = rc * Cm + (2 * S - 1 - r_rank)
-        l_out[k, :nl] = lc * S + l_rank
-        r_pos[k, :nr] = r_mpos
-        if segmented:
-            sid_sorted = np.concatenate(
-                [l_sid[k, :nl], r_sid[k, :nr]])[order]
-            np.maximum.at(pad_sid[k], np.arange(n, dtype=np.int64) // S,
-                          sid_sorted.astype(np.int64))
-
+    flat = lambda a: np.ascontiguousarray(a).reshape(-1)
+    l_flat, r_flat = flat(l_ts), flat(r_ts)
+    l_keys, r_keys = [l_flat], [r_flat]
     if segmented:
+        l_keys.insert(0, flat(l_sid))
+        r_keys.insert(0, flat(r_sid))
+    if l_seq is not None:
+        l_keys.append(flat(l_seq))
+        r_keys.append(flat(r_seq))
+
+    # real rows lead each side: the count is where ts reaches the pads
+    rows = np.arange(K, dtype=np.int64)
+    zero = np.zeros(K, np.int64)
+    l_counts = _bisect(zero, np.full(K, Ll, np.int64),
+                       lambda sel, i: l_flat[sel * Ll + i] < TS_REAL_MAX)
+    r_counts = _bisect(zero, np.full(K, Lr, np.int64),
+                       lambda sel, i: r_flat[sel * Lr + i] < TS_REAL_MAX)
+    n = l_counts + r_counts
+    n_chunks = max(int(-(-int(n.max(initial=0)) // S)), 1)
+
+    # merge path: the first p merged rows of row k hold i left rows,
+    # i the least in [p - nr, min(p, nl)] whose left row does not
+    # precede right row p - 1 - i
+    p = np.minimum(np.arange(n_chunks + 1, dtype=np.int64)[None, :] * S,
+                   n[:, None])
+    k = np.broadcast_to(rows[:, None], p.shape).ravel()
+    pf = p.ravel()
+    l_bounds = _bisect(
+        np.maximum(pf - r_counts[k], 0), np.minimum(pf, l_counts[k]),
+        lambda sel, i: _left_first(l_keys, r_keys, k[sel] * Ll + i,
+                                   k[sel] * Lr + pf[sel] - 1 - i),
+    ).reshape(p.shape)
+    r_bounds = p - l_bounds
+
+    pad_sid = None
+    if segmented:
+        # sid ascends along a packed row: a chunk's largest series id
+        # is at the last row of one of its two runs
+        pad_sid = np.maximum(_run_last(np.asarray(l_sid), l_bounds),
+                             _run_last(np.asarray(r_sid), r_bounds))
         pad_sid = np.where(pad_sid < 0, np.int64(SID_PAD),
                            pad_sid).astype(np.int32)
     return AsofChunkPlan(
         n_chunks=n_chunks, chunk_rows=S, merged_lanes=Cm,
-        l_dest=l_dest, r_dest=r_dest, l_out=l_out, r_pos=r_pos,
-        chunk_pad_sid=pad_sid,
+        l_bounds=l_bounds, r_bounds=r_bounds, chunk_pad_sid=pad_sid,
+        merge_keys=(l_keys, r_keys), l_width=Ll, r_width=Lr,
     )
 
 
-def chunk_scatter(src: np.ndarray, dest: np.ndarray, width: int, fill,
-                  dtype=None) -> np.ndarray:
-    """Scatter per-row source lanes into the [K, width] chunked layout
-    (``dest`` from :func:`asof_chunk_plan`, -1 entries dropped)."""
-    K = src.shape[0]
-    out = np.full((K, width), fill, dtype=dtype or src.dtype)
-    rows = np.broadcast_to(np.arange(K)[:, None], dest.shape)
-    m = dest >= 0
-    out[rows[m], dest[m]] = src[m]
-    return out
+def chunk_layout_plane(plan: AsofChunkPlan, left, right, fill,
+                       dtype) -> np.ndarray:
+    """One ``[K, n_chunks * Cm]`` plane of the chunk layout, written
+    chunk by chunk with slice copies: the left run of ``left [K, Ll]``
+    ascending at the chunk's head, the right run of ``right [K, Lr]``
+    reversed at its tail, ``fill`` between — a scalar, or a ``[K,
+    n_chunks]`` array of one value per chunk.  ``left=None`` leaves the
+    head to ``fill`` as well (the payload planes, which only the right
+    side feeds).  Sources are cast on assignment, never copied whole."""
+    K = plan.l_bounds.shape[0]
+    nc, Cm = plan.n_chunks, plan.merged_lanes
+    out = np.empty((K, nc, Cm), dtype)
+    per_chunk = np.ndim(fill) == 2
+    l_b, r_b = plan.l_bounds.tolist(), plan.r_bounds.tolist()
+    for k in range(K):
+        lk, rk, ok = l_b[k], r_b[k], out[k]
+        fk = fill[k] if per_chunk else None
+        for c in range(nc):
+            a0, a1 = (lk[c], lk[c + 1]) if left is not None else (0, 0)
+            b0, b1 = rk[c], rk[c + 1]
+            if lk[c] == lk[nc] and b0 == rk[nc]:   # every row placed
+                ok[c:] = fk[c:, None] if per_chunk else fill
+                break
+            ch = ok[c]
+            tail = Cm - (b1 - b0)
+            if a1 > a0:
+                ch[:a1 - a0] = left[k, a0:a1]
+            ch[a1 - a0:tail] = fk[c] if per_chunk else fill
+            if b1 > b0:
+                ch[tail:] = right[k, b0:b1][::-1]
+    return out.reshape(K, nc * Cm)
 
 
 def chunk_take_index(plan: AsofChunkPlan, l_lane: np.ndarray) -> np.ndarray:
     """Flat position, in the kernel's [K, n_chunks * S] outputs, of every
     left row whose flat lane in the packed [K, Ll] left side is
-    ``l_lane`` (from :func:`binpack_dest`): built once per join, so each
-    output channel reaches left-row order in one ``np.take``."""
-    K = plan.l_out.shape[0]
-    row_base = np.arange(K, dtype=np.int64) * (plan.n_chunks * plan.chunk_rows)
-    return np.take((plan.l_out + row_base[:, None]).reshape(-1), l_lane)
+    ``l_lane`` (from :func:`binpack_dest`; real lanes only): built once
+    per join, so each output channel reaches left-row order in one
+    ``np.take``.  Left row ``a`` of chunk ``c`` of row ``k`` sits at
+    ``k*nc*S + c*S + a - l_bounds[k, c]``, an offset constant over the
+    chunk's run: one repeat of the per-chunk offsets, one take."""
+    K, n1 = plan.l_bounds.shape
+    Ll, S = plan.l_width, plan.chunk_rows
+    shift = (np.arange(K, dtype=np.int64)[:, None] * ((n1 - 1) * S - Ll)
+             + np.arange(n1 - 1, dtype=np.int64) * S - plan.l_bounds[:, :-1])
+    per_lane = _per_lane(plan.l_bounds, Ll, shift, 0)
+    take = np.take(per_lane, l_lane)
+    take += l_lane
+    return take
 
 
 def chunk_gather(plane: np.ndarray, dest: np.ndarray, fill,
                  dtype=None) -> np.ndarray:
-    """Inverse of :func:`chunk_scatter` for kernel outputs: read each
-    real lane's chunked destination back into the packed [K, L] form."""
+    """Read each real lane's chunked destination (``dest``, -1 entries
+    dropped) of a kernel output back into the packed [K, L] form."""
     K = dest.shape[0]
     out = np.full(dest.shape, fill, dtype=dtype or plane.dtype)
     rows = np.broadcast_to(np.arange(K)[:, None], dest.shape)

@@ -579,3 +579,341 @@ def test_chunked_ring_depth_bitwise(monkeypatch):
             np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(b),
                 err_msg=f"depth={depth}:{name}")
+
+
+# ----------------------------------------------------------------------
+# The chunk plan: per-chunk run bounds against the per-row lexsort
+# ----------------------------------------------------------------------
+
+def _lexsort_plan(l_ts, r_ts, Cm, l_sid=None, r_sid=None, l_seq=None,
+                  r_seq=None):
+    """The former planner, kept as the oracle: one lexsort of every
+    row's merged stream, per-row destinations from each row's merged
+    position, the pad sid a maximum over every merged row."""
+    from tempo_tpu import packing
+
+    K, Ll = l_ts.shape
+    Lr = r_ts.shape[1]
+    S = Cm // 2
+    segmented = l_sid is not None
+    if l_seq is not None or r_seq is not None:
+        l_seq, r_seq = packing._seq_merge_sides_np(l_seq, r_seq, K, Ll, Lr)
+    l_counts = (l_ts < packing.TS_REAL_MAX).sum(axis=1)
+    r_counts = (r_ts < packing.TS_REAL_MAX).sum(axis=1)
+    n_chunks = max(int(-(-int((l_counts + r_counts).max(initial=0)) // S)),
+                   1)
+    l_dest = np.full((K, Ll), -1, np.int64)
+    r_dest = np.full((K, Lr), -1, np.int64)
+    l_out = np.full((K, Ll), -1, np.int64)
+    r_pos = np.full((K, Lr), -1, np.int64)
+    pad_sid = np.full((K, n_chunks), -1, np.int64) if segmented else None
+    for k in range(K):
+        nl, nr = int(l_counts[k]), int(r_counts[k])
+        n = nl + nr
+        if n == 0:
+            continue
+        side = np.concatenate([np.ones(nl, np.int8), np.zeros(nr, np.int8)])
+        lex = [side]
+        if l_seq is not None:
+            lex.append(np.concatenate([l_seq[k, :nl], r_seq[k, :nr]]))
+        lex.append(np.concatenate([l_ts[k, :nl], r_ts[k, :nr]]))
+        if segmented:
+            lex.append(np.concatenate([l_sid[k, :nl], r_sid[k, :nr]]))
+        order = np.lexsort(tuple(lex))
+        mpos = np.empty(n, np.int64)
+        mpos[order] = np.arange(n, dtype=np.int64)
+        l_mpos, r_mpos = mpos[:nl], mpos[nl:]
+        lc, rc = l_mpos // S, r_mpos // S
+        l_rank = np.arange(nl) - np.searchsorted(l_mpos, lc * S)
+        r_rank = np.arange(nr) - np.searchsorted(r_mpos, rc * S)
+        l_dest[k, :nl] = lc * Cm + l_rank
+        r_dest[k, :nr] = rc * Cm + (2 * S - 1 - r_rank)
+        l_out[k, :nl] = lc * S + l_rank
+        r_pos[k, :nr] = r_mpos
+        if segmented:
+            sid_sorted = np.concatenate(
+                [l_sid[k, :nl], r_sid[k, :nr]])[order]
+            np.maximum.at(pad_sid[k], np.arange(n, dtype=np.int64) // S,
+                          sid_sorted.astype(np.int64))
+    if segmented:
+        pad_sid = np.where(pad_sid < 0, np.int64(packing.SID_PAD),
+                           pad_sid).astype(np.int32)
+    return dict(n_chunks=n_chunks, l_dest=l_dest, r_dest=r_dest,
+                l_out=l_out, r_pos=r_pos, chunk_pad_sid=pad_sid)
+
+
+def _scatter_planes(plan, l_ts, r_ts, r_valids, r_values, l_sid, r_sid,
+                    ls, rs, skip, ml):
+    """The former plane build, kept as the oracle: every plane a 2-D
+    fancy scatter through the oracle plan's per-row destinations."""
+    K = l_ts.shape[0]
+    Lr = r_ts.shape[1]
+    Cm = CHUNK
+    W = plan["n_chunks"] * Cm
+    imax = np.int32(2**31 - 1)
+
+    def scatter(base, src, dest):
+        rows = np.broadcast_to(np.arange(K)[:, None], dest.shape)
+        m = dest >= 0
+        base[rows[m], dest[m]] = src[m]
+        return base
+
+    keys = []
+    if l_sid is not None:
+        sid_pl = np.repeat(plan["chunk_pad_sid"], Cm, axis=1).astype(np.int32)
+        scatter(sid_pl, l_sid.astype(np.int32), plan["l_dest"])
+        keys.append(scatter(sid_pl, r_sid.astype(np.int32), plan["r_dest"]))
+    def split(ts):
+        ts = ts.astype(np.int64)
+        return [(ts >> 32).astype(np.int32),
+                ((ts & 0xFFFFFFFF) - (1 << 31)).astype(np.int32)]
+
+    def seq_planes(seq):
+        if seq.dtype == np.int32:
+            return [seq]
+        if seq.dtype == np.int64:
+            return split(seq)
+        b = seq.view(np.int32)
+        return [np.where(b >= 0, b.astype(np.int64),
+                         np.int64(-(2**31)) - b.astype(np.int64)
+                         ).astype(np.int32)]
+
+    pairs = list(zip(split(l_ts), split(r_ts)))
+    if ls is not None:
+        pairs += list(zip(seq_planes(ls), seq_planes(rs)))
+    for a, b in pairs:
+        p = np.full((K, W), imax, np.int32)
+        scatter(p, a, plan["l_dest"])
+        keys.append(scatter(p, b, plan["r_dest"]))
+
+    def rscat(src):
+        return scatter(np.full((K, W), np.nan, np.float32),
+                       src.astype(np.float32), plan["r_dest"])
+
+    val_srcs = [np.where(r_valids[c], r_values[c].astype(np.float32),
+                         np.float32(np.nan)) for c in range(len(r_values))]
+    planes = [rscat(src) for src in val_srcs]
+    planes.append(rscat(np.broadcast_to(np.arange(Lr, dtype=np.float32),
+                                        (K, Lr))))
+    if ml:
+        rpos = plan["r_pos"].astype(np.float32)
+        if not skip:
+            planes.append(rscat(rpos))
+        else:
+            planes.extend(rscat(np.where(np.isnan(s), np.float32(np.nan),
+                                         rpos)) for s in val_srcs)
+            planes.append(rscat(rpos))
+    return keys, planes
+
+
+def _sorted_side(rng, K, L, lengths, span, base):
+    ts = np.full((K, L), TS_PAD, np.int64)
+    for k in range(K):
+        ts[k, :lengths[k]] = np.sort(
+            base[k] + rng.integers(0, span, lengths[k]) * 10**9)
+    return ts
+
+
+def _seq_side(rng, ts, dtype):
+    """Seq per row ascending within each ts run, nulls (the dtype's
+    floor: -inf for floats) leading some runs."""
+    K, L = ts.shape
+    floating = np.issubdtype(dtype, np.floating)
+    lo, hi = ((-np.inf, np.inf) if floating
+              else (np.iinfo(dtype).min, np.iinfo(dtype).max))
+    seq = np.full((K, L), hi, dtype)
+    for k in range(K):
+        n = int((ts[k] < TS_PAD).sum())
+        raw = rng.integers(-3, 4, n)
+        null = rng.random(n) < 0.2
+        # ascending (ts, seq): nulls first inside every equal-ts run
+        order = np.lexsort((raw, ~null, ts[k, :n]))
+        seq[k, :n] = np.where(null[order], lo, raw[order])
+    return seq
+
+
+def _segmented_rows(rng, rows):
+    """Bin-packed sides from per-row lists of (left, right) series
+    lengths: series ids ascend along each row, then pads."""
+    from tempo_tpu.packing import SID_PAD
+
+    def side(j):
+        width = max(8, -(-max(sum(sp[j] for sp in r) for r in rows) // 8) * 8)
+        ts = np.full((len(rows), width), TS_PAD, np.int64)
+        sid = np.full((len(rows), width), SID_PAD, np.int32)
+        s = 0
+        for k, r in enumerate(rows):
+            at = 0
+            for sp in r:
+                n = sp[j]
+                ts[k, at:at + n] = np.sort(rng.integers(0, 30, n)) * 10**9
+                sid[k, at:at + n] = s
+                at += n
+                s += 1
+        return ts, sid
+
+    (l_ts, l_sid), (r_ts, r_sid) = side(0), side(1)
+    return l_ts, r_ts, l_sid, r_sid
+
+
+def _plan_case(name):
+    """(l_ts, r_ts, r_valids, r_values, l_sid, r_sid, l_seq, r_seq)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    S = CHUNK // 2
+    if name == "binpacked":
+        case = _binpacked_case(seed=13, S=23, Lmax=80)
+        lt2, rt2, lsid, rsid, rv2, rm2 = case[7:]
+        return lt2, rt2, rm2, rv2, lsid, rsid, None, None
+    if name == "segmented_edges":
+        # left-only and right-only series ending chunk runs, a chunk
+        # of left rows alone before the first right rows
+        l_ts, r_ts, l_sid, r_sid = _segmented_rows(rng, [
+            [(300, 0), (0, 90), (50, 60), (70, 0), (0, 40)],
+            [(130, 3), (5, 140), (60, 60)]])
+        Lr = r_ts.shape[1]
+        r_values = rng.standard_normal((2, 2, Lr)).astype(np.float32)
+        r_valids = (rng.random((2, 2, Lr)) > 0.3) & (r_ts < TS_PAD)
+        return l_ts, r_ts, r_valids, r_values, l_sid, r_sid, None, None
+    if name == "disjoint_runs":
+        # one side's rows all before the other's: chunks holding one
+        # side alone, then the other
+        K, L = 2, 3 * S
+        l_ts = _sorted_side(rng, K, L, [2 * S + 5, S + 20], 10,
+                            np.array([0, 100 * 10**9]))
+        r_ts = _sorted_side(rng, K, L, [S + 30, 2 * S + 1], 10,
+                            np.array([100 * 10**9, 0]))
+    elif name == "ties_at_edges":
+        # equal ts on both sides across and at every chunk edge
+        K, L = 3, 5 * S
+        lens = np.array([L, 3 * S + 17, 2 * S])
+        base = np.zeros(K, np.int64)
+        l_ts = _sorted_side(rng, K, L, lens, 6, base)
+        r_ts = _sorted_side(rng, K, L, lens[::-1], 6, base)
+    elif name == "ragged_empty":
+        # an empty row, a left-only row, a right-only row, a ragged one
+        K, L = 4, 3 * S
+        l_ts = _sorted_side(rng, K, L, [0, 2 * S + 5, 0, 3 * S], 40,
+                            np.zeros(K, np.int64))
+        r_ts = _sorted_side(rng, K, L, [0, 0, 3 * S, S + 3], 40,
+                            np.zeros(K, np.int64))
+    elif name == "exact_fill":
+        # row 0 fills its last chunk exactly, row 1 ends one row short
+        K, L = 2, 4 * S
+        l_ts = _sorted_side(rng, K, L, [2 * S + 7, 2 * S], 30,
+                            np.zeros(K, np.int64))
+        r_ts = _sorted_side(rng, K, L, [S - 7, 2 * S - 1], 30,
+                            np.zeros(K, np.int64))
+    else:                                   # seq_<dtype>[_both]
+        K, L = 3, 3 * S
+        lens = np.array([3 * S, 2 * S + 9, S])
+        base = np.zeros(K, np.int64)
+        l_ts = _sorted_side(rng, K, L, lens, 5, base)
+        r_ts = _sorted_side(rng, K, L, lens[::-1], 5, base)
+    C = 2
+    Lr = r_ts.shape[1]
+    r_values = rng.standard_normal((C, r_ts.shape[0], Lr)).astype(np.float32)
+    r_valids = (rng.random((C, r_ts.shape[0], Lr)) > 0.3) & (r_ts < TS_PAD)
+    l_seq = r_seq = None
+    if name.startswith("seq_"):
+        dt = np.dtype(name.split("_")[1])
+        r_seq = _seq_side(rng, r_ts, dt)
+        if name.endswith("_both"):
+            l_seq = _seq_side(rng, l_ts, dt)
+    return l_ts, r_ts, r_valids, r_values, None, None, l_seq, r_seq
+
+
+_PLAN_CASES = ["ties_at_edges", "ragged_empty", "exact_fill",
+               "disjoint_runs", "binpacked", "segmented_edges", "seq_int32",
+               "seq_int64", "seq_float32", "seq_float32_both"]
+
+
+@pytest.mark.parametrize("ml,skip", [(0, True), (5, True), (5, False)])
+@pytest.mark.parametrize("name", _PLAN_CASES)
+def test_chunk_plan_matches_lexsort_oracle(name, ml, skip):
+    """Run bounds from the merge-path bisection give the lexsort
+    planner's layout exactly: chunk count, every derived per-row
+    array, the pad sids, the take index and every uploaded plane
+    bitwise."""
+    from tempo_tpu import packing
+
+    (l_ts, r_ts, r_valids, r_values, l_sid, r_sid,
+     l_seq, r_seq) = _plan_case(name)
+    keys, planes, plan, _ = pm.build_chunked_planes(
+        l_ts, r_ts, r_valids, r_values, l_sid=l_sid, r_sid=r_sid,
+        l_seq=l_seq, r_seq=r_seq, skip_nulls=skip, max_lookback=ml,
+        chunk_lanes=CHUNK)
+    ls = rs = None
+    if l_seq is not None or r_seq is not None:
+        K, Ll, Lr = l_ts.shape[0], l_ts.shape[1], r_ts.shape[1]
+        ls, rs = packing._seq_merge_sides_np(
+            np.asarray(pm.seq_kernel_form(jnp.asarray(l_seq)))
+            if l_seq is not None else None,
+            np.asarray(pm.seq_kernel_form(jnp.asarray(r_seq)))
+            if r_seq is not None else None, K, Ll, Lr)
+    want = _lexsort_plan(l_ts, r_ts, CHUNK, l_sid, r_sid, ls, rs)
+    assert plan.n_chunks == want["n_chunks"]
+    assert plan.n_chunks >= 2, "the case must cross a chunk edge"
+    for f in ("l_out", "r_pos", "l_dest", "r_dest"):
+        np.testing.assert_array_equal(getattr(plan, f), want[f], err_msg=f)
+    if l_sid is None:
+        assert plan.chunk_pad_sid is None
+    else:
+        np.testing.assert_array_equal(plan.chunk_pad_sid,
+                                      want["chunk_pad_sid"])
+
+    real = np.flatnonzero((l_ts < TS_PAD).ravel())
+    K = l_ts.shape[0]
+    base = np.arange(K, dtype=np.int64)[:, None] * (plan.n_chunks * CHUNK // 2)
+    np.testing.assert_array_equal(
+        packing.chunk_take_index(plan, real),
+        (want["l_out"] + base).ravel()[real], err_msg="take index")
+
+    want_keys, want_planes = _scatter_planes(
+        want, l_ts, r_ts, r_valids, r_values, l_sid, r_sid, ls, rs,
+        skip, ml)
+    assert len(keys) == len(want_keys) + 1      # + the side/pos plane
+    assert len(planes) == len(want_planes)
+    for i, (a, b) in enumerate(zip(keys, want_keys)):
+        assert a.dtype == np.int32 and a.flags.c_contiguous
+        np.testing.assert_array_equal(a, b, err_msg=f"key plane {i}")
+    for i, (a, b) in enumerate(zip(planes, want_planes)):
+        assert a.dtype == np.float32 and a.flags.c_contiguous
+        np.testing.assert_array_equal(a.view(np.int32), b.view(np.int32),
+                                      err_msg=f"payload plane {i}")
+
+
+def test_chunk_plan_sorts_no_row(monkeypatch):
+    """The planner reads bounds by bisection: no sort, search or
+    scatter-maximum over rows may run inside it, and its bounds are
+    ``n_chunks + 1`` non-decreasing columns ending at the counts."""
+    import types
+
+    from tempo_tpu import packing
+
+    def boom(*a, **k):
+        raise AssertionError("per-row sort or search in the chunk plan")
+
+    class _NoAt:
+        def __call__(self, *a, **k):
+            return np.maximum(*a, **k)
+
+        at = staticmethod(boom)
+
+    cases = [_plan_case(n) for n in
+             ("ties_at_edges", "segmented_edges", "seq_float32_both")]
+    guarded = types.SimpleNamespace(**vars(np))
+    for f in ("lexsort", "argsort", "sort", "searchsorted"):
+        setattr(guarded, f, boom)
+    guarded.maximum = _NoAt()
+    monkeypatch.setattr(packing, "np", guarded)
+    for l_ts, r_ts, _, _, l_sid, r_sid, l_seq, r_seq in cases:
+        plan = packing.asof_chunk_plan(l_ts, r_ts, CHUNK, l_sid, r_sid,
+                                       l_seq, r_seq)
+        assert plan.n_chunks >= 2
+        for b, ts in ((plan.l_bounds, l_ts), (plan.r_bounds, r_ts)):
+            assert b.shape == (l_ts.shape[0], plan.n_chunks + 1)
+            assert (np.diff(b, axis=1) >= 0).all()
+            assert (b[:, 0] == 0).all()
+            assert (b[:, -1] == (ts < TS_PAD).sum(axis=1)).all()
+        assert ((np.diff(plan.l_bounds, axis=1)
+                 + np.diff(plan.r_bounds, axis=1)) <= CHUNK // 2).all()
