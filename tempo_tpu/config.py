@@ -125,10 +125,6 @@ KNOBS: Dict[str, Knob] = _knobs(
          "until a blocker); explicit = reshard around every such op, "
          "never eliminated; declarative = no plan nodes, each op keeps "
          "its internal all_to_all pair"),
-    Knob("TEMPO_TPU_MESH_DEVICES", "int", None, "bench.py",
-         "device-count ceiling of the --only-mesh-scaling bench sweep "
-         "(the 1->2->4->8 ladder is clipped here; unset = up to 8 or "
-         "the available device count)"),
     Knob("TEMPO_TPU_PLAN_CACHE_SIZE", "int", "64", "tempo_tpu/plan/cache",
          "LRU bound of the planner's compiled-executable cache "
          "(entries keyed by plan signature + shapes + mesh; 0 disables "
@@ -267,11 +263,6 @@ KNOBS: Dict[str, Knob] = _knobs(
          "streaming/placement stage, dying with a stage-named "
          "DeadlineExceeded; unset/0 = no deadline (the per-call "
          "retry-policy deadlines still bound individual IO retries)"),
-    Knob("TEMPO_TPU_CHAOS_ROWS", "int", None, "bench.py",
-         "row target of bench config 16's batch-plane chaos campaign "
-         "(--only-chaos-pipeline) in full mode; unset = 1e9 (the "
-         "ROADMAP billion-row out-of-core sweep), smoke mode ignores "
-         "it"),
     Knob("TEMPO_TPU_TUNE_PROFILE", "path|off", None, "tempo_tpu/tune",
          "tuned-knob profile source: a path to a harness-produced "
          "profile, 'off' to disable profile loading, unset = the "

@@ -58,7 +58,7 @@ Round 6 adds two engines past the single-shot VMEM plan (which capped
 the join at the ~205K merged-lane compiler-OOM cliff, VERDICT r5
 missing #1):
 
-* **Lane-chunked streaming merge** (``asof_merge_values_chunked``): the
+* **Lane-chunked streaming merge** (``asof_merge_indices_chunked``): the
   FlashAttention idiom applied to the join — grid over the merged-lane
   axis in VMEM-sized chunks (host merge-path split,
   packing.asof_chunk_plan), each chunk a full merge+ffill+unmerge
@@ -1221,80 +1221,8 @@ def _require_concrete(name, a):
         raise TypeError(
             f"the chunked asof engine builds its lane layout host-side "
             f"and requires concrete arrays ({name} is a tracer); inside "
-            f"jit/shard_map use asof_merge_values_bitonic instead")
+            f"jit/shard_map use asof_merge_indices_bitonic instead")
     return np.asarray(a)
-
-
-def asof_merge_values_chunked(l_ts, r_ts, r_valids, r_values,
-                              l_sid=None, r_sid=None,
-                              l_seq=None, r_seq=None,
-                              skip_nulls: bool = True,
-                              max_lookback: int = 0,
-                              chunk_lanes=None,
-                              interpret: bool = False):
-    """Lane-chunked streaming form of :func:`asof_merge_values_pallas`
-    — same contract and flag surface PLUS ``max_lookback`` (which the
-    single-plan kernel never supported), at any length under 2^24
-    merged rows per lane row.
-
-    Host-orchestrated: the merge-path chunk split is one vectorised
-    bisection per (row, chunk boundary) (packing.asof_chunk_plan — no
-    row is sorted or searched), the chunk-major planes are slice copies
-    of each chunk's two runs (packing.chunk_layout_plane) and the
-    unscatter a numpy gather, all cheaper than the packing every join
-    already pays; the join itself
-    is ONE pallas_call gridded (row blocks × chunks) with the fill
-    state carried across chunks in VMEM scratch.  HBM traffic stays
-    one read + one write of the (≤2x padded) chunk layout regardless
-    of length — the property the single-plan kernel had and the XLA
-    ladders lose.  Outputs are bit-identical to the single-plan kernel
-    and the XLA oracle: fills select values, they never compute."""
-    out, plan, meta = _chunked_run(
-        l_ts, r_ts, r_valids, r_values, l_sid, r_sid, l_seq, r_seq,
-        skip_nulls, max_lookback, chunk_lanes, interpret)
-    return chunked_outputs(out, plan, meta["C"], int(np.asarray(l_ts).shape[1]))
-
-
-def _chunked_run(l_ts, r_ts, r_valids, r_values, l_sid, r_sid, l_seq,
-                 r_seq, skip_nulls, max_lookback, chunk_lanes, interpret):
-    """Plan, pack and launch the chunked kernel: ``(out, plan, meta)``,
-    ``out`` the device outputs ``[K, n_chunks * S]`` f32 — the C value
-    channels, then the last-right-row channel."""
-    with span("tempo.pack", rows=np.size(l_ts) + np.size(r_ts)):
-        keys, planes, plan, meta = build_chunked_planes(
-            l_ts, r_ts, r_valids, r_values, l_sid=l_sid, r_sid=r_sid,
-            l_seq=l_seq, r_seq=r_seq, skip_nulls=skip_nulls,
-            max_lookback=max_lookback, chunk_lanes=chunk_lanes)
-    # every operand is 32-bit by construction, so the whole call can
-    # run in the 32-bit scope interpret mode needs (pk.interpret_scope)
-    ml = int(max_lookback or 0)
-    with pk.interpret_scope(interpret):
-        out = _chunked_call(
-            tuple(jnp.asarray(k) for k in keys),
-            tuple(jnp.asarray(x) for x in planes),
-            n_payload=meta["n_payload"], n_out=meta["n_out"],
-            Cm=plan.merged_lanes, segmented=l_sid is not None,
-            keyed_fill=not skip_nulls, chunk_rows=plan.chunk_rows,
-            windowed=ml > 0, ml=float(ml), depth=psr.dma_buffers(),
-            interpret=interpret,
-        )
-    return out, plan, meta
-
-
-def chunked_outputs(out, plan, C, Ll):
-    """Unscatter kernel outputs back to the packed [*, K, Ll] form."""
-    from tempo_tpu.packing import chunk_gather
-
-    K = plan.l_out.shape[0]
-    fetched = [np.asarray(o) for o in out]
-    with span("tempo.unpack", rows=plan.l_out.size):
-        outs = [chunk_gather(o, plan.l_out, np.nan, np.float32)
-                for o in fetched]
-        vals = (np.stack(outs[:C]) if C
-                else np.zeros((0, K, Ll), np.float32))
-        found = ~np.isnan(vals)
-        idx = np.where(np.isnan(outs[C]), -1, outs[C]).astype(np.int32)
-    return jnp.asarray(vals), jnp.asarray(found), jnp.asarray(idx)
 
 
 def build_chunked_planes(l_ts, r_ts, r_valids, r_values,
@@ -1306,8 +1234,9 @@ def build_chunked_planes(l_ts, r_ts, r_valids, r_values,
     """Host side of the chunked engine: chunk plan (per-chunk run
     bounds) + key/payload plane construction, each plane written chunk
     by chunk with slice copies (``packing.chunk_layout_plane``), each
-    source converted to its plane dtype at most once.  Split out so
-    bench.py can time the device program on prebuilt planes.  Returns
+    source converted to its plane dtype at most once.  Split from the
+    launch so ``tests/test_tpu_compile.py`` can compile the device
+    program (``_chunked_call``) on host-built planes.  Returns
     ``(keys, planes, plan, meta)``."""
     from tempo_tpu import packing
 
@@ -1447,9 +1376,22 @@ def asof_merge_indices_chunked(l_ts, r_ts, r_valids, l_lane,
                                max_lookback: int = 0,
                                chunk_lanes=None,
                                interpret: bool = False):
-    """Index-returning chunked sibling (position-encoded payloads, like
-    :func:`asof_merge_indices_pallas`) for the host join: ``(take,
-    planes)``, host arrays only.
+    """Lane-chunked streaming AS-OF merge, index-returning (position-
+    encoded payloads, like :func:`asof_merge_indices_pallas`) for the
+    host join: ``(take, planes)``, host arrays only.  Same contract and
+    flag surface as the single-plan kernel PLUS ``max_lookback``, at
+    any length under 2^24 merged rows per lane row.
+
+    Host-orchestrated: the merge-path chunk split is one vectorised
+    bisection per (row, chunk boundary) (packing.asof_chunk_plan — no
+    row is sorted or searched) and the chunk-major planes are slice
+    copies of each chunk's two runs (packing.chunk_layout_plane); the
+    join itself is ONE pallas_call gridded (row blocks × chunks) with
+    the fill state carried across chunks in VMEM scratch.  HBM traffic
+    stays one read + one write of the (≤2x padded) chunk layout
+    regardless of length.  Fills select values, they never compute, so
+    the positions are bit-identical to the single-plan kernel and the
+    XLA oracle.
 
     ``planes`` are the kernel's ``[K, n_chunks * S]`` f32 outputs of the
     channels the join reads — the C per-column last-valid channels
@@ -1461,14 +1403,29 @@ def asof_merge_indices_chunked(l_ts, r_ts, r_valids, l_lane,
     left-row order."""
     from tempo_tpu.packing import chunk_take_index
 
-    r_valids = np.asarray(r_valids)
+    r_valids = _require_concrete("r_valids", r_valids)
     C, K, Lr = r_valids.shape
     # every channel's payload is the right row's position (a view: the
     # plane build reads it once, masked by each channel's validity)
-    planes = np.broadcast_to(np.arange(Lr, dtype=np.float32), (C, K, Lr))
-    out, plan, _ = _chunked_run(
-        l_ts, r_ts, r_valids, planes, l_sid, r_sid, l_seq, r_seq, True,
-        max_lookback, chunk_lanes, interpret)
+    pos = np.broadcast_to(np.arange(Lr, dtype=np.float32), (C, K, Lr))
+    with span("tempo.pack", rows=np.size(l_ts) + np.size(r_ts)):
+        keys, planes, plan, meta = build_chunked_planes(
+            l_ts, r_ts, r_valids, pos, l_sid=l_sid, r_sid=r_sid,
+            l_seq=l_seq, r_seq=r_seq, max_lookback=max_lookback,
+            chunk_lanes=chunk_lanes)
+    # every operand is 32-bit by construction, so the whole call can
+    # run in the 32-bit scope interpret mode needs (pk.interpret_scope)
+    ml = int(max_lookback or 0)
+    with pk.interpret_scope(interpret):
+        out = _chunked_call(
+            tuple(jnp.asarray(k) for k in keys),
+            tuple(jnp.asarray(x) for x in planes),
+            n_payload=meta["n_payload"], n_out=meta["n_out"],
+            Cm=plan.merged_lanes, segmented=l_sid is not None,
+            keyed_fill=False, chunk_rows=plan.chunk_rows,
+            windowed=ml > 0, ml=float(ml), depth=psr.dma_buffers(),
+            interpret=interpret,
+        )
     fetched = [np.asarray(o) for o in (out[:C] if skip_nulls else out[C:])]
     with span("tempo.unpack"):
         take = chunk_take_index(plan, l_lane)

@@ -38,13 +38,16 @@ folded key planes carry the inter-pass boundary state.
 An ``unroll=True`` twin (static trip count, python-int rotate
 amounts) exists for small windows where the legacy kernel used to
 engage; the three-way auto-pick (``ops/rolling.pick_range_engine``)
-chooses between shifted/VMEM-unrolled and streaming forms from the
-measured crossovers (bench.py ``rolling_crossover``).
+chooses between shifted/VMEM-unrolled and streaming forms at row-extent
+crossovers from the retired root benchmark harness.  No benchmark cell
+runs these forms or has measured the crossovers on the chip (the chain
+cells take ``windowed_stats`` and ``range_stats_chunk``);
+tests/test_tpu_compile.py compiles both kernels for a v5e.
 
 Both forms take an optional ``scale`` scalar that multiplies ``x``
 inside the kernel — downstream consumers that previously re-streamed
-the column through a separate elementwise pass (bench bodies, fused
-pipelines) fold it here for free.
+the column through a separate elementwise pass (the autotuner's probe
+bodies, fused pipelines) fold it here for free.
 
 HBM-roofline mechanisms (PR 6 — the pre-PR-1 chip bench put these
 kernels at 0.18-0.28 of the measured stream rate):
@@ -134,9 +137,9 @@ def _stream_max_rows() -> int:
     dynamic-rotate passes, so at SOME width the O(L log L) sort-based
     windowed form must win again; extrapolating the measured pass rate
     (~15us per [1024, 8192] rotate) against the measured RMQ-path
-    floor (~1.05 s/iteration at that shape, the pre-PR-1 chip bench)
-    puts the crossover above 20k rows.  Re-measure with bench.py
-    --only-stream-stats and override here.  Env unset falls back to
+    floor (~1.05 s/iteration at that shape, a record older than the
+    benchmark cells) puts the crossover above 20k rows; no benchmark
+    cell has measured it on the chip.  Env unset falls back to
     the tuned-profile prior (tempo_tpu/tune — the autotuner's
     audit-gated winner: a candidate ceiling that flipped the engine
     pick changed result bits and was rejected at sweep time), then to

@@ -687,7 +687,7 @@ def _range_stats_shifted_xla(
     ``max_behind``/``max_ahead``) audits exactly that — the same
     contract as the halo layer's clipped counts (parallel/halo.py).
     Callers derive bounds from real data and assert the audit is zero
-    (frame layer: deferred collect-time audit; bench.py: hard assert).
+    (the frame layer's deferred collect-time audit).
     """
     dt = x.dtype
     xz = jnp.where(valid, x, 0.0)

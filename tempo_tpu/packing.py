@@ -607,12 +607,9 @@ class AsofChunkPlan:
     ``chunk_pad_sid`` is the per-(row, chunk) series id given to pad
     lanes so the segmented fill flows into the chunk tail and the
     cross-chunk carry can be read at the last lane (SID_PAD when the
-    chunk is empty).  The per-row arrays are derived on first read, for
-    the consumers that want them: ``l_dest``/``r_dest`` lane
-    destinations inside [K, n_chunks*Cm] (-1 at padding), ``l_out`` the
-    destination inside the kernel's [K, n_chunks*S] output, ``r_pos``
-    each right row's global merged position (the psrc planes of the
-    maxLookback form)."""
+    chunk is empty).  ``r_pos``, each right row's global merged
+    position (the psrc planes of the maxLookback form), is derived on
+    first read."""
 
     n_chunks: int
     chunk_rows: int                 # S = real merged rows per full chunk
@@ -626,34 +623,11 @@ class AsofChunkPlan:
     r_width: int                    # Lr
 
     @functools.cached_property
-    def _l_lanes(self):
-        return _lane_chunks(self.l_bounds, self.l_width)
-
-    @functools.cached_property
-    def _r_lanes(self):
-        return _lane_chunks(self.r_bounds, self.r_width)
-
-    @functools.cached_property
-    def l_dest(self) -> np.ndarray:          # [K, Ll] int64, -1 pads
-        c, rank = self._l_lanes
-        return np.where(c >= 0, c * self.merged_lanes + rank, -1)
-
-    @functools.cached_property
-    def r_dest(self) -> np.ndarray:          # [K, Lr] int64, -1 pads
-        c, rank = self._r_lanes
-        return np.where(c >= 0, (c + 1) * self.merged_lanes - 1 - rank, -1)
-
-    @functools.cached_property
-    def l_out(self) -> np.ndarray:           # [K, Ll] int64, -1 pads
-        c, rank = self._l_lanes
-        return np.where(c >= 0, c * self.chunk_rows + rank, -1)
-
-    @functools.cached_property
     def r_pos(self) -> np.ndarray:           # [K, Lr] int64, -1 pads
         """Right row ``b`` of chunk ``c`` follows exactly the left rows
         ordered before it, and those are bounded by the chunk's left
         run: one bisection inside that run, per right row."""
-        c, _ = self._r_lanes
+        c = _lane_chunks(self.r_bounds, self.r_width)
         K, Lr = c.shape
         k, b = np.nonzero(c >= 0)
         cc = c[k, b]
@@ -711,14 +685,12 @@ def _per_lane(bounds: np.ndarray, width: int, per_chunk, tail) -> np.ndarray:
     return np.repeat(vals.ravel(), counts.ravel())
 
 
-def _lane_chunks(bounds: np.ndarray, width: int):
-    """Chunk index (-1 past the side's real rows) and rank inside the
-    chunk's run of every lane of a packed ``[K, width]`` side."""
+def _lane_chunks(bounds: np.ndarray, width: int) -> np.ndarray:
+    """Chunk index of every lane of a packed ``[K, width]`` side, -1
+    past the side's real rows."""
     K, n1 = bounds.shape
     c = _per_lane(bounds, width, np.arange(n1 - 1, dtype=np.int64), -1)
-    start = _per_lane(bounds, width, bounds[:, :-1], 0)
-    return (c.reshape(K, width),
-            np.arange(width, dtype=np.int64) - start.reshape(K, width))
+    return c.reshape(K, width)
 
 
 def _run_last(side: np.ndarray, bounds: np.ndarray) -> np.ndarray:
@@ -876,18 +848,6 @@ def chunk_take_index(plan: AsofChunkPlan, l_lane: np.ndarray) -> np.ndarray:
     take = np.take(per_lane, l_lane)
     take += l_lane
     return take
-
-
-def chunk_gather(plane: np.ndarray, dest: np.ndarray, fill,
-                 dtype=None) -> np.ndarray:
-    """Read each real lane's chunked destination (``dest``, -1 entries
-    dropped) of a kernel output back into the packed [K, L] form."""
-    K = dest.shape[0]
-    out = np.full(dest.shape, fill, dtype=dtype or plane.dtype)
-    rows = np.broadcast_to(np.arange(K)[:, None], dest.shape)
-    m = dest >= 0
-    out[m] = plane[rows[m], dest[m]]
-    return out
 
 
 def unpack_ragged(

@@ -98,8 +98,8 @@ Registry coverage map (program -> production user):
 ==============================  =======================================
 
 The Mosaic-lowered engines (lane-chunked join, streaming window
-kernels) cannot produce a TPU artifact on a CPU-only image; their
-registry entries are gated ``requires_tpu`` and their carry/identity
+kernels) have no registry entry: tests/test_tpu_compile.py compiles
+their device programs for a described v5e, and their carry/identity
 behaviour stays pinned by the interpret-mode suites
 (tests/test_chunked_join.py, test_pallas_window.py).
 
@@ -232,16 +232,14 @@ _BUILDERS: Dict[str, Callable] = {}
 _BUILDER_META: Dict[str, dict] = {}
 
 
-def register(name: str, requires_devices: int = 1,
-             requires_tpu: bool = False):
+def register(name: str, requires_devices: int = 1):
     """Declare a compiled-contract builder.  The builder returns
     ``(programs, chains)`` (lists; a bare CompiledProgram also works)
     and is invoked lazily by :func:`build_all`."""
 
     def deco(fn):
         _BUILDERS[name] = fn
-        _BUILDER_META[name] = dict(requires_devices=requires_devices,
-                                   requires_tpu=requires_tpu)
+        _BUILDER_META[name] = dict(requires_devices=requires_devices)
         return fn
 
     return deco
@@ -273,7 +271,7 @@ def _normalize(name: str, result) -> Tuple[List[CompiledProgram],
 def build_all(only: Optional[Sequence[str]] = None):
     """Build the registry (or the named subset).  Returns
     ``(programs, chains, skipped, errors)`` where ``skipped`` maps
-    name -> reason (unmet backend requirement) and ``errors`` maps
+    name -> reason (unmet device-count requirement) and ``errors`` maps
     name -> exception string (a build failure is a finding, not a
     crash — the runner turns it into the build-error exit bit).
 
@@ -302,7 +300,6 @@ def build_all(only: Optional[Sequence[str]] = None):
             "tools/analyze.py --compiled does) before building")
 
     n_dev = len(jax.devices())
-    backend = jax.default_backend()
     wanted = list(only) if only else names()
     unknown = [n for n in wanted if n not in _BUILDERS]
     if unknown:
@@ -315,11 +312,6 @@ def build_all(only: Optional[Sequence[str]] = None):
     errors: Dict[str, str] = {}
     for name in wanted:
         meta = _BUILDER_META[name]
-        if meta["requires_tpu"] and backend != "tpu":
-            skipped[name] = ("Mosaic-lowered engine: no TPU artifact on "
-                            f"backend {backend!r} (pinned by the "
-                            "interpret-mode suites)")
-            continue
         if n_dev < meta["requires_devices"]:
             skipped[name] = (f"needs {meta['requires_devices']} devices, "
                              f"have {n_dev} (set --xla_force_host_"
@@ -951,21 +943,3 @@ def _donate_landed(compiled) -> bool:
         return "input_output_alias" in compiled.as_text()
     except Exception:  # pragma: no cover - backend-specific
         return False
-
-
-@register("engine.join_chunked", requires_tpu=True)
-def _build_engine_join_chunked():  # pragma: no cover - TPU image only
-    """The lane-chunked streaming merge (Mosaic): TPU artifact only."""
-    import jax
-    import numpy as np
-
-    from tempo_tpu.ops import pallas_merge as pm
-
-    mesh = _series_mesh()
-    a = _mesh_arrays(mesh)
-    fn = jax.jit(lambda lts, rts, rvd, rv: pm.asof_merge_values_chunked(
-        lts, rts, rvd, rv))
-    compiled = fn.lower(np.asarray(a["ts"]), np.asarray(a["ts"]),
-                        np.asarray(a["rvalids"]),
-                        np.asarray(a["rvals"])).compile()
-    return CompiledProgram("engine.join_chunked", compiled, Contract())

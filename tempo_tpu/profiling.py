@@ -307,9 +307,9 @@ _COLLECTIVE_OPS = ("collective-permute", "all-to-all", "all-gather",
 #: collective-inventory compiled-contract rule
 #: (tools/analysis/compiled/), so "how much XLA padding is
 #: acceptable" is decided once.  The CPU-mesh measurements are
-#: byte-exact (ratio 1.0, MULTICHIP_r05 + the round-8 contract
-#: baselines); the headroom covers XLA padding/fusion round-up on
-#: real ICI, and all-reduce gets extra slack because scalar audit
+#: byte-exact (ratio 1.0: tests/test_mesh_scaling.py and the compiled
+#: contract baselines); no chip mesh has measured it, and the headroom
+#: is meant for XLA padding/fusion round-up on real ICI, and all-reduce gets extra slack because scalar audit
 #: reductions ride tuple-combined all-reduces whose shapes XLA may
 #: widen.
 COLLECTIVE_TOLERANCE: Dict[str, float] = {

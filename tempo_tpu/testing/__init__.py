@@ -1,7 +1,7 @@
 """Test-support subpackage: fault injection
 (:mod:`tempo_tpu.testing.faults`) and the chaos campaign harness
 (:mod:`tempo_tpu.testing.chaos` — scripted kill/flaky/delay schedules
-against live serving + query planes, bench config 15's body).
+against live serving + query planes).
 
 Shipped inside the library (not under tests/) so downstream users can
 chaos-test their own pipelines against the same harness the ``chaos``

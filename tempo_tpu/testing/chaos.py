@@ -30,11 +30,10 @@ stream so per-stream order survives retries (an event is re-submitted
 only until it is acked — the at-least-once feeder every replayable
 event source implements).
 
-Entry points: :func:`run_serving_campaign`,
-:func:`run_service_campaign`, and :func:`run_campaign` (both planes,
-one report — bench config 15's ``--only-chaos-serving`` body); the
-BATCH plane's campaign is :func:`run_pipeline_campaign` (bench config
-16's ``--only-chaos-pipeline`` body): the Parquet→mesh→planned-chain
+Entry points: :func:`run_serving_campaign` and
+:func:`run_service_campaign` (tests/test_fault_domain.py); the BATCH
+plane's campaign is :func:`run_pipeline_campaign`
+(tests/test_batch_chaos.py): the Parquet→mesh→planned-chain
 path driven through ingest kills, row-group corruption, torn writes,
 deadlines, a flapping file, a mid-chain plan-barrier kill, and the
 ≥1B-row out-of-core slab sweep killed and resumed mid-run — with
@@ -576,22 +575,8 @@ def run_service_campaign(*, n_queries: int = 12, seed: int = 5,
     }
 
 
-def run_campaign(checkpoint_dir: str, *, n_streams: int = 12,
-                 events_per_stream: int = 24, seed: int = 17,
-                 recovery_bound_s: float = 60.0) -> dict:
-    """Both planes, one report — the body of bench config 15
-    (``--only-chaos-serving``)."""
-    serving = run_serving_campaign(
-        checkpoint_dir, n_streams=n_streams,
-        events_per_stream=events_per_stream, seed=seed,
-        recovery_bound_s=recovery_bound_s)
-    service = run_service_campaign(seed=seed + 1)
-    serving["service"] = service
-    return serving
-
-
 # ----------------------------------------------------------------------
-# The batch-pipeline campaign (bench config 16)
+# The batch-pipeline campaign
 # ----------------------------------------------------------------------
 
 def make_parquet_dataset(path: str, *, n_rows: int, n_keys: int,
@@ -652,7 +637,7 @@ def run_pipeline_campaign(workdir: str, *, rows_total: int = 360_000,
     → mesh → planned streaming AS-OF join + packed range stats, driven
     to ``rows_total`` cumulative rows through the out-of-core slab
     sweep, under a kill/flaky/corrupt schedule.  Asserted HARD (a
-    violation raises and nulls bench config 16):
+    violation raises):
 
     * a mid-file ingest kill resumes from the per-shard progress
       manifest: completed shards are NOT re-read, and the resumed
@@ -1047,7 +1032,7 @@ def run_pipeline_campaign(workdir: str, *, rows_total: int = 360_000,
 
 
 # ----------------------------------------------------------------------
-# Storage-engine chaos (bench config 17)
+# Storage-engine chaos
 # ----------------------------------------------------------------------
 
 def run_store_campaign(workdir: str, *, rows: int = 20_000,
@@ -1058,7 +1043,7 @@ def run_store_campaign(workdir: str, *, rows: int = 20_000,
     """The storage-plane chaos campaign — transactional clustered
     write-back, background compaction, and the tiered cohort-state
     spill, under a kill/corrupt schedule.  Asserted HARD (a violation
-    raises and nulls bench config 17):
+    raises):
 
     * a mid-write kill resumes the staged generation with ZERO
       committed-segment re-writes (segment writes are call-counted),

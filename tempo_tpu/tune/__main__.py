@@ -5,7 +5,7 @@ Modes:
 * (default) full sweep of every shape class, profile written to the
   checked-in per-device-kind location (``--out`` overrides);
 * ``--smoke`` — the CI gate: tiny shapes (``TEMPO_BENCH_SMOKE`` in the
-  probe children), the clipped smoke ladders, profile written to
+  ``tools/tune_probe.py`` children), the clipped smoke ladders, profile written to
   ``--out`` when given (a temp artifact otherwise, never the
   checked-in path).  **Exits nonzero on any bitwise-audit failure** —
   a contract-bitwise knob (DMA depth, pack width, megacore, serve
@@ -15,7 +15,7 @@ Modes:
   ``TEMPO_TPU_TUNE_PROFILE`` resolution + refusal checks) and exit.
 
 The summary table and progress go to stderr; stdout carries ONE JSON
-line (the sweep record) so drivers can parse it like the bench.
+line (the sweep record) for scripts to parse.
 """
 
 from __future__ import annotations

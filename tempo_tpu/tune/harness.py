@@ -1,11 +1,10 @@
 """The sweep harness: measure the knob space, gate on bitwise audits,
 persist the winners as a profile.
 
-Every candidate point runs in a **child process** (``bench.py
---only-tune-probe <probe>`` with the candidate knobs in the child's
-environment — the same isolation discipline as ``bench.py``'s
-``_config_subprocess``/``bench_pipelined``): a Mosaic OOM, an infeasible
-ring depth or a compiler hang kills the child, never the tuner.  Each
+Every candidate point runs in a **child process** (``python
+tools/tune_probe.py <probe>`` with the candidate knobs in the child's
+environment): a Mosaic OOM, an infeasible ring depth or a compiler hang
+kills the child, never the tuner.  Each
 probe reports a rate AND a CRC-32 digest of the full kernel outputs on
 deterministic data; the **bitwise value-audit gate** compares every
 candidate's digest against the all-defaults baseline and rejects any
@@ -60,18 +59,18 @@ MARGIN = 0.02
 PRUNE_AFTER = 2
 
 
-def _bench_path() -> str:
+def _probe_path() -> str:
     import tempo_tpu
 
     root = os.path.dirname(os.path.dirname(
         os.path.abspath(tempo_tpu.__file__)))
-    return os.path.join(root, "bench.py")
+    return os.path.join(root, "tools", "tune_probe.py")
 
 
 def run_probe(probe: str, knobs: Dict[str, object],
               smoke: bool = False,
               timeout: Optional[float] = None) -> Dict:
-    """One measurement child: ``bench.py --only-tune-probe <probe>``
+    """One measurement child: ``python tools/tune_probe.py <probe>``
     with exactly ``knobs`` applied (every other tunable knob cleared —
     an inherited env knob must not contaminate the baseline) and
     profile loading off (the sweep measures raw knob values).  Returns
@@ -94,7 +93,7 @@ def run_probe(probe: str, knobs: Dict[str, object],
         timeout = 300 if smoke else 1200
     try:
         proc = subprocess.run(
-            [sys.executable, _bench_path(), "--only-tune-probe", probe],
+            [sys.executable, _probe_path(), probe],
             capture_output=True, text=True, timeout=timeout, env=env,
         )
     except subprocess.TimeoutExpired:

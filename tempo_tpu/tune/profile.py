@@ -225,7 +225,7 @@ def load(strict: bool = False):
     """The active profile document, or None (loading off, no profile
     for this device kind, or a refused profile).  Memoized per
     ``TEMPO_TPU_TUNE_PROFILE`` value — flipping the knob mid-process
-    (the bench's tuned-vs-default flip, the tests) reloads on the next
+    (the tests' tuned-vs-default flips) reloads on the next
     read; :func:`reload` drops the memo outright.  Refusals warn ONCE
     per memo generation and fall back to defaults; ``strict=True``
     re-raises them (the CLI and the lifecycle tests)."""
@@ -265,7 +265,7 @@ def load(strict: bool = False):
 
 
 def reload() -> None:
-    """Drop the memoized profile (tests, the bench's in-process
+    """Drop the memoized profile (the tests' in-process
     tuned-vs-default flips)."""
     global _cache
     with _lock:

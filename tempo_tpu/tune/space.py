@@ -21,10 +21,9 @@ gate decides whether the candidate is even admissible:
   engine at shapes the probe never ran.  The axis rides the sweep
   purely as the audit surface; the default ceiling always stands.
 
-Shape classes mirror the regimes the bench measures: the dense/medium
-streaming stats kernels (configs 2b's densities), the column-packed
-streaming kernel, the fused join+stats+EMA chain (configs 1-3's
-composite), the lane-chunked AS-OF join (TPU-only: the Mosaic kernel),
+Shape classes mirror the engine regimes: the dense/medium streaming
+stats kernels (50 Hz and 10 Hz ticks), the column-packed streaming
+kernel, the fused join+stats+EMA chain (``__graft_entry__``'s step), the lane-chunked AS-OF join (TPU-only: the Mosaic kernel),
 the serving micro-batch executor, and the PR 17 dispatch-floor planes
 (the slab-pipeline ring depth, the whole-chain stitch length, and the
 cohort dispatch-coalescing window).  Each knob has exactly ONE
@@ -53,7 +52,7 @@ class Axis(NamedTuple):
 
 class ShapeClass(NamedTuple):
     name: str
-    probe: str              # bench.py --only-tune-probe <probe>
+    probe: str              # tools/tune_probe.py <probe>
     axes: Tuple[Axis, ...]
     owns: Tuple[str, ...]   # knobs whose winner feeds profile["knobs"]
     requires_tpu: bool = False
@@ -68,7 +67,7 @@ SPACE: Tuple[ShapeClass, ...] = (
             Axis("TEMPO_TPU_MEGACORE", (1, 0), (1, 0)),
         ),
         owns=("TEMPO_TPU_DMA_BUFFERS", "TEMPO_TPU_MEGACORE"),
-        doc="config 2b's ~50 Hz density: the streaming window engine's "
+        doc="~50 Hz density: the streaming window engine's "
             "home regime — owns the DMA ring depth + megacore knobs"),
     ShapeClass(
         "stream_medium", "stream_medium",

@@ -16,9 +16,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # or cost priors under tests that pin rule behaviour — and neither may
 # a TEMPO_TPU_TUNE_PROFILE leaking in from the developer's shell, so
 # this is a hard assignment like JAX_PLATFORMS above.  Tests that
-# exercise the profile machinery (test_tune.py via monkeypatch, and
-# bench's tuned child via test_bench_contract's child env) opt back in
-# explicitly.
+# exercise the profile machinery (test_tune.py via monkeypatch) opt
+# back in explicitly.
 os.environ["TEMPO_TPU_TUNE_PROFILE"] = "off"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

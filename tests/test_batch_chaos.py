@@ -171,7 +171,7 @@ def test_pipeline_signature_stable_for_reprless_kwargs():
 
 
 # ----------------------------------------------------------------------
-# The campaign smoke (bench config 16's body at tiny sizes)
+# The campaign smoke (the batch-plane campaign at tiny sizes)
 # ----------------------------------------------------------------------
 
 def test_pipeline_campaign_smoke(tmp_path):

@@ -1,7 +1,7 @@
 """Lane-chunked streaming AS-OF merge: chunked vs single-plan vs
 host-bracket oracle across the full flag matrix.
 
-The chunked engine (ops/pallas_merge.py:asof_merge_values_chunked) must
+The chunked engine (ops/pallas_merge.py:asof_merge_indices_chunked) must
 be bit-identical to the XLA sort-and-scan oracle — and therefore to the
 single-plan kernel and the host time-bracketing path, which are pinned
 against the same oracle — for every flag combination, with chunk
@@ -31,6 +31,38 @@ from tests.test_pallas_merge import _binpacked_case, _rand_case
 
 CHUNK = 256  # merged lanes per chunk; S = 128 real rows -> boundaries
              # land inside every case below
+
+
+def _chunked_indices(l_ts, r_ts, r_valids, **kw):
+    """The chunked engine's ``(last_idx [K, Ll], per_col_idx [C, K,
+    Ll])`` int32, -1 for none and at pad lanes — the form of
+    ``asof_merge_indices_pallas``: the last-row channel and the
+    per-column channels, each taken to the real left lanes."""
+    K, Ll = l_ts.shape
+    real = np.flatnonzero(l_ts.ravel() < TS_PAD)
+
+    def lanes(skip):
+        take, planes = pm.asof_merge_indices_chunked(
+            l_ts, r_ts, r_valids, real, skip_nulls=skip, **kw)
+        out = np.full((len(planes), K * Ll), -1, np.int32)
+        for o, p in zip(out, planes):
+            got = np.take(p, take)
+            o[real] = np.where(np.isnan(got), -1, got)
+        return out.reshape(-1, K, Ll)
+
+    return lanes(False)[0], lanes(True)
+
+
+def _chunked_values(l_ts, r_ts, r_valids, r_values, **kw):
+    """``(vals, found, idx)`` as the values engines return them: the
+    chunked positions with each column's right value gathered at its
+    last valid row on the host, NaN where there is none."""
+    last, per_col = _chunked_indices(l_ts, r_ts, r_valids, **kw)
+    rows = np.arange(l_ts.shape[0])[:, None]
+    vals = np.stack([np.where(p >= 0, np.asarray(v, np.float32)[rows, p],
+                              np.float32(np.nan))
+                     for p, v in zip(per_col, r_values)])
+    return vals, ~np.isnan(vals), last
 
 
 def _check_real(got, want, l_ts, label, idx_too=True):
@@ -65,7 +97,7 @@ def test_nan_run_longer_than_a_chunk_carries_across():
     want = sm._asof_merge_explicit(
         jnp.asarray(l_ts), jnp.asarray(r_ts), jnp.asarray(r_valids),
         jnp.asarray(r_values))
-    got = pm.asof_merge_values_chunked(
+    got = _chunked_values(
         l_ts, r_ts, r_valids, r_values, chunk_lanes=CHUNK, interpret=True)
     _check_real(got, want, l_ts, "nan-run")
 
@@ -85,7 +117,7 @@ def test_lookback_straddles_chunk_boundaries(ml):
     want = sm._asof_merge_explicit(
         jnp.asarray(l_ts), jnp.asarray(r_ts), jnp.asarray(r_valids),
         jnp.asarray(r_values), max_lookback=ml)
-    got = pm.asof_merge_values_chunked(
+    got = _chunked_values(
         l_ts, r_ts, r_valids, r_values, max_lookback=ml,
         chunk_lanes=CHUNK, interpret=True)
     _check_real(got, want, l_ts, f"ml={ml}")
@@ -108,7 +140,7 @@ def test_seq_ties_at_chunk_edges():
     want = sm._asof_merge_explicit(
         jnp.asarray(l_ts), jnp.asarray(r_ts), jnp.asarray(r_valids),
         jnp.asarray(r_values), r_seq=jnp.asarray(r_seq))
-    got = pm.asof_merge_values_chunked(
+    got = _chunked_values(
         l_ts, r_ts, r_valids, r_values, l_seq=l_seq, r_seq=r_seq,
         chunk_lanes=CHUNK, interpret=True)
     _check_real(got, want, l_ts, "seq-ties")
@@ -124,8 +156,8 @@ def test_binpacked_series_straddling_chunks():
     want_v, want_f, _ = (np.asarray(a) for a in sm._asof_merge_explicit(
         jnp.asarray(l_ts), jnp.asarray(r_ts), jnp.asarray(r_valids),
         jnp.asarray(r_values)))
-    got = pm.asof_merge_values_chunked(
-        lt2, rt2, rm2, rv2, lsid, rsid, chunk_lanes=CHUNK, interpret=True)
+    got = _chunked_values(lt2, rt2, rm2, rv2, l_sid=lsid, r_sid=rsid,
+                          chunk_lanes=CHUNK, interpret=True)
     gv, gf = np.asarray(got[0]), np.asarray(got[1])
     for s in range(S):
         r0, o0 = bp.row[s], bp.l_off[s]
@@ -138,41 +170,39 @@ def test_binpacked_series_straddling_chunks():
 
 
 def test_chunked_equals_single_plan_and_bitonic_bitwise():
-    """The three engines run the same network: real-lane outputs are
+    """The three engines run the same network: real-lane positions are
     bit-identical (fills select values, they never compute)."""
     rng = np.random.default_rng(21)
-    l_ts, r_ts, r_valids, r_values = _rand_case(rng, 4, 256, 256, 2,
-                                                tie_heavy=True)
-    a = pm.asof_merge_values_pallas(
-        jnp.asarray(l_ts), jnp.asarray(r_ts), jnp.asarray(r_valids),
-        jnp.asarray(r_values), interpret=True)
-    b = pm.asof_merge_values_chunked(
-        l_ts, r_ts, r_valids, r_values, chunk_lanes=CHUNK, interpret=True)
-    c = pm.asof_merge_values_bitonic(
-        jnp.asarray(l_ts), jnp.asarray(r_ts), jnp.asarray(r_valids),
-        jnp.asarray(r_values))
+    l_ts, r_ts, r_valids, _ = _rand_case(rng, 4, 256, 256, 2,
+                                         tie_heavy=True)
+    dev = [jnp.asarray(a) for a in (l_ts, r_ts, r_valids)]
+    a = pm.asof_merge_indices_pallas(*dev, interpret=True)
+    b = _chunked_indices(l_ts, r_ts, r_valids, chunk_lanes=CHUNK,
+                         interpret=True)
+    c = pm.asof_merge_indices_bitonic(*dev)
     real = l_ts < TS_PAD
     for other, label in ((b, "chunked"), (c, "bitonic")):
         np.testing.assert_array_equal(
-            np.asarray(a[0])[:, real].view(np.int32),
-            np.asarray(other[0])[:, real].view(np.int32),
-            err_msg=f"{label} not bitwise-identical")
+            np.asarray(a[0])[real], np.asarray(other[0])[real],
+            err_msg=f"{label} last-row index not bitwise-identical")
         np.testing.assert_array_equal(
-            np.asarray(a[2])[real], np.asarray(other[2])[real],
-            err_msg=label)
+            np.asarray(a[1])[:, real], np.asarray(other[1])[:, real],
+            err_msg=f"{label} per-column index not bitwise-identical")
 
 
 def test_chunked_rejects_tracers():
-    def f(a, b, c, d):
-        return pm.asof_merge_values_chunked(a, b, c, d)[0]
+    l_ts, r_ts, r_valids, _ = _rand_case(
+        np.random.default_rng(0), 2, 128, 128, 1)
+    real = np.flatnonzero(l_ts.ravel() < TS_PAD)
+
+    def f(a, b, c):
+        return pm.asof_merge_indices_chunked(a, b, c, real)[0]
 
     import jax
 
-    l_ts, r_ts, r_valids, r_values = _rand_case(
-        np.random.default_rng(0), 2, 128, 128, 1)
     with pytest.raises(TypeError, match="bitonic"):
         jax.jit(f)(jnp.asarray(l_ts), jnp.asarray(r_ts),
-                   jnp.asarray(r_valids), jnp.asarray(r_values))
+                   jnp.asarray(r_valids))
 
 
 # ----------------------------------------------------------------------
@@ -430,16 +460,14 @@ def _force_chunked(monkeypatch, binpack):
     monkeypatch.setenv("TEMPO_TPU_JOIN_CHUNK_LANES", str(CHUNK))
 
 
-def _plane_chain(out, plan, l_layout, r_layout, bp, skip):
+def _plane_chain(out, l_out, l_layout, r_layout, bp, skip):
     """The former unpack, kept as the oracle: every channel gathered into
-    a [K, Ll] plane (chunk_gather), -1 for no match, then read at each
-    left row's (lane row, lane) plus its series' right start."""
-    from tempo_tpu import packing
-
-    coded = [np.where(np.isnan(p), -1, p).astype(np.int32)
-             for p in (packing.chunk_gather(np.asarray(o), plan.l_out,
-                                            np.nan, np.float32)
-                       for o in out)]
+    a [K, Ll] plane through the lexsort oracle's destinations ``l_out``,
+    -1 for no match, then read at each left row's (lane row, lane) plus
+    its series' right start."""
+    rows = np.arange(l_out.shape[0])[:, None]
+    coded = [np.where((l_out >= 0) & ~np.isnan(g), g, -1).astype(np.int32)
+             for g in (np.asarray(o)[rows, l_out] for o in out)]
     C = len(coded) - 1
     k = l_layout.key_ids
     pos = np.arange(l_layout.n_rows) - l_layout.starts[k]
@@ -473,12 +501,17 @@ def test_flat_take_matches_plane_chain(monkeypatch, binpack, skip):
     want = L.asofJoin(R, skipNulls=skip).df
 
     seen = {"rows": []}
-    real_run, real_starts, real_rows = (
-        pm._chunked_run, join._right_starts, join._right_rows)
+    real_build, real_call, real_starts, real_rows = (
+        pm.build_chunked_planes, pm._chunked_call, join._right_starts,
+        join._right_rows)
 
-    def run_spy(*a):
-        seen["run"] = real_run(*a)
-        return seen["run"]
+    def build_spy(*a, **k):
+        seen["build"] = (a, k, real_build(*a, **k))
+        return seen["build"][2]
+
+    def call_spy(*a, **k):
+        seen["out"] = real_call(*a, **k)
+        return seen["out"]
 
     def starts_spy(*a):
         seen["layouts"] = a
@@ -488,19 +521,22 @@ def test_flat_take_matches_plane_chain(monkeypatch, binpack, skip):
         seen["rows"].append(real_rows(*a))
         return seen["rows"][-1]
 
-    monkeypatch.setattr(pm, "_chunked_run", run_spy)
+    monkeypatch.setattr(pm, "build_chunked_planes", build_spy)
+    monkeypatch.setattr(pm, "_chunked_call", call_spy)
     monkeypatch.setattr(join, "_right_starts", starts_spy)
     monkeypatch.setattr(join, "_right_rows", rows_spy)
     _force_chunked(monkeypatch, binpack)
     got = L.asofJoin(R, skipNulls=skip).df
     pd.testing.assert_frame_equal(got, want, check_exact=True)
 
-    out, plan, _ = seen["run"]
+    (l_ts, r_ts, *_), kw, (_, _, plan, _) = seen["build"]
     l_layout, _, bp = seen["layouts"]
     assert (bp is not None) == binpack
     assert plan.n_chunks >= 3                  # left runs cross chunk edges
     assert 0 in l_layout.lengths               # the empty left series
-    oracle = _plane_chain(out, plan, *seen["layouts"], skip)
+    l_out = _lexsort_plan(l_ts, r_ts, plan.merged_lanes, kw["l_sid"],
+                          kw["r_sid"])["l_out"]
+    oracle = _plane_chain(seen["out"], l_out, *seen["layouts"], skip)
     assert len(seen["rows"]) == len(oracle) == (4 if skip else 1)
     for i, ((flat, ok), (wflat, wok)) in enumerate(zip(seen["rows"],
                                                        oracle)):
@@ -568,11 +604,11 @@ def test_chunked_ring_depth_bitwise(monkeypatch):
     r_valids = rng.random((2, K, L)) > 0.1
     r_valids[0, 3] = False                  # NaN runs straddle chunks
     monkeypatch.delenv("TEMPO_TPU_DMA_BUFFERS", raising=False)
-    base = pm.asof_merge_values_chunked(
+    base = _chunked_values(
         l_ts, r_ts, r_valids, r_values, chunk_lanes=512, interpret=True)
     for depth in (3, 4):
         monkeypatch.setenv("TEMPO_TPU_DMA_BUFFERS", str(depth))
-        ring = pm.asof_merge_values_chunked(
+        ring = _chunked_values(
             l_ts, r_ts, r_valids, r_values, chunk_lanes=512,
             interpret=True)
         for a, b, name in zip(base, ring, ("vals", "found", "idx")):
@@ -831,9 +867,9 @@ _PLAN_CASES = ["ties_at_edges", "ragged_empty", "exact_fill",
 @pytest.mark.parametrize("name", _PLAN_CASES)
 def test_chunk_plan_matches_lexsort_oracle(name, ml, skip):
     """Run bounds from the merge-path bisection give the lexsort
-    planner's layout exactly: chunk count, every derived per-row
-    array, the pad sids, the take index and every uploaded plane
-    bitwise."""
+    planner's layout exactly: chunk count, the right rows' merged
+    positions, the pad sids, the take index (the left rows' output
+    destinations) and every uploaded plane bitwise."""
     from tempo_tpu import packing
 
     (l_ts, r_ts, r_valids, r_values, l_sid, r_sid,
@@ -853,8 +889,8 @@ def test_chunk_plan_matches_lexsort_oracle(name, ml, skip):
     want = _lexsort_plan(l_ts, r_ts, CHUNK, l_sid, r_sid, ls, rs)
     assert plan.n_chunks == want["n_chunks"]
     assert plan.n_chunks >= 2, "the case must cross a chunk edge"
-    for f in ("l_out", "r_pos", "l_dest", "r_dest"):
-        np.testing.assert_array_equal(getattr(plan, f), want[f], err_msg=f)
+    np.testing.assert_array_equal(plan.r_pos, want["r_pos"],
+                                  err_msg="r_pos")
     if l_sid is None:
         assert plan.chunk_pad_sid is None
     else:

@@ -459,7 +459,7 @@ def test_chain_prune_keeps_diffs_reachable(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# Campaign smoke (the bench config-15 body at test scale)
+# Campaign smoke (the serving and service campaigns at test scale)
 # ----------------------------------------------------------------------
 
 def test_serving_campaign_smoke(tmp_path):

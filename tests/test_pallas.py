@@ -1,8 +1,8 @@
 """Pallas scan kernels: interpret-mode correctness vs XLA/numpy oracles.
 
-On CPU the kernels run through the Pallas interpreter (the compiled path
-is TPU-only and exercised by bench.py on real hardware); the ladder
-logic (roll + iota masking) is identical in both modes.
+On CPU the kernels run through the Pallas interpreter; the compiled
+path is TPU-only, and tests/test_tpu_compile.py compiles each kernel
+for a v5e.  The ladder logic (roll + iota masking) is identical in both modes.
 """
 
 import numpy as np

@@ -1,9 +1,10 @@
 """Bucket (resample) VMEM kernels: interpret-mode parity vs the XLA
 windowed/segment forms and numpy oracles.
 
-The compiled path is TPU-only (bench.py config 3 + the resample device
-dispatch); the ladder logic (segmented scan + tail broadcast, fused
-head/EMA) is identical in interpret mode.
+The compiled path is TPU-only: tests/test_tpu_compile.py compiles it
+for a v5e, and no benchmark cell runs it on the chip yet.  The ladder
+logic (segmented scan + tail broadcast, fused head/EMA) is identical in
+interpret mode.
 """
 
 import numpy as np

@@ -1,9 +1,10 @@
 """Pallas merge-join kernel: interpret-mode correctness vs the XLA
 sort-and-scan oracle (``sortmerge._asof_merge_explicit``) and numpy.
 
-The compiled path is TPU-only (exercised at scale by bench.py on real
-hardware); the network logic (bitonic merge, ffill ladder, routing
-sort via roll + iota masks) is identical in interpret mode.
+The compiled path is TPU-only: tests/test_tpu_compile.py compiles it
+for a v5e, and the ``hhar.batch_chain`` cell runs it on the chip.  The
+network logic (bitonic merge, ffill ladder, routing sort via roll +
+iota masks) is identical in interpret mode.
 """
 
 import numpy as np

@@ -173,6 +173,13 @@ def test_join_flag_matrix_bitwise(monkeypatch, skip_nulls, max_lookback):
 HOST_CHAINS = {
     "join_select": lambda lt, rt: lt.asofJoin(rt)
     .select(["event_ts", "sym", "x", "right_v0"]),
+    # the skew ladder's bracketed rungs: the explicit tsPartitionVal
+    # brackets, and the oversize auto-bracketing (lane limit below)
+    "join_ts_partition_stats": lambda lt, rt: lt.asofJoin(
+        rt, tsPartitionVal=12).withRangeStats(
+        colsToSummarize=["x"], rangeBackWindowSecs=WINDOW),
+    "join_auto_bracket_stats": lambda lt, rt: lt.asofJoin(rt)
+    .withRangeStats(colsToSummarize=["x"], rangeBackWindowSecs=WINDOW),
     "stats_ema": lambda lt, rt: lt.withRangeStats(
         colsToSummarize=["x"], rangeBackWindowSecs=WINDOW)
     .EMA("x", exact=False),
@@ -196,6 +203,9 @@ def test_host_chain_bitwise_vs_eager(monkeypatch, chain):
             dfs.append(df)
         lt = TSDF(dfs[0], "event_ts", ["sym"])
         rt = TSDF(dfs[1], "event_ts", ["sym"])
+    if chain == "join_auto_bracket_stats":
+        # under the ~96 merged lanes a series: the join brackets
+        monkeypatch.setenv("TEMPO_TPU_MAX_MERGED_LANES", "64")
     fn = HOST_CHAINS[chain]
     monkeypatch.delenv("TEMPO_TPU_PLAN", raising=False)
     eager = fn(lt, rt).df

@@ -1,8 +1,8 @@
-"""Storage-plane chaos: the kill/corrupt campaign behind bench config
-17 at smoke sizes, the legacy-writer overwrite data-loss fix (never
-delete the old table before its replacement exists), and the hardened
-``io.writer.read`` path (corrupt row groups named, quarantined,
-never an opaque traceback)."""
+"""Storage-plane chaos: the kill/corrupt campaign at smoke sizes, the
+legacy-writer overwrite data-loss fix (never delete the old table
+before its replacement exists), and the hardened ``io.writer.read``
+path (corrupt row groups named, quarantined, never an opaque
+traceback)."""
 
 import os
 
@@ -162,7 +162,7 @@ def test_store_errors_classify_for_retry_policy(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# The campaign smoke (bench config 17's body at tiny sizes)
+# The campaign smoke (the storage-plane campaign at tiny sizes)
 # ----------------------------------------------------------------------
 
 def test_store_campaign_smoke(tmp_path):

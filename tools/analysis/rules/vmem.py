@@ -1,8 +1,8 @@
 """vmem-budget: every ``pl.pallas_call`` site must provably fit VMEM.
 
-The bug class (PR 3, BASELINE r3): a kernel whose per-step buffers are
-sized from data-dependent extents compiles fine on small inputs and
-OOMs the compiler/chip at scale — the ~205K-merged-lane XLA cliff, and
+The bug class: a kernel whose per-step buffers are sized from
+data-dependent extents compiles fine on small inputs and OOMs the
+compiler/chip at scale — the ~205K-merged-lane XLA cliff, and
 the measured [32, 16384] f32 block that blew the 16M scoped-VMEM cap
 at 23.5M.  The dynamic twin of this check is ``packing.asof_chunk_plan``
 / ``pallas_kernels._plan``; this rule is the static one, run at lint
