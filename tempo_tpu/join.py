@@ -30,6 +30,7 @@ from typing import List, Optional
 
 import numpy as np
 import pandas as pd
+from pandas._libs.lib import is_range_indexer
 
 from tempo_tpu import packing, profiling, resilience
 from tempo_tpu.profiling import span
@@ -132,6 +133,13 @@ def _prefixed(cols: List[str], prefix: Optional[str]) -> dict:
     if prefix is None or prefix == "":
         return {c: c for c in cols}
     return {c: f"{prefix}_{c}" for c in cols}
+
+
+def _rows_copied(indexer: np.ndarray, n: int) -> int:
+    """Rows ``iloc[indexer]`` copies out of an ``n``-row frame: none for
+    the identity order, which pandas answers with a lazy copy (the test
+    is the one ``DataFrame.take`` makes)."""
+    return 0 if is_range_indexer(indexer, n) else len(indexer)
 
 
 def _gather(values: np.ndarray, idx: np.ndarray, ok: np.ndarray):
@@ -604,13 +612,18 @@ def asof_join(
         with span("tempo.unpack", rows=n_left):
             taken.append(_right_rows(plane, take, r_start))
 
+    # The left columns go into the output as Series: each keeps its
+    # dtype and storage (an Arrow string column stays Arrow, no Python
+    # objects), and copy-on-write keeps the result and the caller's
+    # frame apart.  A side already in layout order is only wrapped.
     out = {}
-    with span("tempo.frame", rows=n_left + len(r_sorted_take)):
+    with span("tempo.frame", rows=_rows_copied(l_layout.order, n_left)
+              + _rows_copied(r_sorted_take, len(right.df))):
         left_sorted = left.df.iloc[l_layout.order].reset_index(drop=True)
         for c in pcols:
-            out[c] = left_sorted[c].to_numpy()
+            out[c] = left_sorted[c]
         for c in left_value_cols:
-            out[lmap[c]] = left_sorted[c].to_numpy()
+            out[lmap[c]] = left_sorted[c]
         r_sorted_df = right.df.iloc[r_sorted_take].reset_index(drop=True)
 
     with span("tempo.unpack"):
@@ -637,13 +650,17 @@ def asof_join(
                         "in the data frame, this warning can be ignored."
                     )
 
-    with span("tempo.frame", rows=n_left):
-        res = pd.DataFrame(out)
+    with span("tempo.frame") as framed:
+        # the right columns are the fresh arrays _gather made: wrapping
+        # them, like the left Series, copies nothing
+        res = pd.DataFrame(out, copy=False)
         if broadcast_path:
             # the inner-join filter, taken into left-row order like the
             # index channels
             keep = np.take(keep_mask_packed.reshape(-1), take)
             res = res[keep].reset_index(drop=True)
+            if len(res) < n_left:
+                framed.rows += len(res)
         if tsPartitionVal is not None or auto_bracketed:
             # the joint (key, bracket) layout emits rows in bracket order;
             # restore the same (key, ts) order the non-skew path produces
@@ -651,6 +668,7 @@ def asof_join(
             perm = np.lexsort(
                 (l_ts_ns[l_layout.order], l_codes[l_layout.order])
             )
+            framed.rows += _rows_copied(perm, len(res))
             res = res.iloc[perm].reset_index(drop=True)
 
         new_ts = lmap[left.ts_col]

@@ -68,3 +68,28 @@ def test_join_layout_sorts_both_sides_once(chain):
     layout = [s for s in spans
               if s.root == op.id and s.name == "tempo.layout"]
     assert sum(s.rows for s in layout) == n_left + n_right
+
+
+@pytest.mark.parametrize("presorted", [True, False],
+                         ids=["presorted", "unsorted"])
+def test_join_frame_spans_count_rows_copied(presorted):
+    """The join's own ``tempo.frame`` spans count the rows they copy: a
+    left side already in layout order is only wrapped (0 rows), one in
+    another order is taken into layout order (every left row).  The
+    right side is in layout order in both."""
+    rng = np.random.default_rng(8)
+    sort = ["User", "event_ts"]
+    # keys first seen in the same order on both sides, so the series
+    # ids, and with them the right side's layout, are the same
+    phone = _frame(rng, 600, ["x", "y"]).sort_values(
+        sort, ascending=[True, presorted], kind="stable")
+    watch = _frame(rng, 150, ["x"]).sort_values(sort, kind="stable")
+    first = max((s.id for s in profiling.recent_spans()[0]), default=0)
+    TSDF(phone, "event_ts", ["User"]).asofJoin(
+        TSDF(watch, "event_ts", ["User"]), right_prefix="watch")
+    spans = [s for s in profiling.recent_spans()[0] if s.id > first]
+    op, = [s for s in spans if s.name == "tempo.asofJoin"]
+    frames = [s for s in spans
+              if s.parent == op.id and s.name == "tempo.frame"]
+    assert len(frames) == 2
+    assert sum(s.rows for s in frames) == (0 if presorted else len(phone))
